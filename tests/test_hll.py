@@ -3,6 +3,7 @@ samplers/samplers_test.go set cases). Standard error at p=14 is ~0.8%;
 assert estimates within 3% (≈4 sigma)."""
 
 import numpy as np
+import pytest
 
 import jax.numpy as jnp
 
@@ -71,6 +72,30 @@ def test_out_of_range_slot_dropped():
     out = hll.insert_batch(regs, jnp.asarray(slot), jnp.asarray(reg),
                            jnp.asarray(rho))
     assert float(jnp.sum(out)) == 0.0
+
+
+@pytest.mark.parametrize("p", [4, 8, 14])
+def test_packed_insert_bit_identical_to_dense_roundtrip(p):
+    """insert_batch_packed touches only the addressed words; its result
+    must be the dense path's, bit for bit: pack(insert_batch(unpack)).
+    Batches carry duplicate (slot, register) pairs, several registers of
+    one word, both word-straddling fields, out-of-range and negative
+    slots, and rho beyond 6 bits (the dense path masks at pack time)."""
+    rng = np.random.default_rng(p)
+    k, r, b = 16, 1 << p, 512
+    words = jnp.asarray(hll.pack_registers_np(
+        rng.integers(0, 62, (k, r)).astype(np.uint8), p))
+    for trial in range(4):
+        slot = rng.integers(-2, k + 3, b).astype(np.int32)
+        reg = rng.integers(0, min(r, 40) if trial % 2 else r,
+                           b).astype(np.int32)
+        rho = rng.integers(0, 64 if trial < 3 else 256, b).astype(np.uint8)
+        got = hll.insert_batch_packed(words, slot, reg, rho, precision=p)
+        dense = hll.insert_batch(hll.unpack_registers(words, precision=p),
+                                 slot, reg, rho, precision=p)
+        want = hll.pack_registers(dense, precision=p)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        words = got
 
 
 # -- reference (axiomhq) wire-format compatibility --------------------------

@@ -1,4 +1,4 @@
-"""Golden parity + gating for the fused Pallas ingest kernel
+"""Golden parity + selection for the fused Pallas ingest kernel
 (veneur_tpu/ops/pallas_ingest.py).
 
 The kernel's whole correctness contract is BYTE parity with the XLA
@@ -30,7 +30,7 @@ SPEC = TableSpec(counter_capacity=64, gauge_capacity=32, status_capacity=8,
 @pytest.fixture
 def fused_on():
     """Force the fused path (interpret mode on CPU); always restore
-    probe gating so later test modules see the default behavior."""
+    the backend rule so later test modules see the default behavior."""
     pallas_ingest.set_enabled(True)
     try:
         yield
@@ -238,38 +238,70 @@ def test_v1_dense_u8_checkpoint_restores_byte_exact(tmp_path):
                                   packed_orig)
 
 
-# -- gating ------------------------------------------------------------------
+# -- block layout -------------------------------------------------------------
 
-def test_gating_env_and_override(monkeypatch):
+def test_blocks_sit_on_the_tpu_tiling():
+    """Mosaic refuses a block whose last two dims are neither (8, 128)-
+    aligned nor the whole array — interpret mode does not care, so the
+    layout rule is pinned here (tests/test_tpu_compile.py compiles it)."""
+    from veneur_tpu.config import Config
+    from veneur_tpu.server.server import spec_from_config
+    spec = spec_from_config(Config())
+    tiles, caps, nblocks, g_total = pallas_ingest._layout(spec)
+    tc, tg, tst, ts, th, ths = tiles
+    for tile, cap in ((tc, caps[0]), (tg, caps[1]), (tst, caps[2]),
+                      (ths, caps[5])):
+        lanes = pallas_ingest._lanes(cap)
+        assert tile % lanes == 0
+        assert tile == cap or (tile // lanes) % 8 == 0
+    for tile, cap in ((ts, caps[3]), (th, caps[4])):
+        assert tile == cap or (tile % 8 == 0 and cap % tile == 0)
+    assert g_total == max(nblocks)
+    # small tables ride whole, whatever their size
+    assert pallas_ingest._tile_1d(40) == 40 and pallas_ingest._lanes(40) == 40
+    assert pallas_ingest._row_tile(5, 64) == 5
+    # a large 1-D table must be lane-dense
+    with pytest.raises(ValueError):
+        pallas_ingest._tile_1d(100_000)
+
+
+# -- selection ---------------------------------------------------------------
+
+def test_selection_rule_and_override(monkeypatch):
+    """No probe, no environment variable: the backend and the module
+    constant decide, and the config override beats both."""
     assert jax.default_backend() == "cpu"
-    monkeypatch.delenv("VENEUR_TPU_PALLAS_INGEST", raising=False)
     pallas_ingest.set_enabled(None)
     try:
-        # CPU default: XLA chain (interpret mode is slower, not wrong)
+        # CPU: the XLA chain, whatever the constant says
+        monkeypatch.setattr(pallas_ingest, "ENABLED", True)
         assert not pallas_ingest.active()
         assert pallas_ingest.interpret_mode()
-        monkeypatch.setenv("VENEUR_TPU_PALLAS_INGEST", "1")
+        # a TPU backend selects the kernel iff the module constant is on
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert pallas_ingest.active()
-        monkeypatch.setenv("VENEUR_TPU_PALLAS_INGEST", "0")
+        monkeypatch.setattr(pallas_ingest, "ENABLED", False)
         assert not pallas_ingest.active()
-        # config-level override beats the env probe gate entirely
+        # the force variables of the probe era are gone
+        monkeypatch.setenv("VENEUR_TPU_PALLAS_INGEST", "1")
+        assert not pallas_ingest.active()
+        # config-level override beats the rule entirely
         pallas_ingest.set_enabled(True)
         assert pallas_ingest.active()
-        monkeypatch.setenv("VENEUR_TPU_PALLAS_INGEST", "1")
+        monkeypatch.setattr(pallas_ingest, "ENABLED", True)
         pallas_ingest.set_enabled(False)
         assert not pallas_ingest.active()
     finally:
         pallas_ingest.set_enabled(None)
 
 
-def test_config_wires_override(monkeypatch):
+def test_config_wires_override():
     """`pallas_ingest_enabled: false` must pin the XLA chain before any
-    aggregator compiles; the default leaves probe gating in place."""
+    aggregator compiles; the default leaves the backend rule in place."""
     from tests.test_server import small_config
     from veneur_tpu.server.server import Server
     from veneur_tpu.sinks.debug import DebugMetricSink
 
-    monkeypatch.delenv("VENEUR_TPU_PALLAS_INGEST", raising=False)
     try:
         srv = Server(small_config(pallas_ingest_enabled=False),
                      metric_sinks=[DebugMetricSink()])

@@ -1,0 +1,245 @@
+"""Compile the served path's device programs for a described TPU v5e.
+
+The sandbox has no chip, but libtpu's compiler is installed and compiles
+for a topology that is described rather than attached, so what the
+chip's compiler would refuse — a program that does not fit HBM, a Pallas
+block off the (8, 128) tiling, an op Mosaic cannot lower — fails here,
+at the shipped default widths (config.py tpu_* defaults), before any
+chip time is spent. Nothing runs: these tests say nothing about results
+or speed (chip_smoke.py does, on the chip).
+
+This is the only file that describes the chip. The topology is described
+inside a module-scoped fixture (one process may load libtpu; under xdist
+only the worker given this file does), and every sharding, mesh and
+shape is built in a fixture or a test, never at import.
+"""
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from veneur_tpu.aggregation import step
+from veneur_tpu.aggregation.host import BatchSpec
+from veneur_tpu.aggregation.state import TableSpec, empty_state
+from veneur_tpu.config import Config
+from veneur_tpu.server.server import spec_from_config
+
+GIB = 1 << 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip (the next run warns and
+    recompiles) — keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def default_spec() -> TableSpec:
+    return spec_from_config(Config())
+
+
+@pytest.fixture(scope="module")
+def default_sizes(default_spec):
+    """Packed lane sizes of the shipped batch config: the compile key of
+    the packed ingest program, derived as the aggregators derive it
+    (sharded_aggregator.py; native_aggregator._alloc_packed_buffers
+    builds the same tuple)."""
+    from veneur_tpu.aggregation.host import Batcher
+    cfg = Config()
+    bspec = BatchSpec(counter=cfg.tpu_batch_counter,
+                      gauge=cfg.tpu_batch_gauge,
+                      status=cfg.tpu_batch_status, set=cfg.tpu_batch_set,
+                      histo=cfg.tpu_batch_histo)
+    return step.batch_sizes(Batcher(default_spec, bspec).force_emit())
+
+
+def _state_shapes(spec, sharding, lead=()):
+    shapes = jax.eval_shape(partial(empty_state, spec))
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(lead + a.shape, a.dtype,
+                                       sharding=sharding), shapes)
+
+
+def _flat(sizes, sharding, lead=()):
+    words = step.packed_layout(sizes)[1]
+    return jax.ShapeDtypeStruct(lead + (words,), jnp.int32,
+                                sharding=sharding)
+
+
+def test_default_spec_is_the_shipped_one(default_spec, default_sizes):
+    """The widths these compiles run at are the ones the issue tables."""
+    assert (default_spec.counter_capacity, default_spec.gauge_capacity,
+            default_spec.status_capacity, default_spec.set_capacity,
+            default_spec.histo_capacity) == (131072, 32768, 1024, 4096,
+                                             16384)
+    assert default_spec.hll_precision == 14
+    assert default_spec.total_cells == 472
+    assert (default_sizes[0], default_sizes[2], default_sizes[4],
+            default_sizes[6], default_sizes[9]) == (8192, 2048, 256, 4096,
+                                                    8192)
+
+
+def test_packed_ingest_program_compiles_under_1gib(one_chip, default_spec,
+                                                   default_sizes):
+    """ingest_step_packed's program — ingest, fold and the in-band
+    compaction — on the XLA scatter chain: what serves wherever the
+    fused kernel is not selected, and always under the sharded vmap.
+    (The fused kernel has its own case below; the compaction it would
+    share with this program is most of the compile time.)"""
+    compiled = jax.jit(
+        partial(step.packed_step_core, spec=default_spec,
+                sizes=default_sizes), donate_argnums=(0,)).lower(
+        _state_shapes(default_spec, one_chip),
+        _flat(default_sizes, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < GIB, mem
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_live_flush_program_compiles(one_chip, default_spec, monkeypatch):
+    """flush_live_in_packed at full-capacity live buckets and the served
+    percentiles (0.5/0.75/0.99), on the XLA quantile path (the Pallas
+    quantile kernel has its own case, and rides the sharded flush
+    below)."""
+    from veneur_tpu.ops import pallas_digest
+    monkeypatch.setattr(pallas_digest, "enabled", lambda: False)
+    buckets = (default_spec.counter_capacity, default_spec.gauge_capacity,
+               default_spec.status_capacity, default_spec.set_capacity,
+               default_spec.histo_capacity)
+    n_q = 3
+    flat = jax.ShapeDtypeStruct((n_q + sum(buckets),), jnp.int32,
+                                sharding=one_chip)
+    compiled = jax.jit(partial(
+        step._flush_live_in_packed_core, spec=default_spec, n_q=n_q,
+        buckets=buckets)).lower(
+            _state_shapes(default_spec, one_chip), flat).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < GIB, mem
+
+
+def test_digest_quantile_kernel_compiles(one_chip):
+    from veneur_tpu.ops import pallas_digest
+    assert pallas_digest.ENABLED
+    r, c, q = 16384, 472, 3
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(pallas_digest.quantiles_rows).lower(
+        s(r, c), s(r, c), s(r), s(r), s(q)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_history_merge_kernel_compiles(one_chip):
+    """The range-query HLL merge at p=14 over a whole default ring (360
+    columns), 64 set rows, 8 steps."""
+    from veneur_tpu.history.spec import HistorySpec
+    from veneur_tpu.ops import hll, pallas_history
+    assert pallas_history.ENABLED
+    hs = HistorySpec()
+    n, w, steps, p = 64, hs.total_cols, 8, hs.hll_precision
+    rows = jax.ShapeDtypeStruct((n, w, hll.packed_words(p)), jnp.int32,
+                                sharding=one_chip)
+    sel = jax.ShapeDtypeStruct((steps, w), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(partial(pallas_history.merge_windows_packed,
+                               precision=p)).lower(rows, sel).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_ingest_kernel_compiles(one_chip, default_spec,
+                                      default_sizes):
+    """The kernel alone (plus the fold), every state leaf aliased in
+    place: no temporaries beyond the sorted streams."""
+    from veneur_tpu.ops import pallas_ingest
+    assert pallas_ingest.ENABLED
+
+    def prog(state, flat):
+        return step._fold_core(pallas_ingest.fused_ingest_core(
+            state, step.unpack_batch(flat[1:], default_sizes),
+            spec=default_spec, interpret=False))
+
+    compiled = jax.jit(prog, donate_argnums=(0,)).lower(
+        _state_shapes(default_spec, one_chip),
+        _flat(default_sizes, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo, default_spec):
+    """The four-chip host's default: one shard per chip, per-shard
+    capacities = default / 4."""
+    import numpy as np
+
+    from veneur_tpu.parallel.sharded import REPLICA_AXIS, SHARD_AXIS
+    from veneur_tpu.server.sharded_aggregator import per_shard_spec
+    n = 4
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, n),
+                (REPLICA_AXIS, SHARD_AXIS))
+    return (mesh, per_shard_spec(default_spec, n),
+            NamedSharding(mesh, P(REPLICA_AXIS, SHARD_AXIS)))
+
+
+def test_sharded_ingest_compiles_on_4_device_mesh(mesh4, default_sizes):
+    """The vmapped XLA chain under shard_map, one tile per device."""
+    from veneur_tpu.parallel.sharded import make_sharded_ingest_packed
+    mesh, pspec, sh = mesh4
+    n = mesh.devices.size
+    fn = make_sharded_ingest_packed(mesh, pspec, default_sizes)
+    compiled = fn.lower(_state_shapes(pspec, sh, lead=(1, n)),
+                        _flat(default_sizes, sh, lead=(1, n))).compile()
+    mem = compiled.memory_analysis()
+    # per-device bytes: a quarter of the table plus O(batch) temporaries
+    assert mem.argument_size_in_bytes < 64 << 20, mem
+    assert mem.temp_size_in_bytes < GIB, mem
+    # each tile's scatters stay on its own device
+    assert "all-" not in compiled.as_text()
+
+
+def test_sharded_flush_compiles_on_4_device_mesh(mesh4, monkeypatch):
+    """The merged flush of the sharded backend with the Pallas quantile
+    kernel under vmap inside shard_map — what four chips select."""
+    from veneur_tpu.ops import pallas_digest
+    from veneur_tpu.parallel.sharded import make_merged_flush
+    monkeypatch.setattr(pallas_digest, "enabled", lambda: True)
+    mesh, pspec, sh = mesh4
+    n = mesh.devices.size
+    qs = jax.ShapeDtypeStruct((3,), jnp.float32,
+                              sharding=NamedSharding(mesh, P()))
+    compiled = make_merged_flush(mesh, pspec).lower(
+        _state_shapes(pspec, sh, lead=(1, n)), qs).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < GIB, mem
+    assert "tpu_custom_call" in compiled.as_text()

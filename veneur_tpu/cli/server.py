@@ -35,6 +35,10 @@ def main(argv=None):
         print("config valid")
         return 0
 
+    # before the first dispatch: every start after the first finds its
+    # ingest and flush programs compiled (utils/compile_cache.py)
+    from veneur_tpu.utils import compile_cache
+    compile_cache.configure()
     from veneur_tpu.server.factory import new_from_config
     server = new_from_config(cfg)
     server.exit_on_quit = True  # /quitquitquit ends the daemon process

@@ -34,12 +34,7 @@ from veneur_tpu.ops import tdigest as td
 REPLICA_AXIS = "replica"
 SHARD_AXIS = "shard"
 
-# jax.shard_map went public after 0.4.x; older installs only have the
-# experimental location
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 
 def twofloat_axis_sum(hi, lo, acc, axis: str = REPLICA_AXIS):

@@ -73,15 +73,8 @@ def make_merged_state(mesh: Mesh, spec: TableSpec):
     def block(state: DeviceState):
         return merge_replica_block(state, spec, REPLICA_AXIS)
 
-    # replica-reduced outputs aren't replicated the way the checker
-    # wants; the kwarg that disables the check was renamed
-    # check_rep -> check_vma
-    try:
-        fn = shard_map(block, mesh=mesh,
-                       in_specs=(P(REPLICA_AXIS, SHARD_AXIS),),
-                       out_specs=P(SHARD_AXIS), check_vma=False)
-    except TypeError:
-        fn = shard_map(block, mesh=mesh,
-                       in_specs=(P(REPLICA_AXIS, SHARD_AXIS),),
-                       out_specs=P(SHARD_AXIS), check_rep=False)
+    # replica-reduced outputs aren't replicated the way the checker wants
+    fn = shard_map(block, mesh=mesh,
+                   in_specs=(P(REPLICA_AXIS, SHARD_AXIS),),
+                   out_specs=P(SHARD_AXIS), check_vma=False)
     return jax.jit(fn)

@@ -140,10 +140,10 @@ class Config:
     checkpoint_on_shutdown: bool = True    # final snapshot of the tail
 
     # device kernels (veneur_tpu/ops/pallas_ingest.py; README §Device
-    # kernels). True = probe-gated: the fused ingest kernel runs where
-    # the backend compiles it (TPU), the XLA scatter chain everywhere
-    # else (CPU tier-1 parity keeps the chain as the oracle). False
-    # forces the chain even on TPU.
+    # kernels). True = the backend rule: the fused ingest kernel runs on
+    # a TPU backend where its module constant says it compiles, the XLA
+    # scatter chain everywhere else (CPU tier-1 parity keeps the chain
+    # as the oracle). False forces the chain even on TPU.
     pallas_ingest_enabled: bool = True
 
     # observability (veneur_tpu/observability/). Both switches default

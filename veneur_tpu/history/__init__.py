@@ -10,8 +10,8 @@ queries (ROADMAP item 4; ISSUE 18).
                 the packed wire helpers
 
 The Pallas variant of the masked HLL window merge lives in
-ops/pallas_history.py behind the same probe gating as the digest
-kernel.
+ops/pallas_history.py, selected like the digest kernel: by the
+backend and a module constant.
 """
 
 from veneur_tpu.history.spec import HistorySpec

@@ -11,8 +11,8 @@ per kind —
     gauges / status            last-writer-wins via a recency-rank
                                argmax over finite selected columns
     sets                       masked 6-bit register max (the Pallas
-                               kernel in ops/pallas_history.py when its
-                               probe passes, the XLA fori chain
+                               kernel in ops/pallas_history.py on a
+                               TPU backend, the XLA fori chain
                                otherwise — bit-identical packed words)
     histos                     selected centroids re-compressed through
                                the ring's own k-cell compression, then
@@ -76,10 +76,10 @@ def _merge_windows_xla(rows, sel, *, precision: int):
 
 
 def merge_windows(rows, sel, *, precision: int):
-    """Masked window merge with the PR-8 gating pattern: Pallas kernel
-    when its one-time probe passes on a real TPU, XLA chain otherwise.
-    Both return identical packed words (integer max commutes with the
-    6-bit packing), asserted in tests via interpret mode."""
+    """Masked window merge: the Pallas kernel on a TPU backend, the XLA
+    chain otherwise. Both return identical packed words (integer max
+    commutes with the 6-bit packing), asserted in tests via interpret
+    mode."""
     from veneur_tpu.ops import pallas_history
     if pallas_history.enabled():
         return pallas_history.merge_windows_packed(rows, sel,

@@ -28,6 +28,7 @@
 #include <cmath>
 #include <condition_variable>
 #include <cstdint>
+#include <cstdio>
 #include <cstdlib>
 #include <cstring>
 #include <deque>
@@ -39,6 +40,7 @@
 #include <unordered_map>
 #include <vector>
 
+#include <poll.h>
 #include <pthread.h>
 #include <sched.h>
 #include <sys/socket.h>
@@ -1664,32 +1666,58 @@ bool admit_datagram2(Admission& a, TenantTable* tt, TenantEntry* te,
   return ok;
 }
 
-void reader_main(ReaderGroup* g, int fd, int max_len) {
-  constexpr int VLEN = 64;
-  std::vector<std::vector<char>> bufs(VLEN, std::vector<char>(max_len));
-  mmsghdr msgs[VLEN];
-  iovec iovs[VLEN];
-  // a receive timeout lets the thread observe the stop flag; fd is our
-  // own dup (vr_start), closed in vr_stop after this thread joins
-  struct timeval tv;
-  tv.tv_sec = 0;
-  tv.tv_usec = 200 * 1000;
-  setsockopt(fd, SOL_SOCKET, SO_RCVTIMEO, &tv, sizeof(tv));
-  while (!g->stop.load(std::memory_order_relaxed)) {
-    for (int i = 0; i < VLEN; i++) {
+// One batched read for a reader thread: wait until the socket is readable
+// — at most 200 ms, so the caller rechecks its stop flag — then take every
+// datagram already queued, up to vlen. poll + MSG_DONTWAIT, not recvmmsg's
+// MSG_WAITFORONE: sandboxed kernels (gVisor) refuse that flag with EINVAL,
+// and a reader that cannot read must not look like an idle one. Returns
+// the datagram count, 0 when there is nothing yet, -1 on a persistent
+// error, which is reported on stderr once per thread (`reported`).
+int recv_batch(int fd, mmsghdr* msgs, iovec* iovs,
+               std::vector<std::vector<char>>& bufs, int max_len,
+               const std::atomic<bool>& stop, bool* reported) {
+  pollfd p;
+  p.fd = fd;
+  p.events = POLLIN;
+  p.revents = 0;
+  int pr = poll(&p, 1, 200);
+  if (pr == 0 || (pr < 0 && errno == EINTR)) return 0;
+  int vlen = (int)bufs.size();
+  int n = -1;
+  if (pr > 0) {
+    for (int i = 0; i < vlen; i++) {
       iovs[i].iov_base = bufs[i].data();
       iovs[i].iov_len = (size_t)max_len;
       memset(&msgs[i], 0, sizeof(msgs[i]));
       msgs[i].msg_hdr.msg_iov = &iovs[i];
       msgs[i].msg_hdr.msg_iovlen = 1;
     }
-    int n = recvmmsg(fd, msgs, VLEN, MSG_WAITFORONE, nullptr);
+    n = recvmmsg(fd, msgs, vlen, MSG_DONTWAIT, nullptr);
+    if (n >= 0) return n;
+    if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR) return 0;
+  }
+  // EBADF here means shutdown closed the fd before this thread was
+  // joined: not worth a line
+  if (!*reported && !stop.load(std::memory_order_relaxed)) {
+    fprintf(stderr, "veneur_tpu native reader: fd %d cannot be read: %s\n",
+            fd, strerror(errno));
+    *reported = true;
+  }
+  return -1;
+}
+
+void reader_main(ReaderGroup* g, int fd, int max_len) {
+  constexpr int VLEN = 64;
+  std::vector<std::vector<char>> bufs(VLEN, std::vector<char>(max_len));
+  mmsghdr msgs[VLEN];
+  iovec iovs[VLEN];
+  bool reported = false;
+  // fd is our own dup (vr_start), closed in vr_stop after this thread joins
+  while (!g->stop.load(std::memory_order_relaxed)) {
+    int n = recv_batch(fd, msgs, iovs, bufs, max_len, g->stop, &reported);
     if (n <= 0) {
-      // rcvtimeo/EINTR: just recheck stop. A persistent error (EBADF —
-      // shutdown closed the fd before we were joined) must not busy-spin.
-      if (n < 0 && errno != EAGAIN && errno != EWOULDBLOCK &&
-          errno != EINTR)
-        std::this_thread::sleep_for(std::chrono::milliseconds(50));
+      // a persistent error must not busy-spin
+      if (n < 0) std::this_thread::sleep_for(std::chrono::milliseconds(50));
       continue;
     }
     {
@@ -2019,23 +2047,12 @@ void vrm_reader_main(MultiRing* mr, Ring* r) {
   std::vector<std::vector<char>> bufs(VLEN, std::vector<char>(r->max_len));
   mmsghdr msgs[VLEN];
   iovec iovs[VLEN];
-  struct timeval tv;
-  tv.tv_sec = 0;
-  tv.tv_usec = 200 * 1000;
-  setsockopt(r->fd, SOL_SOCKET, SO_RCVTIMEO, &tv, sizeof(tv));
+  bool reported = false;
   while (!mr->stop.load(std::memory_order_relaxed)) {
-    for (int i = 0; i < VLEN; i++) {
-      iovs[i].iov_base = bufs[i].data();
-      iovs[i].iov_len = (size_t)r->max_len;
-      memset(&msgs[i], 0, sizeof(msgs[i]));
-      msgs[i].msg_hdr.msg_iov = &iovs[i];
-      msgs[i].msg_hdr.msg_iovlen = 1;
-    }
-    int n = recvmmsg(r->fd, msgs, VLEN, MSG_WAITFORONE, nullptr);
+    int n = recv_batch(r->fd, msgs, iovs, bufs, r->max_len, mr->stop,
+                       &reported);
     if (n <= 0) {
-      if (n < 0 && errno != EAGAIN && errno != EWOULDBLOCK &&
-          errno != EINTR)
-        std::this_thread::sleep_for(std::chrono::milliseconds(50));
+      if (n < 0) std::this_thread::sleep_for(std::chrono::milliseconds(50));
       continue;
     }
     for (int i = 0; i < n; i++)

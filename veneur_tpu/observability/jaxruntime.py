@@ -26,48 +26,39 @@ the split everywhere else.
 
 from __future__ import annotations
 
-import logging
 import tempfile
 import threading
 import time
-
-log = logging.getLogger("veneur_tpu.observability.jax")
 
 _lock = threading.Lock()
 _installed = False
 _compiles_total = 0
 _compile_seconds_total = 0.0
 
-# substring match: the exact event path has varied across jax versions
-# (/jax/core/compile/backend_compile_duration today)
-_COMPILE_EVENT = "backend_compile_duration"
+# fires once per program handed to the backend compiler, persistent-cache
+# hits included (their duration is the cache read)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _on_duration(event: str, duration_secs: float, **_kw) -> None:
     global _compiles_total, _compile_seconds_total
-    if _COMPILE_EVENT not in event:
+    if event != _COMPILE_EVENT:
         return
     with _lock:
         _compiles_total += 1
         _compile_seconds_total += float(duration_secs)
 
 
-def install() -> bool:
+def install() -> None:
     """Register the compile listener once per process; safe to call from
-    every Server.__init__. Returns False when jax.monitoring is absent
-    (the accumulators then just stay 0)."""
+    every Server.__init__."""
     global _installed
     with _lock:
         if _installed:
-            return True
-        try:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception as e:
-            log.debug("jax.monitoring unavailable: %s", e)
-            return False
+            return
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _installed = True
-        return True
 
 
 def compiles_total() -> int:

@@ -86,6 +86,25 @@ def split_hash(hashes64, precision: int = DEFAULT_PRECISION):
     return reg, rho.astype(np.uint8)
 
 
+def _dedup_max(slot, reg, rho):
+    """Sort a batch by (slot, register) and reduce every run of equal
+    pairs to its max rho, so a scatter that follows has unique indices —
+    the fast path on TPU. Returns (slot, reg, rho_max, is_last) in sorted
+    order; `is_last` marks the one position of each run that carries the
+    update."""
+    order = jnp.lexsort((reg, slot))
+    ss = slot[order]
+    gs = reg[order]
+    same = (ss[:-1] == ss[1:]) & (gs[:-1] == gs[1:])
+    is_last = jnp.concatenate([~same, jnp.ones((1,), bool)])
+    seg_start = jnp.concatenate([jnp.ones((1,), bool), ~same])
+    seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
+    run_max = jax.ops.segment_max(rho[order].astype(jnp.int32), seg_id,
+                                  num_segments=slot.shape[0],
+                                  indices_are_sorted=True)
+    return ss, gs, run_max[seg_id], is_last
+
+
 @partial(jax.jit, static_argnames=("precision",))
 def insert_batch(registers, slot, reg, rho, *, precision: int = DEFAULT_PRECISION):
     """Scatter-max a batch of (slot, register, rho) into registers [K, R].
@@ -94,29 +113,16 @@ def insert_batch(registers, slot, reg, rho, *, precision: int = DEFAULT_PRECISIO
     reg:  i32[B] register index in [0, R),
     rho:  u8[B] rank value.
 
-    Dedup first (sort by flat index, segment-max) so the final scatter has
-    unique indices — the fast path on TPU.
+    Dedup first (`_dedup_max`) so the final scatter has unique indices.
     """
     k = registers.shape[0]
     # 2D scatter indices (slot, reg) — avoids int32 overflow of a flattened
     # slot*R+reg index for large key tables (K*R can exceed 2^31).
     slot = jnp.where((slot >= 0) & (slot < k), slot, k)
-    order = jnp.lexsort((reg, slot))
-    ss = slot[order]
-    gs = reg[order]
-    rs = rho[order]
-    same = (ss[:-1] == ss[1:]) & (gs[:-1] == gs[1:])
-    is_last = jnp.concatenate([~same, jnp.ones((1,), bool)])
-    # running max within runs of equal (slot, reg)
-    seg_start = jnp.concatenate([jnp.ones((1,), bool), ~same])
-    seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
-    run_max = jax.ops.segment_max(rs.astype(jnp.int32), seg_id,
-                                  num_segments=slot.shape[0],
-                                  indices_are_sorted=True)
+    ss, gs, run_max, is_last = _dedup_max(slot, reg, rho)
     upd_slot = jnp.where(is_last, ss, k)
-    upd_val = run_max[seg_id].astype(jnp.uint8)
-    return registers.at[upd_slot, gs].max(jnp.where(is_last, upd_val, 0),
-                                          mode="drop")
+    upd_val = jnp.where(is_last, run_max.astype(jnp.uint8), 0)
+    return registers.at[upd_slot, gs].max(upd_val, mode="drop")
 
 
 def merge(a, b):
@@ -250,13 +256,44 @@ def unpack_registers_np(words, precision: int = DEFAULT_PRECISION):
 @partial(jax.jit, static_argnames=("precision",))
 def insert_batch_packed(words, slot, reg, rho, *,
                         precision: int = DEFAULT_PRECISION):
-    """`insert_batch` over the packed table: unpack -> dense scatter-max ->
-    repack. The XLA fallback path when the fused Pallas kernel is off; the
-    round trip through the dense layout makes parity with `insert_batch`
-    true by construction (register max commutes with packing)."""
-    dense = unpack_registers(words, precision=precision)
-    dense = insert_batch(dense, slot, reg, rho, precision=precision)
-    return pack_registers(dense, precision=precision)
+    """`insert_batch` over the packed table, touching only the addressed
+    words: dedup (slot, reg) by sort + segment-max, gather each
+    register's one or two words, max the 6-bit field, and scatter-add
+    the per-word field deltas back. Work and temporaries are O(batch),
+    independent of the table size. Bit-identical to
+    unpack -> `insert_batch` -> pack (register max commutes with
+    packing; tests/test_hll.py pins it): fields are disjoint bit ranges,
+    so adding ((new - cur) << shift) in wrapping i32 arithmetic rewrites
+    exactly that field, and a word shared by several updated registers
+    just sums their deltas. Out-of-range slots or registers —
+    negative ones included — are dropped."""
+    k, w = words.shape[-2], words.shape[-1]
+    ok = ((slot >= 0) & (slot < k)
+          & (reg >= 0) & (reg < num_registers(precision)))
+    slot = jnp.where(ok, slot, k)
+    reg = jnp.where(ok, reg, 0)
+    ss, gs, run_max, is_last = _dedup_max(slot, reg, rho)
+
+    bit = gs * REGISTER_BITS
+    w0 = bit >> 5
+    sh = bit & 31
+    straddle = sh > 32 - REGISTER_BITS      # field continues in word w0+1
+    nlo = jnp.where(straddle, 32 - sh, 0)   # field bits held by word w0
+    sc = jnp.minimum(ss, k - 1)             # dropped rows gather junk,
+    #                                         never written back
+    w1 = jnp.minimum(w0 + 1, w - 1)
+    lo = words[sc, w0]
+    hi = words[sc, w1]
+    cur = (jax.lax.shift_right_logical(lo, sh)
+           | jnp.where(straddle, hi << nlo, 0)) & 0x3F
+    new = jnp.maximum(cur, run_max) & 0x3F
+    d_lo = (new - cur) << sh
+    d_hi = (new >> nlo) - (cur >> nlo)
+    tgt = jnp.where(is_last, ss, k)         # one update per (slot, reg)
+    tgt_hi = jnp.where(straddle, tgt, k)
+    return words.at[jnp.concatenate([tgt, tgt_hi]),
+                    jnp.concatenate([w0, w1])].add(
+        jnp.concatenate([d_lo, d_hi]), mode="drop")
 
 
 @partial(jax.jit, static_argnames=("precision",))

@@ -13,44 +13,47 @@ Shape of the kernel:
   index) — reusing `_histo_plan` verbatim for the digest lane so cell
   assignment math is shared, not duplicated — maps invalid slots to a
   2^30 sentinel, and computes per-grid-step window offsets with one
-  searchsorted per kind. The offsets ride as a scalar-prefetch operand
-  (`pltpu.PrefetchScalarGridSpec`), so block index maps and loop bounds
-  know them before the body runs.
-- A 1-D grid walks each kind's blocks in slot order; a kind with fewer
-  blocks than the grid clamps its index map (`min(g, blocks-1)`), which
-  under Pallas revisit semantics keeps its last block resident in VMEM
-  with no extra HBM traffic. Out blocks are copy-initialized from the
-  aliased inputs on first visit only (`@pl.when(g < blocks)` — the
+  searchsorted per leaf group. The offsets ride as a scalar-prefetch
+  operand (`pltpu.PrefetchScalarGridSpec`), so block index maps and
+  loop bounds know them before the body runs; the sorted streams ride
+  in SMEM, the one memory a scalar can be read from by a dynamic index.
+- A 1-D grid walks each group's blocks in slot order; a group with
+  fewer blocks than the grid clamps its index map (`min(g, blocks-1)`),
+  which under Pallas revisit semantics keeps its last block resident in
+  VMEM with no extra HBM traffic. Out blocks are copy-initialized from
+  the aliased inputs on first visit only (`@pl.when(g < blocks)` — the
   first visit of block b is exactly grid step b), then mutated by
-  sequential scalar read-modify-writes driven by
+  sequential read-modify-writes driven by
   `fori_loop(offs[k, g], offs[k, g + 1])`.
+- Mosaic has no scalar store to VMEM ("Cannot store scalars to VMEM"),
+  so every update is a read-modify-write of ONE (1, lanes) row under a
+  lane mask: the row is a dynamic sublane offset, the lane position
+  never is. Per-slot 1-D leaves are therefore viewed [K/128, 128] (a
+  free reshape) and tile on their own; the two u8 stamp leaves ride as
+  i32, since a single-row store of a packed dtype has no lowering
+  either.
 - Update order inside a window is ascending (slot, batch index), so per
-  slot the adds/sets land in batch order — exactly the order XLA
-  applies duplicate scatter updates — which is what makes the kernel
+  slot the adds/sets land in batch order. On CPU that is exactly the
+  order XLA applies duplicate scatter updates, which makes the kernel
   BYTE-identical to the scatter chain on every state leaf
-  (tests/test_pallas_ingest.py pins this in interpret mode).
+  (tests/test_pallas_ingest.py pins this in interpret mode). On a TPU,
+  XLA's scatter-add orders duplicates its own way: the two paths are
+  byte-identical wherever sums are exact, and an f32 sum of three or
+  more addends may differ in its last bits (chip_smoke.py holds both).
 - HLL registers update directly in the 6-bit packed words
-  (ops/hll.py §packed): a register's field is read with a
-  shift/mask, maxed with rho, and written back; a field straddling a
-  word boundary (in-word bit 28 or 30) patches the second word under
-  `@pl.when(straddle)`. Since 2^p % 16 == 0 a straddle never occurs at
-  a row's final word, so the second word always exists.
+  (ops/hll.py §packed): the field's one or two words are picked out of
+  the row with a lane mask, the field is maxed with rho, and the words
+  are put back under the same masks. Since 2^p % 16 == 0 a straddle
+  never occurs at a row's final word, so the second word always exists.
 
-Gating mirrors ops/pallas_digest.py: `enabled()` probes the backend in
-a bounded subprocess (any Mosaic lowering gap → XLA fallback, never a
-crash), `VENEUR_TPU_PALLAS_INGEST=1/0` force-overrides, and the
-`pallas_ingest_enabled` config key feeds `set_enabled` at server
-construction. On CPU the kernel runs in interpret mode (traced JAX
-ops) — correct everywhere, used by the parity suite; the production
-CPU path stays the XLA chain.
+Selection is `active()`: the `pallas_ingest_enabled` config key feeds
+`set_enabled` at server construction; otherwise a TPU backend plus the
+module constant `ENABLED` decide. On CPU the kernel runs in interpret
+mode (traced JAX ops) — correct everywhere, used by the parity suite;
+the production CPU path stays the XLA chain.
 """
 
 from __future__ import annotations
-
-import logging
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -59,33 +62,71 @@ from jax.experimental.pallas import tpu as pltpu
 
 from veneur_tpu.aggregation.state import DeviceState, TableSpec
 
-log = logging.getLogger(__name__)
-
 _BIG = 1 << 30   # sentinel slot for invalid rows: beyond every window
 
 
+_LANES = 128
+
+
+def _lanes(cap: int) -> int:
+    """Lane width L of a 1-D leaf's 2-D view [cap / L, L]: Mosaic has no
+    scalar store to VMEM, so a per-slot leaf is updated one (1, L) row
+    at a time under a lane mask, and wants the lane dim dense."""
+    return _LANES if cap % _LANES == 0 else cap
+
+
+def _tile_1d(cap: int, budget: int = 1 << 15) -> int:
+    """Slots per block of a 1-D leaf: the whole leaf when it fits the
+    budget (a block equal to the array is always legal), else the
+    budget — a multiple of 8 * 128, so a block is whole (8, 128)
+    tiles of the [cap / 128, 128] view."""
+    if cap <= budget:
+        return cap
+    if cap % _LANES:
+        raise ValueError(
+            f"fused ingest: a table capacity above {budget} must be a "
+            f"multiple of {_LANES}, got {cap}")
+    return budget
+
+
+def _row_tile(cap: int, budget: int) -> int:
+    """Rows per block of a 2-D leaf: the whole table when it fits the
+    budget, else the budget rounded down to the sublane tiling — Mosaic
+    refuses a block whose tiled dims are neither aligned nor the full
+    array dim. A ragged last block is legal, a whole one is preferred."""
+    if cap <= budget:
+        return cap
+    top = max(8, budget // 8 * 8)
+    for tile in range(top, 7, -8):      # prefer a tile that divides
+        if cap % tile == 0:
+            return tile
+    return top
+
+
 def _tiles(spec: TableSpec):
-    """Per-kind VMEM tile rows (counter, gauge, status, set, histo).
-    Budgeted so in+out blocks of every kind fit ~6MB total at the
-    default spec — half a core's VMEM, leaving room for the streams."""
-    tc = min(spec.counter_capacity, 1 << 15)
-    tg = min(spec.gauge_capacity, 1 << 15)
-    tst = min(spec.status_capacity, 1 << 15)
-    ts = max(1, min(spec.set_capacity, (1 << 18) // spec.hll_words))
-    th = max(1, min(spec.histo_capacity, (1 << 17) // spec.total_cells))
-    return tc, tg, tst, ts, th
+    """Per-group VMEM tile rows: counter, gauge, status, set, histo
+    cells (h_w / h_wm) and histo scalars (the six per-row leaves, which
+    tile on their own so that their blocks stay lane-dense). Budgeted
+    so in+out blocks of every group, double-buffered, fit ~10MB at the
+    default spec."""
+    return (_tile_1d(spec.counter_capacity), _tile_1d(spec.gauge_capacity),
+            _tile_1d(spec.status_capacity),
+            _row_tile(spec.set_capacity, (1 << 18) // spec.hll_words),
+            _row_tile(spec.histo_capacity, (1 << 17) // spec.total_cells),
+            _tile_1d(spec.histo_capacity))
 
 
 def _layout(spec: TableSpec):
     tiles = _tiles(spec)
     caps = (spec.counter_capacity, spec.gauge_capacity,
-            spec.status_capacity, spec.set_capacity, spec.histo_capacity)
+            spec.status_capacity, spec.set_capacity, spec.histo_capacity,
+            spec.histo_capacity)
     nblocks = tuple(-(-c // t) for c, t in zip(caps, tiles))
     return tiles, caps, nblocks, max(nblocks)
 
 
 def _pad1(a):
-    """A zero-length lane still needs a nonempty VMEM block; one sentinel
+    """A zero-length lane still needs a nonempty block; one sentinel
     row (slot == _BIG lands outside every window) keeps the BlockSpec
     legal without a second compiled variant."""
     if a.shape[0] > 0:
@@ -111,10 +152,11 @@ def _stream(slot, cap, *vals, extra_valid=None):
 
 
 def _offsets(skeys, tiles, g_total):
-    """i32[5, G+1] window offsets: row k, step g covers sorted positions
-    [offs[k, g], offs[k, g+1]) — the slots in [g*tile_k, (g+1)*tile_k).
-    Steps past a kind's last block get empty windows (every valid slot
-    is below blocks_k * tile_k); sentinel rows sit past offs[k, G]."""
+    """i32[groups, G+1] window offsets: row k, step g covers sorted
+    positions [offs[k, g], offs[k, g+1]) — the slots in
+    [g*tile_k, (g+1)*tile_k). Steps past a group's last block get empty
+    windows (every valid slot is below blocks_k * tile_k); sentinel rows
+    sit past offs[k, G]."""
     rows = []
     for sk, t in zip(skeys, tiles):
         bounds = jnp.arange(g_total + 1, dtype=jnp.int32) * t
@@ -128,12 +170,16 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
     """Drop-in replacement for ingest_core's scatter chain (everything
     except the optional histo_stat_* import lanes and the two-float
     fold, which stay in XLA around the kernel). Pure; safe under jit
-    and donation — state leaves alias the kernel outputs."""
+    and donation — state leaves alias the kernel outputs (the two u8
+    stamp leaves ride as i32 through the kernel: a single-row store of
+    a packed dtype has no TPU lowering)."""
     from veneur_tpu.aggregation.step import _histo_plan
 
-    tiles, _caps, nblocks, g_total = _layout(spec)
-    tc, tg, tst, ts, th = tiles
-    ncb, ngb, nstb, nsb, nhb = nblocks
+    tiles, caps, nblocks, g_total = _layout(spec)
+    tc, tg, tst, ts, th, ths = tiles
+    ncb, ngb, nstb, nsb, nhb, nhsb = nblocks
+    lc, lg, lst, lh = (_lanes(caps[0]), _lanes(caps[1]), _lanes(caps[2]),
+                       _lanes(caps[4]))
     w_words = spec.hll_words
     cells = spec.total_cells
 
@@ -161,7 +207,17 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
     h_wv = h_w * h_v
     h_rcp = jnp.where(h_w > 0, h_w / h_v, 0.0)
 
-    offs = _offsets([c_sk, g_sk, st_sk, s_sk, h_sk], tiles, g_total)
+    offs = _offsets([c_sk, g_sk, st_sk, s_sk, h_sk, h_sk], tiles, g_total)
+
+    def lane_iota(n):
+        return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+
+    def locate(l, tile, lanes):
+        """Block-local slot -> (row, lane) of the [tile / lanes, lanes]
+        view; a single-row block needs no division."""
+        if tile == lanes:
+            return 0, l
+        return l // lanes, l % lanes
 
     def kernel(offs_ref,
                counter_in, gauge_in, gstamp_in, status_in, ststamp_in,
@@ -176,7 +232,7 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
         g = pl.program_id(0)
 
         # copy-initialize out blocks from the aliased inputs on FIRST
-        # visit only: the clamped index maps revisit each kind's last
+        # visit only: the clamped index maps revisit each group's last
         # block, and re-copying would erase the resident RMW results
         for dst, src, nb in ((counter_out, counter_in, ncb),
                              (gauge_out, gauge_in, ngb),
@@ -186,48 +242,66 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
                              (hll_out, hll_in, nsb),
                              (hw_out, hw_in, nhb),
                              (hwm_out, hwm_in, nhb),
-                             (htn_out, htn_in, nhb),
-                             (hmin_out, hmin_in, nhb),
-                             (hmax_out, hmax_in, nhb),
-                             (hcnt_out, hcnt_in, nhb),
-                             (hsum_out, hsum_in, nhb),
-                             (hrcp_out, hrcp_in, nhb)):
+                             (htn_out, htn_in, nhsb),
+                             (hmin_out, hmin_in, nhsb),
+                             (hmax_out, hmax_in, nhsb),
+                             (hcnt_out, hcnt_in, nhsb),
+                             (hsum_out, hsum_in, nhsb),
+                             (hrcp_out, hrcp_in, nhsb)):
             @pl.when(g < nb)
             def _(dst=dst, src=src):
                 dst[...] = src[...]
 
+        # Every update is a read-modify-write of ONE (1, lanes) row under
+        # a lane mask: the row index is dynamic (a sublane offset), the
+        # lane position never is. Streams live in SMEM, where a scalar
+        # can be read by a dynamic index.
+        def update(ref, row, at, fn):
+            cur = ref[pl.ds(row, 1), :]
+            ref[pl.ds(row, 1), :] = jnp.where(at, fn(cur), cur)
+
         cbase = jnp.minimum(g, ncb - 1) * tc
+        c_lane = lane_iota(lc)
 
         def c_body(i, _):
-            counter_out[c_slot_s[i] - cbase] += c_inc_s[i]
+            row, lane = locate(c_slot_s[i] - cbase, tc, lc)
+            inc = c_inc_s[i]
+            update(counter_out, row, c_lane == lane, lambda x: x + inc)
             return 0
 
         jax.lax.fori_loop(offs_ref[0, g], offs_ref[0, g + 1], c_body, 0)
 
         gbase = jnp.minimum(g, ngb - 1) * tg
+        g_lane = lane_iota(lg)
 
         def g_body(i, _):
-            l = g_slot_s[i] - gbase
-            gauge_out[l] = g_val_s[i]
-            gstamp_out[l] = jnp.uint8(1)
+            row, lane = locate(g_slot_s[i] - gbase, tg, lg)
+            at = g_lane == lane
+            val = g_val_s[i]
+            update(gauge_out, row, at, lambda x: jnp.full_like(x, val))
+            update(gstamp_out, row, at, lambda x: jnp.ones_like(x))
             return 0
 
         jax.lax.fori_loop(offs_ref[1, g], offs_ref[1, g + 1], g_body, 0)
 
         stbase = jnp.minimum(g, nstb - 1) * tst
+        st_lane = lane_iota(lst)
 
         def st_body(i, _):
-            l = st_slot_s[i] - stbase
-            status_out[l] = st_val_s[i]
-            ststamp_out[l] = jnp.uint8(1)
+            row, lane = locate(st_slot_s[i] - stbase, tst, lst)
+            at = st_lane == lane
+            val = st_val_s[i]
+            update(status_out, row, at, lambda x: jnp.full_like(x, val))
+            update(ststamp_out, row, at, lambda x: jnp.ones_like(x))
             return 0
 
         jax.lax.fori_loop(offs_ref[2, g], offs_ref[2, g + 1], st_body, 0)
 
         sbase = jnp.minimum(g, nsb - 1) * ts
+        w_lane = lane_iota(w_words)
 
         def s_body(i, _):
-            l = s_slot_s[i] - sbase
+            row = s_slot_s[i] - sbase
             bit = 6 * s_reg_s[i]
             w0 = bit >> 5
             sh = bit & 31
@@ -235,71 +309,94 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
             nlo = jnp.where(straddle, 32 - sh, 6)
             nhi = 6 - nlo                     # 0 when the field fits
             mask_lo = (1 << nlo) - 1
-            lo = hll_out[l, w0]
-            w1 = jnp.where(straddle, w0 + 1, w0)  # guard: no OOB read
-            hi = hll_out[l, w1]
-            cur = ((lo >> sh) & mask_lo) | ((hi & ((1 << nhi) - 1)) << nlo)
+            mask_hi = (1 << nhi) - 1
+            w1 = jnp.where(straddle, w0 + 1, w0)
+            words = hll_out[pl.ds(row, 1), :]             # [1, W]
+            at0 = w_lane == w0
+            at1 = w_lane == w1
+            # the one or two words of the field, as [1, 1] (a masked
+            # sum with a single live lane is exact)
+            lo = jnp.sum(jnp.where(at0, words, 0), axis=1, keepdims=True)
+            hi = jnp.sum(jnp.where(at1, words, 0), axis=1, keepdims=True)
+            cur = ((lo >> sh) & mask_lo) | ((hi & mask_hi) << nlo)
             new = jnp.maximum(cur, s_rho_s[i])
-            hll_out[l, w0] = ((lo & ~(mask_lo << sh))
-                              | ((new & mask_lo) << sh))
-
-            @pl.when(straddle)
-            def _():
-                hll_out[l, w1] = (hi & ~((1 << nhi) - 1)) | (new >> nlo)
+            word0 = (lo & ~(mask_lo << sh)) | ((new & mask_lo) << sh)
+            word1 = (hi & ~mask_hi) | (new >> nlo)
+            words = jnp.where(at0, word0, words)
+            words = jnp.where(at1 & straddle, word1, words)
+            hll_out[pl.ds(row, 1), :] = words
             return 0
 
         jax.lax.fori_loop(offs_ref[3, g], offs_ref[3, g + 1], s_body, 0)
 
         hbase = jnp.minimum(g, nhb - 1) * th
+        cell_lane = lane_iota(cells)
 
         def h_body(i, _):
-            l = h_slot_s[i] - hbase
-            cell = h_cell_s[i]
-            v = h_v_s[i]
+            row = h_slot_s[i] - hbase
+            at = cell_lane == h_cell_s[i]
             w = h_w_s[i]
             wv = h_wv_s[i]
-            hw_out[l, cell] += w
-            hwm_out[l, cell] += wv
-            htn_out[l] += h_tadd_s[i]
-            hmin_out[l] = jnp.minimum(hmin_out[l],
-                                      jnp.where(w > 0, v, jnp.inf))
-            hmax_out[l] = jnp.maximum(hmax_out[l],
-                                      jnp.where(w > 0, v, -jnp.inf))
-            hcnt_out[l] += w
-            hsum_out[l] += wv
-            hrcp_out[l] += h_rcp_s[i]
+            update(hw_out, row, at, lambda x: x + w)
+            update(hwm_out, row, at, lambda x: x + wv)
             return 0
 
         jax.lax.fori_loop(offs_ref[4, g], offs_ref[4, g + 1], h_body, 0)
 
-    state_ins = (state.counter_acc, state.gauge, state.gauge_stamp,
-                 state.status, state.status_stamp, state.hll,
-                 state.h_w, state.h_wm, state.h_temp_n,
-                 state.h_min, state.h_max,
-                 state.h_count_acc, state.h_sum_acc, state.h_recip_acc)
+        hsbase = jnp.minimum(g, nhsb - 1) * ths
+        hs_lane = lane_iota(lh)
+
+        def hs_body(i, _):
+            row, lane = locate(h_slot_s[i] - hsbase, ths, lh)
+            at = hs_lane == lane
+            v = h_v_s[i]
+            w = h_w_s[i]
+            wv = h_wv_s[i]
+            rcp = h_rcp_s[i]
+            tadd = h_tadd_s[i]
+            lo = jnp.where(w > 0, v, jnp.inf)
+            hi = jnp.where(w > 0, v, -jnp.inf)
+            update(htn_out, row, at, lambda x: x + tadd)
+            update(hmin_out, row, at, lambda x: jnp.minimum(x, lo))
+            update(hmax_out, row, at, lambda x: jnp.maximum(x, hi))
+            update(hcnt_out, row, at, lambda x: x + w)
+            update(hsum_out, row, at, lambda x: x + wv)
+            update(hrcp_out, row, at, lambda x: x + rcp)
+            return 0
+
+        jax.lax.fori_loop(offs_ref[5, g], offs_ref[5, g + 1], hs_body, 0)
+
+    def view(a, lanes):
+        return a.reshape(a.shape[0] // lanes, lanes)
+
+    state_ins = (view(state.counter_acc, lc), view(state.gauge, lg),
+                 view(state.gauge_stamp.astype(jnp.int32), lg),
+                 view(state.status, lst),
+                 view(state.status_stamp.astype(jnp.int32), lst),
+                 state.hll, state.h_w, state.h_wm,
+                 view(state.h_temp_n, lh), view(state.h_min, lh),
+                 view(state.h_max, lh), view(state.h_count_acc, lh),
+                 view(state.h_sum_acc, lh), view(state.h_recip_acc, lh))
     streams = (c_sk, c_inc, g_sk, g_val, st_sk, st_val,
                s_sk, s_reg, s_rho,
                h_sk, h_cell, h_v, h_w, h_wv, h_rcp, h_tadd)
 
-    def spec1(tile, nb):
-        return pl.BlockSpec((tile,), lambda g, o, nb=nb: (jnp.minimum(g, nb - 1),))
+    def rows(tile, lanes, nb):     # a 1-D leaf's [tile / lanes, lanes] block
+        return pl.BlockSpec(
+            (tile // lanes, lanes),
+            lambda g, o, nb=nb: (jnp.minimum(g, nb - 1), 0))
 
-    def spec2(tile, ncols, nb):
+    def table(tile, ncols, nb):
         return pl.BlockSpec((tile, ncols),
                             lambda g, o, nb=nb: (jnp.minimum(g, nb - 1), 0))
 
-    def whole(n):
-        return pl.BlockSpec((n,), lambda g, o: (0,))
-
     state_specs = [
-        spec1(tc, ncb), spec1(tg, ngb), spec1(tg, ngb),
-        spec1(tst, nstb), spec1(tst, nstb),
-        spec2(ts, w_words, nsb),
-        spec2(th, cells, nhb), spec2(th, cells, nhb),
-        spec1(th, nhb), spec1(th, nhb), spec1(th, nhb),
-        spec1(th, nhb), spec1(th, nhb), spec1(th, nhb),
-    ]
-    stream_specs = [whole(a.shape[0]) for a in streams]
+        rows(tc, lc, ncb), rows(tg, lg, ngb), rows(tg, lg, ngb),
+        rows(tst, lst, nstb), rows(tst, lst, nstb),
+        table(ts, w_words, nsb),
+        table(th, cells, nhb), table(th, cells, nhb),
+    ] + [rows(ths, lh, nhsb)] * 6
+    stream_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] * len(streams)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -317,24 +414,31 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
         input_output_aliases={i + 1: i for i in range(len(state_ins))},
         interpret=interpret,
     )(offs, *state_ins, *streams)
+    flat = [o.reshape(-1) for o in outs]
     return state._replace(
-        counter_acc=outs[0], gauge=outs[1], gauge_stamp=outs[2],
-        status=outs[3], status_stamp=outs[4], hll=outs[5],
-        h_w=outs[6], h_wm=outs[7], h_temp_n=outs[8],
-        h_min=outs[9], h_max=outs[10],
-        h_count_acc=outs[11], h_sum_acc=outs[12], h_recip_acc=outs[13])
+        counter_acc=flat[0], gauge=flat[1],
+        gauge_stamp=flat[2].astype(jnp.uint8),
+        status=flat[3], status_stamp=flat[4].astype(jnp.uint8),
+        hll=outs[5], h_w=outs[6], h_wm=outs[7], h_temp_n=flat[8],
+        h_min=flat[9], h_max=flat[10],
+        h_count_acc=flat[11], h_sum_acc=flat[12], h_recip_acc=flat[13])
 
 
-# -- gating ------------------------------------------------------------------
+# -- selection ---------------------------------------------------------------
 
-_PROBE_RESULT = None
+# Module switch, see `active`. True: the kernel compiles for the v5e at
+# the default widths (tests/test_tpu_compile.py) and agrees with the XLA
+# chain on the chip (chip_smoke.py, kernels phase).
+ENABLED = True
+
 _OVERRIDE = None
 
 
 def set_enabled(value) -> None:
     """Config-level override wired from `pallas_ingest_enabled` at server
     construction: False forces the XLA chain, True forces the kernel
-    (interpret mode on CPU), None restores probe gating."""
+    (interpret mode on CPU — the parity suite's switch), None restores
+    the backend rule."""
     global _OVERRIDE
     _OVERRIDE = value
 
@@ -346,113 +450,10 @@ def interpret_mode() -> bool:
 
 
 def active() -> bool:
-    """Should ingest_core take the fused path right now?"""
+    """Should ingest_core take the fused path right now? The config
+    override first; otherwise the backend alone decides: on TPU the
+    kernel runs (a failure to compile or run raises — no fallback), on
+    CPU the XLA chain runs."""
     if _OVERRIDE is not None:
         return bool(_OVERRIDE)
-    return enabled()
-
-
-def enabled() -> bool:
-    """Probe-gated availability, mirroring pallas_digest.enabled():
-    VENEUR_TPU_PALLAS_INGEST=1/0 forces; CPU backend → False (the XLA
-    chain is faster than interpret mode); otherwise a bounded-subprocess
-    parity probe decides once per process."""
-    env = os.environ.get("VENEUR_TPU_PALLAS_INGEST", "")
-    if env == "1":
-        return True
-    if env == "0":
-        return False
-    if jax.default_backend() == "cpu":
-        return False
-    global _PROBE_RESULT
-    if _PROBE_RESULT is None:
-        try:
-            _PROBE_RESULT = _run_probe_bounded()
-        except Exception as exc:  # noqa: BLE001 - any probe failure = no
-            log.warning("pallas ingest probe failed; using XLA chain: %s",
-                        exc)
-            _PROBE_RESULT = False
-        if not _PROBE_RESULT:
-            log.warning("pallas ingest kernel unavailable on %s; "
-                        "falling back to the XLA scatter chain",
-                        jax.default_backend())
-    return _PROBE_RESULT
-
-
-def _probe_spec() -> TableSpec:
-    return TableSpec(counter_capacity=64, gauge_capacity=64,
-                     status_capacity=32, set_capacity=8,
-                     histo_capacity=32, hll_precision=6, temp_cells=16)
-
-
-def _probe_batch(spec: TableSpec):
-    import numpy as np
-    from veneur_tpu.aggregation.step import Batch
-    rng = np.random.default_rng(7)
-    n = 32
-
-    def slots(cap):
-        return jnp.asarray(rng.integers(0, cap + 2, n).astype(np.int32))
-
-    return Batch(
-        counter_slot=slots(spec.counter_capacity),
-        counter_inc=jnp.asarray(rng.normal(size=n).astype(np.float32)),
-        gauge_slot=slots(spec.gauge_capacity),
-        gauge_val=jnp.asarray(rng.normal(size=n).astype(np.float32)),
-        status_slot=slots(spec.status_capacity),
-        status_val=jnp.asarray(rng.normal(size=n).astype(np.float32)),
-        set_slot=slots(spec.set_capacity),
-        set_reg=jnp.asarray(
-            rng.integers(0, spec.registers, n).astype(np.int32)),
-        set_rho=jnp.asarray(rng.integers(0, 50, n).astype(np.uint8)),
-        histo_slot=slots(spec.histo_capacity),
-        histo_val=jnp.asarray(
-            rng.normal(size=n).astype(np.float32) + 2.0),
-        histo_wt=jnp.asarray(
-            rng.uniform(0.5, 2.0, n).astype(np.float32)),
-    )
-
-
-def _probe() -> bool:
-    """Compiled fused kernel vs the XLA chain on the live backend —
-    exact equality on every state leaf, in the production calling
-    context (inside jit)."""
-    import numpy as np
-    from functools import partial
-    from veneur_tpu.aggregation import step
-    from veneur_tpu.aggregation.state import empty_state
-
-    spec = _probe_spec()
-    batch = _probe_batch(spec)
-    ref = jax.jit(partial(step.ingest_core, spec=spec,
-                          allow_pallas=False))(empty_state(spec), batch)
-
-    def fused_core(state, batch):
-        state = fused_ingest_core(state, batch, spec=spec, interpret=False)
-        return step._fold_core(state)
-
-    fused = jax.jit(fused_core)(empty_state(spec), batch)
-    for a, b in zip(ref, fused):
-        if not np.array_equal(np.asarray(a), np.asarray(b), equal_nan=True):
-            return False
-    return True
-
-
-def _run_probe_bounded(budget_s: float = 60.0) -> bool:
-    """Run _probe in a subprocess with a hard wall-clock budget: a Mosaic
-    lowering bug or a wedged backend must degrade to the XLA chain, not
-    hang or kill the server (same containment as pallas_digest)."""
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    code = ("import sys; sys.path.insert(0, %r); "
-            "from veneur_tpu.ops.pallas_ingest import _probe; "
-            "print('PALLAS_INGEST_OK' if _probe() else 'PALLAS_INGEST_NO')"
-            % root)
-    try:
-        res = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True,
-                             timeout=budget_s)
-    except subprocess.TimeoutExpired:
-        log.warning("pallas ingest probe exceeded %.0fs budget", budget_s)
-        return False
-    return "PALLAS_INGEST_OK" in res.stdout
+    return ENABLED and jax.default_backend() == "tpu"

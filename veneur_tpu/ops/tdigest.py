@@ -286,11 +286,11 @@ def _quantiles_one(mean, weight, mn, mx, qs):
 
 
 def quantiles(table: TDigestTable, qs) -> jax.Array:
-    """Quantiles for every digest: returns f32[..., Q]. On a real TPU
+    """Quantiles for every digest: returns f32[..., Q]. On a TPU
     backend this routes to the fused Pallas kernel (sort + cumsum +
-    interpolation in one VMEM pass, ops/pallas_digest.py) when its probe
-    compile succeeds; the XLA vmap path is the portable fallback and the
-    parity oracle (tests/test_pallas_digest.py)."""
+    interpolation in one VMEM pass, ops/pallas_digest.py); the XLA vmap
+    path runs everywhere else and is the parity oracle
+    (tests/test_pallas_digest.py)."""
     qs = jnp.asarray(qs, jnp.float32)
     lead = table.mean.shape[:-1]
     c = table.mean.shape[-1]

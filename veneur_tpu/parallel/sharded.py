@@ -173,18 +173,10 @@ def make_merged_flush(mesh: Mesh, spec: TableSpec):
         out = jax.vmap(lambda st: flush_core(st, qs, spec=spec))(merged)
         return out
 
-    # replica-reduced outputs aren't replicated the way the checker wants;
-    # the kwarg that disables the check was renamed check_rep -> check_vma
-    try:
-        fn = _shard_map(
-            block, mesh=mesh,
-            in_specs=(P(REPLICA_AXIS, SHARD_AXIS), P()),
-            out_specs=P(SHARD_AXIS),
-            check_vma=False)
-    except TypeError:
-        fn = _shard_map(
-            block, mesh=mesh,
-            in_specs=(P(REPLICA_AXIS, SHARD_AXIS), P()),
-            out_specs=P(SHARD_AXIS),
-            check_rep=False)
+    # replica-reduced outputs aren't replicated the way the checker wants
+    fn = _shard_map(
+        block, mesh=mesh,
+        in_specs=(P(REPLICA_AXIS, SHARD_AXIS), P()),
+        out_specs=P(SHARD_AXIS),
+        check_vma=False)
     return jax.jit(fn)

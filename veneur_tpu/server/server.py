@@ -149,9 +149,10 @@ class Server:
         self.interval = cfg.parse_interval()
         self.hostname = cfg.hostname
         self.tags = list(cfg.tags)
-        # fused ingest kernel gate (ops/pallas_ingest.py): None restores
-        # probe gating (kernel on TPU, XLA chain on CPU), False forces
-        # the chain everywhere. Set before any aggregator compiles.
+        # fused ingest kernel switch (ops/pallas_ingest.py): None leaves
+        # the backend rule (on TPU the kernel, where its module constant
+        # says it compiles; XLA chain on CPU), False forces the chain
+        # everywhere. Set before any aggregator compiles.
         from veneur_tpu.ops import pallas_ingest
         pallas_ingest.set_enabled(
             None if cfg.pallas_ingest_enabled else False)
