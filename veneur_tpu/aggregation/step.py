@@ -198,34 +198,46 @@ def ingest_core(state: DeviceState, batch: Batch, *, spec: TableSpec,
     everywhere else, and the parity oracle."""
     from veneur_tpu.ops import pallas_ingest
     if allow_pallas and pallas_ingest.active():
-        state = pallas_ingest.fused_ingest_core(
-            state, batch, spec=spec,
-            interpret=pallas_ingest.interpret_mode())
+        with jax.named_scope("ingest.fused"):
+            state = pallas_ingest.fused_ingest_core(
+                state, batch, spec=spec,
+                interpret=pallas_ingest.interpret_mode())
     else:
-        counter_acc = state.counter_acc.at[batch.counter_slot].add(
-            batch.counter_inc, mode="drop")
-        gauge, gauge_stamp = _last_per_slot_set(
-            state.gauge, state.gauge_stamp, batch.gauge_slot,
-            batch.gauge_val, spec.gauge_capacity)
-        status, status_stamp = _last_per_slot_set(
-            state.status, state.status_stamp, batch.status_slot,
-            batch.status_val, spec.status_capacity)
-        hll = hll_ops.insert_batch_packed(
-            state.hll, batch.set_slot, batch.set_reg, batch.set_rho,
-            precision=spec.hll_precision)
+        # one named scope per kind: the profiler's device ops and the
+        # lowered program carry these names, which survive a refactor
+        # where HLO instruction numbers do not
+        with jax.named_scope("ingest.counter"):
+            counter_acc = state.counter_acc.at[batch.counter_slot].add(
+                batch.counter_inc, mode="drop")
+        with jax.named_scope("ingest.gauge"):
+            gauge, gauge_stamp = _last_per_slot_set(
+                state.gauge, state.gauge_stamp, batch.gauge_slot,
+                batch.gauge_val, spec.gauge_capacity)
+        with jax.named_scope("ingest.status"):
+            status, status_stamp = _last_per_slot_set(
+                state.status, state.status_stamp, batch.status_slot,
+                batch.status_val, spec.status_capacity)
+        with jax.named_scope("ingest.set"):
+            hll = hll_ops.insert_batch_packed(
+                state.hll, batch.set_slot, batch.set_reg, batch.set_rho,
+                precision=spec.hll_precision)
         state = state._replace(counter_acc=counter_acc,
                                gauge=gauge, gauge_stamp=gauge_stamp,
                                status=status, status_stamp=status_stamp,
                                hll=hll)
-        state = _histo_update(state, batch.histo_slot, batch.histo_val,
-                              batch.histo_wt, spec)
+        with jax.named_scope("ingest.histo"):
+            state = _histo_update(state, batch.histo_slot, batch.histo_val,
+                                  batch.histo_wt, spec)
     if batch.histo_stat_slot is not None:
         s = batch.histo_stat_slot
-        state = state._replace(
-            h_min=state.h_min.at[s].min(batch.histo_stat_min, mode="drop"),
-            h_max=state.h_max.at[s].max(batch.histo_stat_max, mode="drop"),
-            h_recip_acc=state.h_recip_acc.at[s].add(batch.histo_stat_recip,
-                                                    mode="drop"))
+        with jax.named_scope("ingest.histo_stat"):
+            state = state._replace(
+                h_min=state.h_min.at[s].min(batch.histo_stat_min,
+                                            mode="drop"),
+                h_max=state.h_max.at[s].max(batch.histo_stat_max,
+                                            mode="drop"),
+                h_recip_acc=state.h_recip_acc.at[s].add(
+                    batch.histo_stat_recip, mode="drop"))
     # Fold the batch's scatter accumulators into the two-float pairs
     # INSIDE the ingest program: XLA fuses the elementwise fold into the
     # scatter dispatch (no extra launch), the f32 accumulator never
@@ -233,7 +245,8 @@ def ingest_core(state: DeviceState, batch: Batch, *, spec: TableSpec,
     # error-free TwoSum — so counters match the reference's int64 for
     # any realistic interval (e.g. a lone :1|c arriving after 2^32 no
     # longer rounds away, which a 64-batch fold cadence allowed).
-    return _fold_core(state)
+    with jax.named_scope("fold"):
+        return _fold_core(state)
 
 
 ingest_step = partial(jax.jit, static_argnames=("spec", "allow_pallas"),
@@ -343,10 +356,18 @@ def packed_step_core(state: DeviceState, flat, *, spec: TableSpec,
     keeps the steady-state hot loop at ONE resident executable and one
     dispatch per batch. Shared by ingest_step_packed and the driver
     entry (__graft_entry__.entry)."""
-    state = ingest_core(state, unpack_batch(flat[1:], sizes), spec=spec)
-    return jax.lax.cond(flat[0] != 0,
-                        lambda s: compact_core(s, spec=spec),
-                        lambda s: s, state)
+    with jax.named_scope("unpack"):
+        batch = unpack_batch(flat[1:], sizes)
+    state = ingest_core(state, batch, spec=spec)
+    # Stable names where the profile had `%cond.8`, an HLO instruction
+    # number the next edit renumbers: the conditional itself is
+    # `maybe_compact`, the ops of its taken branch `compact`. (The other
+    # branch is the identity and has no op to name.)
+    with jax.named_scope("maybe_compact"):
+        return jax.lax.cond(
+            flat[0] != 0,
+            jax.named_scope("compact")(partial(compact_core, spec=spec)),
+            lambda s: s, state)
 
 
 ingest_step_packed = partial(
@@ -370,8 +391,9 @@ def packed_rings_core(state: DeviceState, arena, *, spec: TableSpec,
     n_rings = arena.shape[0]
     state = packed_step_core(state, arena[0], spec=spec, sizes=sizes)
     for r in range(1, n_rings):
-        state = ingest_core(state, unpack_batch(arena[r][1:], sizes),
-                            spec=spec)
+        with jax.named_scope("unpack"):
+            batch = unpack_batch(arena[r][1:], sizes)
+        state = ingest_core(state, batch, spec=spec)
     return state
 
 
@@ -448,14 +470,17 @@ def flush_core(state: DeviceState, qs: jax.Array, *, spec: TableSpec):
     # interval would flush as 2^32). The host combines them in float64
     # (combine_flush_scalars) — device f64 is unavailable without
     # jax_enable_x64.
-    hq, hmed = quantiles_with_median(table, qs)
+    with jax.named_scope("flush.quantiles"):
+        hq, hmed = quantiles_with_median(table, qs)
+    with jax.named_scope("flush.hll_estimate"):
+        set_estimate = hll_ops.estimate(state.hll,
+                                        precision=spec.hll_precision)
     return {
         "counter_hi": state.counter_hi,
         "counter_lo": state.counter_lo,
         "gauge": state.gauge,
         "status": state.status,
-        "set_estimate": hll_ops.estimate(state.hll,
-                                         precision=spec.hll_precision),
+        "set_estimate": set_estimate,
         "histo_quantiles": hq,
         "histo_min": state.h_min,
         "histo_max": state.h_max,
@@ -484,35 +509,39 @@ def flush_live_core(state: DeviceState, qs: jax.Array, cidx, gidx, stidx,
     O(capacity), and (b) only O(live) bytes cross the device→host
     boundary. Output arrays are indexed by POSITION: row i corresponds
     to table.get_meta(kind)[i]."""
-    wm = _take(state.h_wm, hidx)
-    w = _take(state.h_w, hidx)
-    mn = _take(state.h_min, hidx)
-    mx = _take(state.h_max, hidx)
-    chi, clo = _take(state.h_count_hi, hidx), _take(state.h_count_lo, hidx)
-    shi, slo = _take(state.h_sum_hi, hidx), _take(state.h_sum_lo, hidx)
-    rhi, rlo = _take(state.h_recip_hi, hidx), _take(state.h_recip_lo, hidx)
-    mean = wm / jnp.maximum(w, 1e-30)
-    table = td.TDigestTable(
-        mean=mean, weight=w, min=mn, max=mx,
-        count_hi=chi, count_lo=clo, sum_hi=shi, sum_lo=slo,
-        recip_hi=rhi, recip_lo=rlo)
-    hll_rows = _take(state.hll, setidx)
-    hq, hmed = quantiles_with_median(table, qs)
-    out = {
-        "counter_hi": _take(state.counter_hi, cidx),
-        "counter_lo": _take(state.counter_lo, cidx),
-        "gauge": _take(state.gauge, gidx),
-        "status": _take(state.status, stidx),
-        "set_estimate": hll_ops.estimate(hll_rows,
-                                         precision=spec.hll_precision),
-        "histo_quantiles": hq,
-        "histo_min": mn,
-        "histo_max": mx,
-        "histo_count_hi": chi, "histo_count_lo": clo,
-        "histo_sum_hi": shi, "histo_sum_lo": slo,
-        "histo_recip_hi": rhi, "histo_recip_lo": rlo,
-        "histo_median": hmed,
-    }
+    with jax.named_scope("flush.gather"):
+        wm = _take(state.h_wm, hidx)
+        w = _take(state.h_w, hidx)
+        mn = _take(state.h_min, hidx)
+        mx = _take(state.h_max, hidx)
+        chi = _take(state.h_count_hi, hidx)
+        clo = _take(state.h_count_lo, hidx)
+        shi, slo = _take(state.h_sum_hi, hidx), _take(state.h_sum_lo, hidx)
+        rhi = _take(state.h_recip_hi, hidx)
+        rlo = _take(state.h_recip_lo, hidx)
+        hll_rows = _take(state.hll, setidx)
+        out = {
+            "counter_hi": _take(state.counter_hi, cidx),
+            "counter_lo": _take(state.counter_lo, cidx),
+            "gauge": _take(state.gauge, gidx),
+            "status": _take(state.status, stidx),
+            "histo_min": mn,
+            "histo_max": mx,
+            "histo_count_hi": chi, "histo_count_lo": clo,
+            "histo_sum_hi": shi, "histo_sum_lo": slo,
+            "histo_recip_hi": rhi, "histo_recip_lo": rlo,
+        }
+    with jax.named_scope("flush.quantiles"):
+        mean = wm / jnp.maximum(w, 1e-30)
+        table = td.TDigestTable(
+            mean=mean, weight=w, min=mn, max=mx,
+            count_hi=chi, count_lo=clo, sum_hi=shi, sum_lo=slo,
+            recip_hi=rhi, recip_lo=rlo)
+        out["histo_quantiles"], out["histo_median"] = quantiles_with_median(
+            table, qs)
+    with jax.named_scope("flush.hll_estimate"):
+        out["set_estimate"] = hll_ops.estimate(
+            hll_rows, precision=spec.hll_precision)
     if want_raw:
         # forwarding needs the mergeable sketch state of live rows
         out["raw_hll"] = hll_rows
@@ -598,7 +627,8 @@ def _flush_live_in_packed_core(state, flat, *, spec, n_q: int,
         idx.append(flat[off:off + n])
         off += n
     out = flush_live_core(state, qs, *idx, spec=spec, want_raw=want_raw)
-    return _pack_outputs(out)
+    with jax.named_scope("flush.pack"):
+        return _pack_outputs(out)
 
 
 flush_live_in_packed = partial(
@@ -648,7 +678,8 @@ def _flush_live_hist_packed_core(state, flat, hist, hflat, *, spec,
                                  hspec=hspec, clear=clear)
     if not want_raw:
         out = {k: v for k, v in out.items() if not k.startswith("raw_")}
-    return _pack_outputs(out), new_hist
+    with jax.named_scope("flush.pack"):
+        return _pack_outputs(out), new_hist
 
 
 flush_live_hist_packed = partial(

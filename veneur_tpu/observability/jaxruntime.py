@@ -19,9 +19,11 @@ This module is also the ONE sanctioned device-sync site: XLA dispatch is
 async, so `perf_counter_ns` around a bare step call measures dispatch
 latency, not device time. sync_and_time() times a block_until_ready on
 the result token; aggregators sample it every N steps (and at every
-swap) so `step_ns` means what it says while `dispatch_ns` keeps the
-cheap always-on host-side number. The vtlint timer-sync pass enforces
-the split everywhere else.
+swap) into `step_ns` while `dispatch_ns` keeps the cheap always-on
+host-side number. The token is ready only when everything queued ahead
+of it has run, so with a backlog on the device a sample reads the
+queue's drain, not one step (PERF.md §5; the profile has per-step device
+time). The vtlint timer-sync pass enforces the split everywhere else.
 """
 
 from __future__ import annotations
@@ -78,8 +80,9 @@ def sync_and_time(token) -> int:
     device arrays) is actually ready. XLA dispatch is async, so timing a
     bare step call measures host-side dispatch, not device work; this is
     the ONE production sync point — aggregators sample it every
-    _SYNC_EVERY steps and at swap(), keeping `step_ns` honest while
-    `dispatch_ns` stays the cheap per-step number."""
+    _SYNC_EVERY steps and at swap() into `step_ns` (the wait covers every
+    step still queued ahead of the token), while `dispatch_ns` stays the
+    cheap per-step number."""
     import jax
     t0 = time.perf_counter_ns()
     # the sanctioned sampled sync point: callers time device completion

@@ -220,6 +220,14 @@ class Timer:
                 out.append((key, TimerStat(st.count, st.sum, quantiles)))
         return out
 
+    def totals(self) -> Dict[LabelValues, Tuple[int, float]]:
+        """{label values: (exact count, exact sum)} without folding:
+        unlike snapshot() it starts no device program, so any thread may
+        call it at any time (a benchmark inside its measured window)."""
+        with self._lock:
+            return {key: (st.count, st.sum)
+                    for key, st in self._states.items()}
+
     # collect-protocol alias so families iterate uniformly
     def samples(self) -> List[Tuple[LabelValues, TimerStat]]:
         return self.snapshot()

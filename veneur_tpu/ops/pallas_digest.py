@@ -194,6 +194,7 @@ def quantiles_rows(mean, weight, mn, mx, qs, *, interpret: bool = False):
         out_specs=pl.BlockSpec((row_tile, n_q), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r_pad, n_q), jnp.float32),
         interpret=interpret,
+        name="digest_quantiles",
     )(jnp.asarray(qs, jnp.float32), mean, weight,
       mn.reshape(-1, 1), mx.reshape(-1, 1))
     return out[:r]

@@ -114,6 +114,7 @@ def merge_windows_packed(rows, sel, *, precision: int,
         ),
         out_shape=jax.ShapeDtypeStruct((s, n_pad, nw), jnp.int32),
         interpret=interpret,
+        name="history_hll_merge",
     # column-major [W, N, nw]: a block's last two dims must be a whole
     # (8, 128)-tiled slab, so the squeezed column dim has to lead
     )((sel > 0.0).astype(jnp.int32), rows.transpose(1, 0, 2))

@@ -183,20 +183,27 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
     w_words = spec.hll_words
     cells = spec.total_cells
 
-    c_sk, (c_inc,) = _stream(batch.counter_slot, spec.counter_capacity,
-                             batch.counter_inc)
-    g_sk, (g_val,) = _stream(batch.gauge_slot, spec.gauge_capacity,
-                             batch.gauge_val)
-    st_sk, (st_val,) = _stream(batch.status_slot, spec.status_capacity,
-                               batch.status_val)
+    # the XLA chain's scope names (step.ingest_core) on each kind's
+    # stream preparation; the kernel itself is `fused_ingest`
+    with jax.named_scope("ingest.counter"):
+        c_sk, (c_inc,) = _stream(batch.counter_slot, spec.counter_capacity,
+                                 batch.counter_inc)
+    with jax.named_scope("ingest.gauge"):
+        g_sk, (g_val,) = _stream(batch.gauge_slot, spec.gauge_capacity,
+                                 batch.gauge_val)
+    with jax.named_scope("ingest.status"):
+        st_sk, (st_val,) = _stream(batch.status_slot, spec.status_capacity,
+                                   batch.status_val)
     # the dense scatter drops out-of-range register indices too (2-D
     # scatter, mode="drop") — mirror that in the stream validity
-    reg_ok = (batch.set_reg >= 0) & (batch.set_reg < spec.registers)
-    s_sk, (s_reg, s_rho) = _stream(
-        batch.set_slot, spec.set_capacity, batch.set_reg,
-        batch.set_rho.astype(jnp.int32), extra_valid=reg_ok)
-    hs, h_cell, h_v, h_w, h_tadd = _histo_plan(
-        state, batch.histo_slot, batch.histo_val, batch.histo_wt, spec)
+    with jax.named_scope("ingest.set"):
+        reg_ok = (batch.set_reg >= 0) & (batch.set_reg < spec.registers)
+        s_sk, (s_reg, s_rho) = _stream(
+            batch.set_slot, spec.set_capacity, batch.set_reg,
+            batch.set_rho.astype(jnp.int32), extra_valid=reg_ok)
+    with jax.named_scope("ingest.histo"):
+        hs, h_cell, h_v, h_w, h_tadd = _histo_plan(
+            state, batch.histo_slot, batch.histo_val, batch.histo_wt, spec)
     # _histo_plan already sorted by (slot, value) with invalid rows at
     # slot == histo_capacity; only the sentinel remap is needed, and the
     # kernel consumes the EXACT arrays the scatter chain would.
@@ -413,6 +420,7 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
         # operand i+1, aliased in place onto output i
         input_output_aliases={i + 1: i for i in range(len(state_ins))},
         interpret=interpret,
+        name="fused_ingest",
     )(offs, *state_ins, *streams)
     flat = [o.reshape(-1) for o in outs]
     return state._replace(

@@ -110,8 +110,12 @@ def make_sharded_ingest(mesh: Mesh, spec: TableSpec):
     scatters stay on its own device — zero communication."""
     core = partial(ingest_core, spec=spec, allow_pallas=False)
     vv = jax.vmap(jax.vmap(core))
+
+    def sharded_ingest(state, batch):
+        return vv(state, batch)
+
     fn = _shard_map(
-        vv, mesh=mesh,
+        sharded_ingest, mesh=mesh,
         in_specs=(P(REPLICA_AXIS, SHARD_AXIS), P(REPLICA_AXIS, SHARD_AXIS)),
         out_specs=P(REPLICA_AXIS, SHARD_AXIS))
     return jax.jit(fn, donate_argnums=(0,))
@@ -135,19 +139,24 @@ def make_sharded_ingest_packed(mesh: Mesh, spec: TableSpec, sizes: tuple):
     def tile_ingest(state, flat):
         # allow_pallas=False: the tile body runs under two vmaps, where
         # the fused kernel's scalar-prefetch grid does not apply
-        return ingest_core(state, unpack_batch(flat[1:], sizes),
-                           spec=spec, allow_pallas=False)
+        with jax.named_scope("unpack"):
+            batch = unpack_batch(flat[1:], sizes)
+        return ingest_core(state, batch, spec=spec, allow_pallas=False)
 
     vv_ingest = jax.vmap(jax.vmap(tile_ingest))
-    vv_compact = jax.vmap(jax.vmap(partial(compact_core, spec=spec)))
+    vv_compact = jax.named_scope("compact")(
+        jax.vmap(jax.vmap(partial(compact_core, spec=spec))))
 
-    def block(state, flat):
+    # the inner function's name is the program's: `jit_sharded_packed_step`
+    # on the profiler's XLA Modules line, apart from the flush's
+    def sharded_packed_step(state, flat):
         st = vv_ingest(state, flat)
         do_compact = flat[0, 0, 0] != 0   # scalar: cond stays a branch
-        return jax.lax.cond(do_compact, vv_compact, lambda s: s, st)
+        with jax.named_scope("maybe_compact"):
+            return jax.lax.cond(do_compact, vv_compact, lambda s: s, st)
 
     fn = _shard_map(
-        block, mesh=mesh,
+        sharded_packed_step, mesh=mesh,
         in_specs=(P(REPLICA_AXIS, SHARD_AXIS), P(REPLICA_AXIS, SHARD_AXIS)),
         out_specs=P(REPLICA_AXIS, SHARD_AXIS))
     return jax.jit(fn, donate_argnums=(0,))
@@ -166,16 +175,17 @@ def make_merged_flush(mesh: Mesh, spec: TableSpec):
     reference's global-tier import (SURVEY §3.4) as one collective program;
     the flush math is flush_core per shard."""
 
-    def block(state: DeviceState, qs):
+    def sharded_merged_flush(state: DeviceState, qs):
         # _merge_replica_block already re-compresses digests to canonical
         # cells; no separate compact pass needed before the flush math.
-        merged = _merge_replica_block(state, spec)
+        with jax.named_scope("flush.replica_merge"):
+            merged = _merge_replica_block(state, spec)
         out = jax.vmap(lambda st: flush_core(st, qs, spec=spec))(merged)
         return out
 
     # replica-reduced outputs aren't replicated the way the checker wants
     fn = _shard_map(
-        block, mesh=mesh,
+        sharded_merged_flush, mesh=mesh,
         in_specs=(P(REPLICA_AXIS, SHARD_AXIS), P()),
         out_specs=P(SHARD_AXIS),
         check_vma=False)

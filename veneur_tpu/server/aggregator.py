@@ -19,7 +19,7 @@ from veneur_tpu.aggregation.host import Batcher, BatchSpec, KeyTable
 from veneur_tpu.aggregation.state import (TableSpec, empty_state_compiled)
 from veneur_tpu.aggregation.step import (
     batch_sizes, ingest_step_packed, pack_batch)
-from veneur_tpu.observability import jaxruntime
+from veneur_tpu.observability import hostspans, jaxruntime
 from veneur_tpu.samplers.parser import UDPMetric
 from veneur_tpu.utils.hashing import fnv1a_64, splitmix64
 
@@ -69,12 +69,14 @@ class Aggregator:
         self.dropped_capacity = 0
         self.h2d_bytes = 0  # packed ingest bytes shipped to the device
         # device-step accounting for /metrics (observability/):
-        # dispatch_ns is host-side dispatch wall time (XLA execution is
-        # async, so this is NOT device time); step_ns is the honest
-        # synced number, sampled every _SYNC_EVERY steps and at swap()
-        # via jaxruntime.sync_and_time. steps_total is monotonic
-        # (_steps resets every swap); steps_synced counts the samples
-        # behind step_ns.
+        # dispatch_ns is host time inside the jitted call (XLA execution
+        # is async, so this is NOT device time: enqueue cost, plus the
+        # wait for a slot once the device queue is full); step_ns sums
+        # the sampled syncs, every _SYNC_EVERY steps and at swap() via
+        # jaxruntime.sync_and_time: each waits for EVERYTHING queued, so
+        # it reads the queue's drain, not one step (_dispatch_step).
+        # steps_total is monotonic (_steps resets every swap);
+        # steps_synced counts the samples behind step_ns.
         self.step_ns = 0
         self.dispatch_ns = 0
         self.steps_total = 0
@@ -180,16 +182,28 @@ class Aggregator:
         bufs[2] ^= 1
         pack_batch(batch, self._steps % self.compact_every == 0, out=flat)
         self.h2d_bytes += flat.nbytes
-        t0 = time.perf_counter_ns()
-        self.state = ingest_step_packed(
-            self.state, flat, spec=self.spec, sizes=sizes)
-        dispatch_dt = time.perf_counter_ns() - t0
+        self._dispatch_step(ingest_step_packed, flat, spec=self.spec,
+                            sizes=sizes)
+
+    def _dispatch_step(self, step, flat, **static) -> None:
+        """The one ingest dispatch every backend's step site goes through
+        (here, the native packed and ring emits, the sharded row):
+        `self.state = step(self.state, flat, **static)` under the
+        `pipeline.dispatch` span, its host time summed into dispatch_ns
+        (a full device queue blocks inside the call, so this is queue
+        wait as much as enqueue), and every _SYNC_EVERY-th step the
+        sampled sync under `pipeline.sampled_sync`. That sync waits for
+        everything queued, so step_ns reads the queue's drain, not one
+        step's device time."""
+        with hostspans.span("pipeline.dispatch"):
+            t0 = time.perf_counter_ns()
+            self.state = step(self.state, flat, **static)
+            dispatch_dt = time.perf_counter_ns() - t0
         self.dispatch_ns += dispatch_dt
         if self.steps_total % _SYNC_EVERY == 0:
-            # sampled sync: dispatch + wait-until-ready = true step wall
-            # time (covers the queued tail, which is the point)
-            self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
-                self.state)
+            with hostspans.span("pipeline.sampled_sync"):
+                self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
+                    self.state)
             self.steps_synced += 1
 
     def process_metric(self, m: UDPMetric) -> None:
@@ -408,21 +422,25 @@ class Aggregator:
         This is the ONLY flush work that must run on the pipeline thread;
         everything downstream operates on the detached (immutable) interval
         and can run on a flush thread while new samples accumulate."""
-        self.batcher.emit()
-        while self._hll_slots:
-            self._flush_hll_imports()
+        with hostspans.span("swap.emit_staged"):
+            self.batcher.emit()
+            while self._hll_slots:
+                self._flush_hll_imports()
         if self._steps:
             # interval boundary sync: step_ns is never 0 after a flush
-            # that ingested, even when _SYNC_EVERY never fired
-            self.step_ns += jaxruntime.sync_and_time(self.state)
+            # that ingested, even when _SYNC_EVERY never fired. The swap
+            # waits here for every step still queued on the device.
+            with hostspans.span("swap.device_wait"):
+                self.step_ns += jaxruntime.sync_and_time(self.state)
             self.steps_synced += 1
-        state, table = self.state, self.table
-        self.state = empty_state_compiled(self.spec)
-        self.table = KeyTable(self.spec, self.n_shards)
-        if self._pressure is not None:
-            self._pressure.attach(self.table)
-        self._steps = 0
-        self._latch_degrade()
+        with hostspans.span("swap.reset"):
+            state, table = self.state, self.table
+            self.state = empty_state_compiled(self.spec)
+            self.table = KeyTable(self.spec, self.n_shards)
+            if self._pressure is not None:
+                self._pressure.attach(self.table)
+            self._steps = 0
+            self._latch_degrade()
         return state, table
 
     # -- query tier ---------------------------------------------------------
@@ -503,37 +521,43 @@ class Aggregator:
         # small-table case: same shapes as the old single-shot path. All
         # blocks are dispatched before any is materialized, so the
         # device pipelines them.
-        if history is not None:
-            from veneur_tpu.history.writer import SENTINEL
-            plan = history.plan_flush(table)
-            hist = history.begin_flush(plan)
-            try:
-                packs = []
-                for i in range(n_blocks):
-                    hflat = np.concatenate(
-                        pack_bucket_chunks(plan.dests, buckets, i,
-                                           fill=SENTINEL)
-                        + [np.asarray([plan.col], np.int32)])
-                    p, hist = flush_live_hist_packed(
+        with hostspans.span("flush_dispatch"):
+            if history is not None:
+                from veneur_tpu.history.writer import SENTINEL
+                plan = history.plan_flush(table)
+                hist = history.begin_flush(plan)
+                try:
+                    packs = []
+                    for i in range(n_blocks):
+                        hflat = np.concatenate(
+                            pack_bucket_chunks(plan.dests, buckets, i,
+                                               fill=SENTINEL)
+                            + [np.asarray([plan.col], np.int32)])
+                        p, hist = flush_live_hist_packed(
+                            state, pack_flush_inputs(
+                                perc,
+                                pack_bucket_chunks(slots, buckets, i)),
+                            hist, hflat, spec=spec, hspec=history.spec,
+                            n_q=len(perc), buckets=buckets,
+                            want_raw=want_raw, clear=(i == 0))
+                        packs.append(p)
+                except BaseException:
+                    history.abort_flush()
+                    raise
+                history.commit_flush(plan, hist)
+            else:
+                packs = [
+                    flush_live_in_packed(
                         state, pack_flush_inputs(
                             perc, pack_bucket_chunks(slots, buckets, i)),
-                        hist, hflat, spec=spec, hspec=history.spec,
-                        n_q=len(perc), buckets=buckets,
-                        want_raw=want_raw, clear=(i == 0))
-                    packs.append(p)
-            except BaseException:
-                history.abort_flush()
-                raise
-            history.commit_flush(plan, hist)
-        else:
-            packs = [
-                flush_live_in_packed(
-                    state, pack_flush_inputs(
-                        perc, pack_bucket_chunks(slots, buckets, i)),
-                    spec=spec, n_q=len(perc), buckets=buckets,
-                    want_raw=want_raw)
-                for i in range(n_blocks)]
-        pieces = [unpack_flush(np.asarray(p), shapes) for p in packs]
+                        spec=spec, n_q=len(perc), buckets=buckets,
+                        want_raw=want_raw)
+                    for i in range(n_blocks)]
+        # the host's wait for the flush program, which queues on the
+        # device behind every ingest step dispatched since the swap,
+        # plus the transfer
+        with hostspans.span("flush_d2h"):
+            pieces = [unpack_flush(np.asarray(p), shapes) for p in packs]
         out = {}
         for key, kind_i in ((k, FLUSH_KEY_KIND[k]) for k in pieces[0]):
             b, n = buckets[kind_i], lens[kind_i]

@@ -36,6 +36,7 @@ Known imprecisions, documented:
 from __future__ import annotations
 
 import logging
+import time
 from typing import List
 
 import numpy as np
@@ -43,7 +44,10 @@ import numpy as np
 from veneur_tpu.aggregation.host import (
     Batcher, BatchSpec, KeyTable, SlotMeta, _KindTable)
 from veneur_tpu.aggregation.state import TableSpec
+from veneur_tpu.aggregation.step import (
+    ingest_step_packed, ingest_step_packed_rings)
 from veneur_tpu.native import NativeIngest
+from veneur_tpu.observability import hostspans
 from veneur_tpu.server.aggregator import Aggregator
 from veneur_tpu.server.sharded_aggregator import ShardedAggregator
 
@@ -255,15 +259,11 @@ class NativeAggregator(Aggregator):
         return self.eng.drain_specials()
 
     def _emit_native(self):
-        import time
-
-        from veneur_tpu.aggregation.step import ingest_step_packed
-        from veneur_tpu.observability import jaxruntime
-        from veneur_tpu.server.aggregator import _SYNC_EVERY
         idx = self._pk_idx
         flat = self._pk_bufs[idx]
-        nc, ng, ns, nh = self.eng.emit_packed(flat, self._pk_offs,
-                                              self._pk_prev[idx])
+        with hostspans.span("pipeline.emit"):
+            nc, ng, ns, nh = self.eng.emit_packed(flat, self._pk_offs,
+                                                  self._pk_prev[idx])
         if nc + ng + ns + nh == 0:
             return
         self._pk_idx = 1 - idx
@@ -271,15 +271,8 @@ class NativeAggregator(Aggregator):
         self.steps_total += 1
         flat[0] = 1 if self._steps % self.compact_every == 0 else 0
         self.h2d_bytes += flat.nbytes
-        t0 = time.perf_counter_ns()
-        self.state = ingest_step_packed(
-            self.state, flat, spec=self.spec, sizes=self._pk_sizes)
-        dispatch_dt = time.perf_counter_ns() - t0
-        self.dispatch_ns += dispatch_dt
-        if self.steps_total % _SYNC_EVERY == 0:
-            self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
-                self.state)
-            self.steps_synced += 1
+        self._dispatch_step(ingest_step_packed, flat, spec=self.spec,
+                            sizes=self._pk_sizes)
 
     def extra_parse_errors(self) -> int:
         return self.eng.stats()["parse_errors"]
@@ -369,35 +362,27 @@ class NativeAggregator(Aggregator):
         run ONE device step over the whole arena. Returns False (no step)
         when all rings were empty — the common idle poll. The compact
         control word rides row 0 only."""
-        import time
-
-        from veneur_tpu.aggregation.step import ingest_step_packed_rings
-        from veneur_tpu.observability import jaxruntime
-        from veneur_tpu.server.aggregator import _SYNC_EVERY
         idx = self._rg_idx
         arena = self._rg_bufs[idx]
         prev = self._rg_prev[idx]
         total = 0
+        t0 = time.monotonic_ns()
         for r in range(self.eng.n_rings):
             counts = self.eng.rings_emit(r, arena[r], self._pk_offs,
                                          prev[r])
             total += counts[0] + counts[1] + counts[2] + counts[3]
         if total == 0:
             return False
+        # stamped after the fact: an empty poll (one per pump call, far
+        # more often than a step) must leave no record
+        hostspans.record("pipeline.emit", t0, time.monotonic_ns())
         self._rg_idx = 1 - idx
         self._steps += 1
         self.steps_total += 1
         arena[0, 0] = 1 if self._steps % self.compact_every == 0 else 0
         self.h2d_bytes += arena.nbytes
-        t0 = time.perf_counter_ns()
-        self.state = ingest_step_packed_rings(
-            self.state, arena, spec=self.spec, sizes=self._pk_sizes)
-        dispatch_dt = time.perf_counter_ns() - t0
-        self.dispatch_ns += dispatch_dt
-        if self.steps_total % _SYNC_EVERY == 0:
-            self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
-                self.state)
-            self.steps_synced += 1
+        self._dispatch_step(ingest_step_packed_rings, arena, spec=self.spec,
+                            sizes=self._pk_sizes)
         return True
 
     def pump(self, max_wait_ms: int, max_emits: int = 8) -> List[bytes]:
@@ -408,21 +393,35 @@ class NativeAggregator(Aggregator):
         packet_queue) would starve — exactly when operators most need the
         flush. Returns escalated event/service-check lines."""
         if self.eng.n_rings:
-            self.eng.rings_wait(max_wait_ms)
+            self._pump(max_wait_ms)
             for _ in range(max_emits):
                 if not self._emit_rings():
                     break
             return self.eng.drain_specials()
-        full, st = self.eng.pump(max_wait_ms)
+        full = self._pump(max_wait_ms)
         for _ in range(max_emits):
             if not full:
                 break
             self._emit_native()
-            full, st = self.eng.pump(0)
+            full = self._pump(0)
         if full:
             # leave staging drained so the next call ingests immediately
             self._emit_native()
         return self.eng.drain_specials()
+
+    def _pump(self, max_wait_ms: int) -> bool:
+        """One vr_pump call (waiting for datagrams and parsing them, GIL
+        released) or, with rings, one wait for their workers, counted
+        into the thread's `pipeline.pump` run: one record for however
+        many consecutive calls. True when a staging lane filled."""
+        hostspans.run_call("pipeline.pump")
+        if self.eng.n_rings:
+            self.eng.rings_wait(max_wait_ms)
+            full = False
+        else:
+            full, _st = self.eng.pump(max_wait_ms)
+        hostspans.run_returned()
+        return full
 
     def reader_counters(self) -> dict:
         return self.eng.reader_counters()
@@ -497,24 +496,31 @@ class NativeAggregator(Aggregator):
     # -- flush ---------------------------------------------------------------
     def swap(self):
         rings = bool(self.eng.n_rings)
-        if rings:
-            # quiesce: no ring worker parses between here and resume, so
-            # staged rows can't race the table reset below. Datagrams
-            # queued (or parked mid-parse on a lane stop) during the pause
-            # are parsed after resume and land in the NEXT interval —
-            # the same boundary semantics as the single-ring pump queue.
-            self.eng.rings_pause()
-            self._emit_rings()
-        self._emit_native()
+        with hostspans.span("swap.emit_staged"):
+            if rings:
+                # quiesce: no ring worker parses between here and resume,
+                # so staged rows can't race the table reset below.
+                # Datagrams queued (or parked mid-parse on a lane stop)
+                # during the pause are parsed after resume and land in
+                # the NEXT interval — the same boundary semantics as the
+                # single-ring pump queue.
+                self.eng.rings_pause()
+                self._emit_rings()
+            self._emit_native()
         detached = self.table
-        detached.finalize()
+        # SlotMeta for every key of the interval, rebuilt from the
+        # engine's new-key records: host work in proportion to the live
+        # keys, on the pipeline thread
+        with hostspans.span("swap.finalize"):
+            detached.finalize()
         state, _ = super().swap()
         # super() replaced self.table with a fresh Python KeyTable; the
         # native engine keeps the slot space, so re-wrap it post-reset
-        self.eng.reset()
-        self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
-        if rings:
-            self.eng.rings_resume()
+        with hostspans.span("swap.reset"):
+            self.eng.reset()
+            self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
+            if rings:
+                self.eng.rings_resume()
         return state, detached
 
     def query_snapshot(self):
@@ -659,8 +665,15 @@ class NativeShardedAggregator(ShardedAggregator):
             self._stage_presharded(nc, ng, ns, nh)
 
     def _emit_native(self):
-        if self.preshard:
-            return self._emit_presharded()
+        # the staging below may fill a shard's batcher and dispatch a
+        # step: that `pipeline.dispatch` is then this span's child
+        with hostspans.span("pipeline.emit"):
+            if self.preshard:
+                self._emit_presharded()
+            else:
+                self._emit_split()
+
+    def _emit_split(self):
         nc, ng, ns, nh = self.eng.emit_into(
             (self._c_slot, self._c_inc, self._g_slot, self._g_val,
              self._s_slot, self._s_reg, self._s_rho, self._h_slot,
@@ -742,34 +755,42 @@ class NativeShardedAggregator(ShardedAggregator):
         """Multi-ring drain into the per-shard batchers (see
         NativeAggregator.pump for the bounding rationale)."""
         if self.eng.n_rings:
-            self.eng.rings_wait(max_wait_ms)
+            self._pump(max_wait_ms)
             for _ in range(max_emits):
                 if not self._emit_rings():
                     break
             return self.eng.drain_specials()
-        full, _st = self.eng.pump(max_wait_ms)
+        full = self._pump(max_wait_ms)
         for _ in range(max_emits):
             if not full:
                 break
             self._emit_native()
-            full, _st = self.eng.pump(0)
+            full = self._pump(0)
         if full:
             self._emit_native()
         return self.eng.drain_specials()
 
+    _pump = NativeAggregator._pump
+
     def swap(self):
         rings = bool(self.eng.n_rings)
-        if rings:
-            self.eng.rings_pause()
-            self._emit_rings()
-        self._emit_native()
+        with hostspans.span("swap.emit_staged"):
+            if rings:
+                self.eng.rings_pause()
+                self._emit_rings()
+            self._emit_native()
         detached = self.table
-        detached.finalize()
+        # SlotMeta for every key of the interval, rebuilt from the
+        # engine's new-key records: host work in proportion to the live
+        # keys, on the pipeline thread
+        with hostspans.span("swap.finalize"):
+            detached.finalize()
         state, _ = super().swap()
-        self.eng.reset()
-        self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
-        if rings:
-            self.eng.rings_resume()
+        with hostspans.span("swap.reset"):
+            self.eng.reset()
+            self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
+            if rings:
+                self.eng.rings_resume()
         return state, detached
 
     def query_snapshot(self):

@@ -17,6 +17,8 @@ Maps the reference's Server (server.go:83 struct, :771 Start, :1303 Serve):
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import os
 import queue
@@ -30,6 +32,7 @@ from veneur_tpu.aggregation.host import BatchSpec
 from veneur_tpu.aggregation.state import TableSpec
 from veneur_tpu.config import Config
 from veneur_tpu.forward.envelope import FRESH, Envelope, EnvelopeError
+from veneur_tpu.observability import hostspans
 from veneur_tpu.reliability.faults import FAULTS, FLUSH_WORKER
 from veneur_tpu.reliability.policy import (OPEN, CircuitBreaker,
                                            CircuitOpenError, RetryPolicy)
@@ -259,6 +262,10 @@ class Server:
         # device-state snapshot, so a backlogged flush worker must drop
         # intervals rather than grow without limit.
         self._flush_jobs: "queue.Queue" = queue.Queue(maxsize=4)
+        # the live interval's number: counted at each swap (pipeline
+        # thread only) and handed to the flush job, so the host spans of
+        # one interval, its ingest, its swap, its flush, share it
+        self._interval_seq = 0
         self.last_flush = time.time()
         self.last_flush_done = time.time()
         # slow-sink containment (flush-worker thread only)
@@ -1345,31 +1352,39 @@ class Server:
         here (parse + stage + batch dispatch) with the GIL released while
         idle. packet_queue still carries control items and the non-UDP
         listeners' data."""
-        while True:
-            # re-checked each pass: start() flips the flag after binding
-            # the UDP sockets, which happens after this thread launches
-            if self._native_readers_active:
-                for special in self.aggregator.pump(20):
-                    # through the backstop like every other work item (a
-                    # special is one event/service-check line; the extra
-                    # native feed() round-trip just re-classifies it)
-                    self._dispatch_item(special)
-                while True:
+        hostspans.set_thread_seq(self._interval_seq)
+        try:
+            while True:
+                # re-checked each pass: start() flips the flag after
+                # binding the UDP sockets, which happens after this
+                # thread launches
+                if self._native_readers_active:
+                    for special in self.aggregator.pump(20):
+                        # through the backstop like every other work
+                        # item (a special is one event/service-check
+                        # line; the extra native feed() round-trip just
+                        # re-classifies it)
+                        self._dispatch_item(special)
+                    while True:
+                        try:
+                            item = self.packet_queue.get_nowait()
+                        except queue.Empty:
+                            break
+                        if item is _STOP:
+                            return
+                        self._dispatch_item(item)
+                else:
                     try:
-                        item = self.packet_queue.get_nowait()
+                        item = self.packet_queue.get(timeout=0.05)
                     except queue.Empty:
-                        break
+                        continue
                     if item is _STOP:
                         return
                     self._dispatch_item(item)
-            else:
-                try:
-                    item = self.packet_queue.get(timeout=0.05)
-                except queue.Empty:
-                    continue
-                if item is _STOP:
-                    return
-                self._dispatch_item(item)
+        finally:
+            # the pump run still open (observability/hostspans.py) would
+            # otherwise leave no record
+            hostspans.close_run()
 
     def _dispatch_item(self, item):
         try:
@@ -1394,7 +1409,18 @@ class Server:
     def _dispatch_item_inner(self, item):
         if isinstance(item, FlushRequest):
             self._handle_flush_request(item)
-        elif isinstance(item, PipelineRequest):
+        elif type(item) is bytes:
+            # a raw packet (exactly bytes: _ImportBytes subclasses it):
+            # one per datagram or stream line, too many for a span each;
+            # the emit and dispatch they trigger are spanned where they
+            # happen
+            self._process_packets(item)
+        else:
+            with hostspans.span("pipeline.item", tag=type(item).__name__):
+                self._handle_item(item)
+
+    def _handle_item(self, item):
+        if isinstance(item, PipelineRequest):
             # query-tier snapshot/launch visits: FIFO position in this
             # queue is exactly the read-your-writes boundary, and a
             # launch dispatched here precedes any later donating ingest
@@ -1486,27 +1512,40 @@ class Server:
         agg = self.aggregator
         # the ingest-drain phase: how long the interval's device state
         # takes to detach from the hot path (the only flush work that
-        # blocks ingest) — timed here, surfaced as the flush trace's
-        # first child span and the phase=ingest_drain timer
-        swap_t0 = time.perf_counter_ns()
+        # blocks ingest) — the `swap` host span, surfaced as the flush
+        # trace's first child span and the phase=ingest_drain timer, and
+        # split by what the aggregator spanned inside it: the wait for
+        # the steps still queued on the device (swap_device_wait) and
+        # everything else (swap_host: the last emit, the fresh state,
+        # the engine and key-table reset)
+        seq = self._interval_seq
         try:
-            if grow_targets:
-                from veneur_tpu.tables import grow_swap, grown_spec
-                state, table, agg = grow_swap(
-                    self, grown_spec(agg.spec, grow_targets))
-            else:
-                state, table = self.aggregator.swap()
+            with hostspans.span("swap", seq=seq) as swap_span:
+                if grow_targets:
+                    from veneur_tpu.tables import grow_swap, grown_spec
+                    state, table, agg = grow_swap(
+                        self, grown_spec(agg.spec, grow_targets))
+                else:
+                    state, table = self.aggregator.swap()
         except Exception as e:
             log.exception("flush swap failed")
             req.finish(False, f"swap failed: {e}")
             return
-        swap_ns = time.perf_counter_ns() - swap_t0
+        self._interval_seq = seq + 1
+        hostspans.set_thread_seq(seq + 1)
+        swap_ns = swap_span.ns
+        wait_ns = swap_span.children.get("swap.device_wait", 0)
         if grow_targets:
             self.tables.note_grow(grow_targets, swap_ns)
         self._t_flush_phase.observe(swap_ns, phase="ingest_drain")
-        # snapshot pipeline-owned counters here: the native engine's
-        # stats call isn't safe to interleave with feed()
+        self._t_flush_phase.observe(wait_ns, phase="swap_device_wait")
+        self._t_flush_phase.observe(swap_ns - wait_ns, phase="swap_host")
+        # snapshot pipeline-owned counters here, at the interval's
+        # boundary. (Not for safety: vt_stats is two atomic loads and a
+        # shared lock on the key maps, and any thread may call it while
+        # the pipeline thread feeds; perfbench polls it at 1 kHz.)
         stats = {
+            "seq": seq,
             "swap_ns": swap_ns,
             "h2d_bytes": getattr(self.aggregator, "h2d_bytes", 0),
             "packets_received": self.packets_received,
@@ -1529,6 +1568,8 @@ class Server:
             # set estimates by 2^shift to undo the member subsampling
             "set_shift": getattr(self.aggregator, "last_set_shift", 0),
         }
+        # the job's wait for the flush worker starts here (queue_wait)
+        stats["queued_ns"] = time.monotonic_ns()
         self._flush_jobs.put_nowait((agg, state, table, stats, now, req))
 
     # -- listeners ----------------------------------------------------------
@@ -2350,31 +2391,29 @@ class Server:
         that is the OLD spec, not self.aggregator's). Containment: a
         checkpoint that cannot be built degrades durability, never the
         flush."""
-        ck_t0 = time.perf_counter_ns()
-        try:
-            from veneur_tpu.persistence import build_snapshot
-            spill_bytes, spill_n = None, 0
-            if self.forward_spill is not None:
-                spill_bytes = self.forward_spill.to_bytes()
-                spill_n = len(self.forward_spill)
-            n_shards = getattr(agg, "n_shards", 1)
-            snap = build_snapshot(
-                agg.spec, table, flush_arrays, raw,
-                agg_kind="sharded" if n_shards > 1 else "single",
-                n_shards=n_shards, interval_ts=ts,
-                hostname=self.hostname, spill=spill_bytes,
-                spill_entries=spill_n,
-                forward_meta=self._forward_meta_snapshot(),
-                watches=self._watch_snapshot(),
-                history=self._history_snapshot(),
-                tenants=self._tenant_snapshot(),
-                keytables=self._tables_snapshot())
-            self._ckpt_writer.submit(snap)
-        except Exception:
-            log.exception("checkpoint snapshot build failed; interval "
-                          "not checkpointed")
-        self._t_flush_phase.observe(time.perf_counter_ns() - ck_t0,
-                                    phase="checkpoint_build")
+        with self._flush_stage(None, "checkpoint_build"):
+            try:
+                from veneur_tpu.persistence import build_snapshot
+                spill_bytes, spill_n = None, 0
+                if self.forward_spill is not None:
+                    spill_bytes = self.forward_spill.to_bytes()
+                    spill_n = len(self.forward_spill)
+                n_shards = getattr(agg, "n_shards", 1)
+                snap = build_snapshot(
+                    agg.spec, table, flush_arrays, raw,
+                    agg_kind="sharded" if n_shards > 1 else "single",
+                    n_shards=n_shards, interval_ts=ts,
+                    hostname=self.hostname, spill=spill_bytes,
+                    spill_entries=spill_n,
+                    forward_meta=self._forward_meta_snapshot(),
+                    watches=self._watch_snapshot(),
+                    history=self._history_snapshot(),
+                    tenants=self._tenant_snapshot(),
+                    keytables=self._tables_snapshot())
+                self._ckpt_writer.submit(snap)
+            except Exception:
+                log.exception("checkpoint snapshot build failed; interval "
+                              "not checkpointed")
 
     def _watch_snapshot(self) -> Optional[dict]:
         """Watch registrations + firing state for the checkpoint's
@@ -2527,9 +2566,18 @@ class Server:
             if job is _STOP:
                 return
             agg, state, table, stats, swapped_at, req = job
+            # the job's wait for this thread: stamped when it was put (on
+            # the pipeline thread), so recorded by hand
+            seq, taken_ns = stats.get("seq"), time.monotonic_ns()
+            queued_ns = stats.get("queued_ns", taken_ns)
+            stats["queue_wait_ns"] = taken_ns - queued_ns
+            hostspans.record("queue_wait", queued_ns, taken_ns, seq=seq)
+            self._t_flush_phase.observe(taken_ns - queued_ns,
+                                        phase="queue_wait")
             ok, detail = True, ""
             try:
-                self._do_flush(agg, state, table, stats, swapped_at)
+                with hostspans.span("flush", seq=seq):
+                    self._do_flush(agg, state, table, stats, swapped_at)
             except Exception as e:
                 # a failed flush must never kill the flush thread; state
                 # was already swapped, next interval starts clean
@@ -2554,7 +2602,8 @@ class Server:
         if self.tenancy is not None and not (
                 self._overload is not None
                 and self.cfg.overload_native_admission):
-            self._sync_native_tenancy(drain=True)
+            with self._flush_stage(None, "tenancy_sync"):
+                self._sync_native_tenancy(drain=True)
         # stamp with the interval's swap time, not the job's run time — a
         # queued interval must not shift into the next time bucket
         ts = int(swapped_at)
@@ -2573,179 +2622,178 @@ class Server:
         h2d_delta = max(0, h2d_total - self._h2d_reported)
         self._h2d_reported = h2d_total
         if trace:
-            # the swap already ran on the pipeline thread before this job
-            # was queued; backdate the root by its duration and replay it
-            # as the first child so the trace covers the whole interval
-            root.start_ns -= swap_ns
+            # the swap already ran on the pipeline thread and the job then
+            # waited for this one; backdate the root by both and replay
+            # them as the first children so the trace covers the whole
+            # interval
+            wait_ns = int(stats.get("queue_wait_ns", 0))
+            root.start_ns -= swap_ns + wait_ns
+            swapped_ns = root.start_ns + swap_ns
             drain = root.child("flush.ingest_drain", start_ns=root.start_ns)
             drain.set_tag("h2d_bytes", str(h2d_delta))
+            queued = root.child("flush.queue_wait", start_ns=swapped_ns)
             if self.trace_client is not None:
+                self.trace_client.record(drain.finish(swapped_ns))
                 self.trace_client.record(
-                    drain.finish(root.start_ns + swap_ns))
+                    queued.finish(swapped_ns + wait_ns))
 
-        def stage(name):
-            return root.child(f"flush.{name}")
+        stage = functools.partial(self._flush_stage, root)
 
-        dev_t0 = time.perf_counter_ns()
-        sp = stage("device_update")
         raw = None
         # a due checkpoint rides the forward path's raw sketch outputs —
         # same want_raw host transfer, zero checkpoint-only device reads
         ckpt_due = (self._ckpt_writer is not None
                     and self._flushes_since_ckpt + 1
                     >= max(1, self.cfg.checkpoint_interval_flushes))
-        if (self._forward_client is not None or ckpt_due
-                or self.cfg.collective_attach):
-            flush_arrays, table, raw = agg.compute_flush(
-                state, table, self.cfg.percentiles, want_raw=True,
-                history=self.history)
-        else:
-            flush_arrays, table = agg.compute_flush(
-                state, table, self.cfg.percentiles, history=self.history)
-        if self.tables is not None:
-            try:
-                # idle census over the detached (immutable) table: exact
-                # evicted_total + the shrink demand signal
-                self.tables.census_flush(table, swapped_at)
-            except Exception:
-                log.exception("table census failed; eviction accounting "
-                              "skipped this interval")
-        self._t_flush_phase.observe(time.perf_counter_ns() - dev_t0,
-                                    phase="device_update")
-        if trace:
-            sp.set_tag("h2d_bytes", str(h2d_delta))
-        sp.client_finish(self.trace_client)
-        # streaming watch tier: hand the DETACHED interval to the watch
-        # engine's own thread. compute_flush does not donate its state
-        # input, so the reference stays valid for that thread's fused
-        # evaluation; offer() is non-blocking (bounded queue,
-        # drop-oldest with exact accounting), so watches can never
-        # stretch the flush deadline. At overload CRITICAL the
-        # evaluation is shed outright — counted, never silent.
-        if self.watch_engine is not None:
-            watch_shed = False
-            if self._overload is not None:
-                from veneur_tpu.reliability.overload import CRITICAL
-                watch_shed = self._overload.state >= CRITICAL
-            if watch_shed:
-                self.watch_engine.skip_interval("overload CRITICAL")
+        with stage("device_update",
+                   split=("flush_dispatch", "flush_d2h")) as sp:
+            if (self._forward_client is not None or ckpt_due
+                    or self.cfg.collective_attach):
+                flush_arrays, table, raw = agg.compute_flush(
+                    state, table, self.cfg.percentiles, want_raw=True,
+                    history=self.history)
             else:
-                # pin THIS interval's ring window seq now — a later
-                # flush advances the ring before the engine thread runs
-                hist_seq = (self.history.seq - 1
-                            if self.history is not None
-                            and self.history.armed else None)
-                self.watch_engine.offer(
-                    state, table, int(stats.get("set_shift", 0)), ts,
-                    hist_seq)
-        # exactly-once forwarding: export + stage this interval's unit
-        # under a fresh (epoch, seq) BEFORE the checkpoint build, so the
-        # snapshot's spill chunk carries the payload with its envelope
-        # (_stage_forward_unit explains the crash-replay invariant)
-        #
-        # co-located collective tier: hand this interval's forwardable
-        # rows to the in-process tier as device staging (zero
-        # serialization). A successful absorb IS the forward — the wire
-        # path (stage + gRPC/HTTP) is skipped for the interval; any
-        # failure falls through to it untouched.
-        absorbed = False
-        if self.cfg.collective_attach and raw is not None:
-            # the co-located absorb IS this interval's forward, so it
-            # gets the same flush.forward stage span the wire path
-            # would; the tier parents its absorb span onto it and the
-            # span tree stays connected across tiers without a wire hop
-            asp = stage("forward")
-            asp.set_tag("transport", "colocated")
-            try:
-                absorbed = self._absorb_colocated(raw, table, span=asp)
-            finally:
-                asp.client_finish(self.trace_client)
-        if (self._fwd_source_id is not None and raw is not None
-                and not absorbed):
-            self._stage_forward_unit(raw, table)
-        if self._ckpt_writer is not None:
-            if ckpt_due:
-                # capture the spill BEFORE the forward drains it: a crash
-                # between here and a successful send replays those
-                # payloads. The replay is NOT uniformly idempotent at the
-                # receiving tier — HLL register folds and LWW gauges
-                # absorb duplicates, but counter accumulators and
-                # t-digest centroid weights are ADDITIVE and double-count
-                # — so with forward_dedup_window > 0 the staged unit
-                # replays under its original (source_id, epoch, seq) and
-                # the receiver's dedup window suppresses the re-fold;
-                # without a window the replay is at-least-once for the
-                # additive kinds (forward/envelope.py).
-                self._checkpoint_interval(agg, flush_arrays, table, raw, ts)
-                self._flushes_since_ckpt = 0
+                flush_arrays, table = agg.compute_flush(
+                    state, table, self.cfg.percentiles, history=self.history)
+            if self.tables is not None:
+                try:
+                    # idle census over the detached (immutable) table: exact
+                    # evicted_total + the shrink demand signal
+                    self.tables.census_flush(table, swapped_at)
+                except Exception:
+                    log.exception("table census failed; eviction accounting "
+                                  "skipped this interval")
+            if trace:
+                sp.set_tag("h2d_bytes", str(h2d_delta))
+        # everything between the device's answer and the frame: none of
+        # it blocks on the device, all of it delays the sinks
+        with stage("post_device", ssf=trace):
+            # streaming watch tier: hand the DETACHED interval to the watch
+            # engine's own thread. compute_flush does not donate its state
+            # input, so the reference stays valid for that thread's fused
+            # evaluation; offer() is non-blocking (bounded queue,
+            # drop-oldest with exact accounting), so watches can never
+            # stretch the flush deadline. At overload CRITICAL the
+            # evaluation is shed outright — counted, never silent.
+            if self.watch_engine is not None:
+                watch_shed = False
+                if self._overload is not None:
+                    from veneur_tpu.reliability.overload import CRITICAL
+                    watch_shed = self._overload.state >= CRITICAL
+                if watch_shed:
+                    self.watch_engine.skip_interval("overload CRITICAL")
+                else:
+                    # pin THIS interval's ring window seq now — a later
+                    # flush advances the ring before the engine thread runs
+                    hist_seq = (self.history.seq - 1
+                                if self.history is not None
+                                and self.history.armed else None)
+                    self.watch_engine.offer(
+                        state, table, int(stats.get("set_shift", 0)), ts,
+                        hist_seq)
+            # exactly-once forwarding: export + stage this interval's unit
+            # under a fresh (epoch, seq) BEFORE the checkpoint build, so the
+            # snapshot's spill chunk carries the payload with its envelope
+            # (_stage_forward_unit explains the crash-replay invariant)
+            #
+            # co-located collective tier: hand this interval's forwardable
+            # rows to the in-process tier as device staging (zero
+            # serialization). A successful absorb IS the forward — the wire
+            # path (stage + gRPC/HTTP) is skipped for the interval; any
+            # failure falls through to it untouched.
+            absorbed = False
+            if self.cfg.collective_attach and raw is not None:
+                # the co-located absorb IS this interval's forward, so it
+                # gets the same flush.forward stage span the wire path
+                # would; the tier parents its absorb span onto it and the
+                # span tree stays connected across tiers without a wire hop
+                with stage("forward", timed=False) as asp:
+                    asp.set_tag("transport", "colocated")
+                    absorbed = self._absorb_colocated(raw, table, span=asp)
+            if (self._fwd_source_id is not None and raw is not None
+                    and not absorbed):
+                self._stage_forward_unit(raw, table)
+            if self._ckpt_writer is not None:
+                if ckpt_due:
+                    # capture the spill BEFORE the forward drains it: a crash
+                    # between here and a successful send replays those
+                    # payloads. The replay is NOT uniformly idempotent at the
+                    # receiving tier — HLL register folds and LWW gauges
+                    # absorb duplicates, but counter accumulators and
+                    # t-digest centroid weights are ADDITIVE and double-count
+                    # — so with forward_dedup_window > 0 the staged unit
+                    # replays under its original (source_id, epoch, seq) and
+                    # the receiver's dedup window suppresses the re-fold;
+                    # without a window the replay is at-least-once for the
+                    # additive kinds (forward/envelope.py).
+                    self._checkpoint_interval(agg, flush_arrays, table,
+                                              raw, ts)
+                    self._flushes_since_ckpt = 0
+                else:
+                    self._flushes_since_ckpt += 1
+            if self._forward_client is not None and not absorbed:
+                # fire-and-forget, concurrent with sink flushes
+                # (flusher.go:84-95); _forward logs and counts its own errors,
+                # and the flush thread must never block on a slow global tier
+                # (its own thread finishes the span and times the phase)
+                fsp = root.child("flush.forward")
+                if self._fwd_source_id is not None:
+                    # ack-gated mode: the interval was staged above; the pump
+                    # replays every pending unit under its original envelope
+                    self._spawn_aux(self._pump_traced, fsp)
+                else:
+                    self._spawn_aux(self._forward_traced, fsp, raw, table)
+
+            if self.cfg.count_unique_timeseries:
+                from veneur_tpu.server.flusher import unique_timeseries
+                self._unique_ts = unique_timeseries(table, self.cfg.is_local)
+
+            # span sinks flush concurrently (flusher.go:56 go flushTraces)
+            self._spawn_aux(self.span_pipeline.flush)
+
+            with self._event_lock:
+                samples, self.event_samples = self.event_samples, []
+            for sink in self.metric_sinks:
+                try:
+                    sink.flush_other_samples(samples)
+                except Exception as e:
+                    log.warning("sink %s FlushOtherSamples: %s", sink.name, e)
+
+            # columnar fast path: when every sink takes frames and no plugin
+            # needs object lists, skip per-metric InterMetric construction
+            # entirely (~20s of host time per interval at the 10M-key north
+            # star; see flusher.MetricFrame)
+            if (self.metric_sinks
+                    and all(getattr(s, "accepts_frames", False)
+                            for s in self.metric_sinks)
+                    and all(getattr(p, "accepts_frames", False)
+                            for p in self.plugins)):
+                from veneur_tpu.server.flusher import generate_frame
+                generate = generate_frame
             else:
-                self._flushes_since_ckpt += 1
-        if self._forward_client is not None and not absorbed:
-            # fire-and-forget, concurrent with sink flushes
-            # (flusher.go:84-95); _forward logs and counts its own errors,
-            # and the flush thread must never block on a slow global tier
-            fsp = stage("forward")
-            if self._fwd_source_id is not None:
-                # ack-gated mode: the interval was staged above; the pump
-                # replays every pending unit under its original envelope
-                self._spawn_aux(self._pump_traced, fsp)
-            else:
-                self._spawn_aux(self._forward_traced, fsp, raw, table)
-
-        if self.cfg.count_unique_timeseries:
-            from veneur_tpu.server.flusher import unique_timeseries
-            self._unique_ts = unique_timeseries(table, self.cfg.is_local)
-
-        # span sinks flush concurrently (flusher.go:56 go flushTraces)
-        self._spawn_aux(self.span_pipeline.flush)
-
-        with self._event_lock:
-            samples, self.event_samples = self.event_samples, []
-        for sink in self.metric_sinks:
-            try:
-                sink.flush_other_samples(samples)
-            except Exception as e:
-                log.warning("sink %s FlushOtherSamples: %s", sink.name, e)
-
-        # columnar fast path: when every sink takes frames and no plugin
-        # needs object lists, skip per-metric InterMetric construction
-        # entirely (~20s of host time per interval at the 10M-key north
-        # star; see flusher.MetricFrame)
-        if (self.metric_sinks
-                and all(getattr(s, "accepts_frames", False)
-                        for s in self.metric_sinks)
-                and all(getattr(p, "accepts_frames", False)
-                        for p in self.plugins)):
-            from veneur_tpu.server.flusher import generate_frame
-            generate = generate_frame
-        else:
-            generate = generate_intermetrics
-        # degraded-aggregation correction: the detached interval staged
-        # set members subsampled at 2^-shift (Aggregator._set_admit), so
-        # multiply the FLUSH estimate back by 2^shift. Forward and
-        # checkpoint carry raw HLL registers and are untouched; a new
-        # dict + new array because the checkpoint snapshot may still
-        # reference the originals.
-        flush_degraded = False
-        set_shift = int(stats.get("set_shift", 0))
-        if set_shift > 0 and flush_arrays.get("set_estimate") is not None:
-            flush_arrays = dict(flush_arrays)
-            flush_arrays["set_estimate"] = (
-                flush_arrays["set_estimate"] * (1 << set_shift))
-            flush_degraded = True
-        fb_t0 = time.perf_counter_ns()
-        fbsp = stage("frame_build") if trace else None
-        final = generate(
-            flush_arrays, table,
-            percentiles=self.cfg.percentiles,
-            aggregates=self.cfg.aggregates,
-            is_local=self.cfg.is_local,
-            timestamp=ts, hostname=self.hostname)
-        self._t_flush_phase.observe(time.perf_counter_ns() - fb_t0,
-                                    phase="frame_build")
-        if fbsp is not None:
-            fbsp.set_tag("rows", str(len(final)))
-            fbsp.client_finish(self.trace_client)
+                generate = generate_intermetrics
+            # degraded-aggregation correction: the detached interval staged
+            # set members subsampled at 2^-shift (Aggregator._set_admit), so
+            # multiply the FLUSH estimate back by 2^shift. Forward and
+            # checkpoint carry raw HLL registers and are untouched; a new
+            # dict + new array because the checkpoint snapshot may still
+            # reference the originals.
+            flush_degraded = False
+            set_shift = int(stats.get("set_shift", 0))
+            if set_shift > 0 and flush_arrays.get("set_estimate") is not None:
+                flush_arrays = dict(flush_arrays)
+                flush_arrays["set_estimate"] = (
+                    flush_arrays["set_estimate"] * (1 << set_shift))
+                flush_degraded = True
+        with stage("frame_build", ssf=trace) as fbsp:
+            final = generate(
+                flush_arrays, table,
+                percentiles=self.cfg.percentiles,
+                aggregates=self.cfg.aggregates,
+                is_local=self.cfg.is_local,
+                timestamp=ts, hostname=self.hostname)
+            if fbsp is not None:
+                fbsp.set_tag("rows", str(len(final)))
         # flush protection: at CRITICAL, withhold low-priority rows from
         # sink fan-out (and plugins) — the device update, forward, and
         # checkpoint above already ran unconditionally, so no aggregated
@@ -2770,66 +2818,64 @@ class Server:
             #   aux set so shutdown still joins it (abandoning a thread
             #   inside gRPC/JAX at teardown aborts the process); daemon
             #   so a truly wedged one cannot block interpreter exit
-            fan_t0 = time.perf_counter_ns()
-            sinks_span = stage("sinks")
-            sinks_span.set_tag("metrics", str(len(final)))
-            threads = []
-            for s in self.metric_sinks:
-                # keyed by instance, not .name — names are class-level
-                # constants and two same-named sinks must not share a
-                # containment slot (instances live as long as the server,
-                # so id() is stable)
-                prev = self._sink_threads.get(id(s))
-                if prev is not None and prev.is_alive():
-                    self._c_sink_skips.inc()
-                    log.warning("sink %s: previous flush still running; "
-                                "skipping this interval", s.name)
-                    continue
-                t = threading.Thread(target=self._flush_sink,
-                                     args=(s, final, sinks_span),
-                                     daemon=True)
-                self._sink_threads[id(s)] = t
-                threads.append(t)
-            for t in threads:
-                t.start()
-            # ONE shared interval budget for the whole barrier (a
-            # per-thread timeout would give N slow sinks N intervals and
-            # stale the watchdog's last_flush_done for merely-slow sinks)
-            barrier_deadline = time.monotonic() + self.interval
-            for t in threads:
-                t.join(timeout=max(0.0,
-                                   barrier_deadline - time.monotonic()))
-                if t.is_alive():
-                    with self._aux_lock:
-                        self._aux_threads = [
-                            x for x in self._aux_threads if x.is_alive()]
-                        self._aux_threads.append(t)
-            self._t_flush_phase.observe(time.perf_counter_ns() - fan_t0,
-                                        phase="sink_fanout")
-            sinks_span.client_finish(self.trace_client)
+            with stage("sink_fanout", ssf_name="sinks") as sinks_span:
+                sinks_span.set_tag("metrics", str(len(final)))
+                threads = []
+                for s in self.metric_sinks:
+                    # keyed by instance, not .name — names are class-level
+                    # constants and two same-named sinks must not share a
+                    # containment slot (instances live as long as the server,
+                    # so id() is stable)
+                    prev = self._sink_threads.get(id(s))
+                    if prev is not None and prev.is_alive():
+                        self._c_sink_skips.inc()
+                        log.warning("sink %s: previous flush still running; "
+                                    "skipping this interval", s.name)
+                        continue
+                    t = threading.Thread(target=self._flush_sink,
+                                         args=(s, final, sinks_span),
+                                         daemon=True)
+                    self._sink_threads[id(s)] = t
+                    threads.append(t)
+                for t in threads:
+                    t.start()
+                # ONE shared interval budget for the whole barrier (a
+                # per-thread timeout would give N slow sinks N intervals and
+                # stale the watchdog's last_flush_done for merely-slow sinks)
+                barrier_deadline = time.monotonic() + self.interval
+                for t in threads:
+                    t.join(timeout=max(0.0,
+                                       barrier_deadline - time.monotonic()))
+                    if t.is_alive():
+                        with self._aux_lock:
+                            self._aux_threads = [
+                                x for x in self._aux_threads if x.is_alive()]
+                            self._aux_threads.append(t)
             # plugins run post-flush (flusher.go:117-131)
-            psp = stage("plugins") if self.plugins else None
-            from veneur_tpu.server.flusher import MetricFrame
-            is_frame = isinstance(final, MetricFrame)
-            for p in self.plugins:
-                try:
-                    if is_frame:
-                        p.flush_frame(final)
-                    else:
-                        p.flush(final)
-                except Exception as e:
-                    psp.error = True
-                    log.warning("plugin %s flush failed: %s", p.name, e)
-            if psp is not None:
-                psp.client_finish(self.trace_client)
+            if self.plugins:
+                from veneur_tpu.server.flusher import MetricFrame
+                is_frame = isinstance(final, MetricFrame)
+                with stage("plugins", timed=False) as psp:
+                    for p in self.plugins:
+                        try:
+                            if is_frame:
+                                p.flush_frame(final)
+                            else:
+                                p.flush(final)
+                        except Exception as e:
+                            psp.error = True
+                            log.warning("plugin %s flush failed: %s",
+                                        p.name, e)
         # Self-telemetry is reported even for an empty interval — the
         # reference always tallies flush totals (flusher.go:300-336), and an
         # idle server must still bootstrap veneur.flush.* / packet counters
         # into its own pipeline.
         # per-interval native-ring poll (emit latency delta average)
-        self._poll_ring_telemetry()
-        self._report_self_metrics(len(final), time.perf_counter() - flush_t0,
-                                  stats, final=final)
+        with stage("self_metrics", ssf=trace):
+            self._poll_ring_telemetry()
+            self._report_self_metrics(
+                len(final), time.perf_counter() - flush_t0, stats,
+                final=final)
         # total = downstream work + the pipeline-thread swap it rode in on
         self._t_flush_phase.observe(
             (time.perf_counter() - flush_t0) * 1e9 + swap_ns, phase="total")
@@ -2837,6 +2883,32 @@ class Server:
             root.set_tag("rows", str(len(final)))
             root.set_tag("h2d_bytes", str(h2d_delta))
         root.client_finish(self.trace_client)
+
+    @contextlib.contextmanager
+    def _flush_stage(self, root, name, ssf=True, timed=True, ssf_name=None,
+                     split=()):
+        """One flush stage on its three surfaces at once: the host span
+        `name` (observability/hostspans.py: the profiler's clock and the
+        in-memory records), `veneur.flush.phase_duration_ns{phase=name}`
+        unless `timed` is off, and, with `ssf` and a `root`, the SSF
+        child span `flush.<ssf_name or name>` of the flush trace, which
+        is what the `with` yields (else None) for the stage's tags and
+        error flag. `split` names host spans a lower layer opens directly
+        inside the stage (the aggregator has no registry): each is
+        observed as a phase of its own."""
+        child = (root.child(f"flush.{ssf_name or name}")
+                 if ssf and root is not None else None)
+        try:
+            with hostspans.span(name) as host:
+                yield child
+        finally:
+            if timed:
+                self._t_flush_phase.observe(host.ns, phase=name)
+            for part in split:
+                self._t_flush_phase.observe(host.children.get(part, 0),
+                                            phase=part)
+            if child is not None:
+                child.client_finish(self.trace_client)
 
     def _flush_protect(self, final):
         """Filter low-priority rows out of a flush result (MetricFrame or

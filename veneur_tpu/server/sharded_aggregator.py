@@ -25,9 +25,8 @@ import numpy as np
 
 from veneur_tpu.aggregation.host import Batcher, BatchSpec, KeyTable
 from veneur_tpu.aggregation.state import TableSpec
-from veneur_tpu.observability import jaxruntime
-from veneur_tpu.server.aggregator import (
-    _SYNC_EVERY, Aggregator, set_member_bytes)
+from veneur_tpu.observability import hostspans, jaxruntime
+from veneur_tpu.server.aggregator import Aggregator, set_member_bytes
 
 
 def per_shard_spec(spec: TableSpec, n_shards: int) -> TableSpec:
@@ -271,8 +270,6 @@ class ShardedAggregator(Aggregator):
         backend (Aggregator._on_batch). Two whole [1, S, W] buffers
         alternate so step N+1 packs while step N's transfer is in
         flight."""
-        import time
-
         from veneur_tpu.aggregation.step import pack_batch, packed_layout
         self._steps += 1
         self.steps_total += 1
@@ -288,14 +285,7 @@ class ShardedAggregator(Aggregator):
         for i, b in enumerate(row):
             pack_batch(b, dc, out=flat[0, i])
         self.h2d_bytes += flat.nbytes
-        t0 = time.perf_counter_ns()
-        self.state = self._ingest(self.state, flat)
-        dispatch_dt = time.perf_counter_ns() - t0
-        self.dispatch_ns += dispatch_dt
-        if self.steps_total % _SYNC_EVERY == 0:
-            self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
-                self.state)
-            self.steps_synced += 1
+        self._dispatch_step(self._ingest, flat)
 
     def _on_shard_batch(self, shard: int, batch):
         self._dispatch_row([batch if i == shard else b.force_emit()
@@ -346,20 +336,23 @@ class ShardedAggregator(Aggregator):
 
     # -- flush ---------------------------------------------------------------
     def swap(self):
-        self._emit_all()
-        self._apply_hll_imports()
+        with hostspans.span("swap.emit_staged"):
+            self._emit_all()
+            self._apply_hll_imports()
         if self._steps:
             # interval boundary sync (see Aggregator.swap)
-            self.step_ns += jaxruntime.sync_and_time(self.state)
+            with hostspans.span("swap.device_wait"):
+                self.step_ns += jaxruntime.sync_and_time(self.state)
             self.steps_synced += 1
-        state, table = self.state, self.table
-        self.state = self._empty()
-        self.table = KeyTable(self.spec, self.n_shards)
-        if self._pressure is not None:
-            self._pressure.attach(self.table)
-        self.batchers = self._make_batchers()
-        self._steps = 0
-        self._latch_degrade()
+        with hostspans.span("swap.reset"):
+            state, table = self.state, self.table
+            self.state = self._empty()
+            self.table = KeyTable(self.spec, self.n_shards)
+            if self._pressure is not None:
+                self._pressure.attach(self.table)
+            self.batchers = self._make_batchers()
+            self._steps = 0
+            self._latch_degrade()
         return state, table
 
     # -- query tier ---------------------------------------------------------
@@ -401,9 +394,15 @@ class ShardedAggregator(Aggregator):
                                  ("set", self.spec.set_capacity),
                                  ("histogram", self.spec.histo_capacity))}
 
-        packed = np.asarray(_gather_sharded(
-            self._flush(state, qs), idx["counter"], idx["gauge"],
-            idx["status"], idx["set"], idx["histogram"]))
+        with hostspans.span("flush_dispatch"):
+            gathered = _gather_sharded(
+                self._flush(state, qs), idx["counter"], idx["gauge"],
+                idx["status"], idx["set"], idx["histogram"])
+        # the host's wait for the flush and gather programs, queued on
+        # the device behind the ingest steps dispatched since the swap,
+        # plus the transfer
+        with hostspans.span("flush_d2h"):
+            packed = np.asarray(gathered)
         out = unpack_flush(packed, flush_live_shapes(
             self.pspec, len(idx["counter"]), len(idx["gauge"]),
             len(idx["status"]), len(idx["set"]), len(idx["histogram"]),
@@ -411,10 +410,12 @@ class ShardedAggregator(Aggregator):
         result = combine_flush_scalars(out)
         if want_raw or history is not None:
             from veneur_tpu.aggregation.step import unpack_flush as _unpack
-            r = _unpack(np.asarray(_gather_sharded_raw(
-                state, idx["set"], idx["histogram"])),
-                _sharded_raw_shapes(self.pspec, len(idx["set"]),
-                                    len(idx["histogram"])))
+            with hostspans.span("flush_dispatch"):
+                gathered = _gather_sharded_raw(
+                    state, idx["set"], idx["histogram"])
+            with hostspans.span("flush_d2h"):
+                r = _unpack(np.asarray(gathered), _sharded_raw_shapes(
+                    self.pspec, len(idx["set"]), len(idx["histogram"])))
             raw = {
                 "counter": result["counter"],
                 "gauge": result["gauge"],
