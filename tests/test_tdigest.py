@@ -2,9 +2,12 @@
 tdigest/histo_test.go: quantile epsilon bounds on uniform data, weight
 conservation, centroid capacity bound, merge fidelity."""
 
+import re
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from veneur_tpu.ops import tdigest
@@ -135,6 +138,136 @@ def test_compress_invariants_weight_and_order():
     merged = tdigest.merge_tables(t1, t1)
     np.testing.assert_allclose(float(np.asarray(merged.weight).sum()),
                                2 * float(wts.sum()), rtol=1e-6)
+
+
+def _compress_case(name):
+    """(mean, weight) f32[n, M] for one named input shape of compress_rows."""
+    rng = np.random.RandomState(sum(map(ord, name)))
+    n, m_len = 24, 560 if name == "merge-560" else 472
+    mean = rng.lognormal(1.0, 1.2, (n, m_len)).astype(np.float32)
+    weight = rng.randint(1, 4, (n, m_len)).astype(np.float32)
+    if name == "sparse":
+        fill = rng.choice([0.01, 0.05, 0.2, 0.4], n)
+        weight *= rng.uniform(size=(n, m_len)) < fill[:, None]
+    elif name == "empty-rows":
+        weight[::2] = 0.0
+        weight[1] = 0.0
+        weight[1, 7] = 3.0                  # a row of one centroid
+    elif name == "negative-means":
+        mean[::2] *= -1.0                   # all negative
+        mean[1::4] -= 3.0                   # straddling zero
+    elif name == "heavy-row":
+        # 2^20 of weight in the middle, weight-1 centroids at the ends: a
+        # cumulative difference would cost the ends ulps of the TOTAL
+        weight[:] = 1.0
+        mean.sort(axis=1)
+        weight[:, 200:280] = float(1 << 20) / 80
+    return mean, weight
+
+
+def _segment_reduce_f64(m, w, cell, out_c):
+    """Float64 NumPy reference of the reduce: per row, each column's total
+    weight, weighted mean and number of inputs, over the inputs with that
+    cell (cell == out_c: an empty, dropped)."""
+    n = m.shape[0]
+    m, w = m.astype(np.float64), w.astype(np.float64)
+    w_ref = np.zeros((n, out_c + 1))
+    wm_ref = np.zeros((n, out_c + 1))
+    awm_ref = np.zeros((n, out_c + 1))
+    cnt = np.zeros((n, out_c + 1), np.int64)
+    rows = np.broadcast_to(np.arange(n)[:, None], cell.shape)
+    np.add.at(w_ref, (rows, cell), w)
+    np.add.at(wm_ref, (rows, cell), w * m)
+    np.add.at(awm_ref, (rows, cell), np.abs(w * m))
+    np.add.at(cnt, (rows, cell), 1)
+    w_ref, wm_ref, awm_ref, cnt = (a[:, :out_c]
+                                   for a in (w_ref, wm_ref, awm_ref, cnt))
+    safe = np.maximum(w_ref, 1e-300)
+    return w_ref, wm_ref / safe, awm_ref / safe, cnt
+
+
+@pytest.mark.parametrize("case", [
+    "dense", "sparse", "empty-rows", "negative-means", "heavy-row",
+    "merge-560", "vmap-vmap"])
+def test_compress_rows_matches_float64_segment_reduce(case):
+    """compress_rows against a float64 segment-reduce of the SAME cell
+    assignment (tdigest._sorted_cells): occupancy equal, integer weights
+    exact, means within a few f32 ulps of the cell's mean magnitude, a
+    column of one input bit-exact, and the E bottom/top inputs verbatim."""
+    mean, weight = _compress_case(case)
+    out_c = tdigest.centroid_capacity()
+    E = tdigest.DEFAULT_EXACT_EXTREMES
+    compress = lambda m, w: tdigest.compress_rows(m, w, out_c=out_c)
+    if case == "vmap-vmap":
+        # the four-chip program's shape: vmap(vmap(compact_core))
+        shaped = lambda a: jnp.asarray(a).reshape((2, 3, 4, a.shape[-1]))
+        got = jax.vmap(jax.vmap(compress))(shaped(mean), shaped(weight))
+        m_out, w_out = (np.asarray(a).reshape((-1, out_c)) for a in got)
+    else:
+        m_out, w_out = (np.asarray(a) for a in compress(mean, weight))
+    m_s, w_s, cell = (np.asarray(a) for a in tdigest._sorted_cells(
+        jnp.asarray(mean), jnp.asarray(weight),
+        compression=tdigest.DEFAULT_COMPRESSION,
+        cells_per_k=tdigest.DEFAULT_CELLS_PER_K, out_c=out_c,
+        exact_extremes=E))
+    assert np.all(np.diff(cell, axis=1) >= 0)    # sorted rows, sorted cells
+    w_ref, m_ref, scale, cnt = _segment_reduce_f64(m_s, w_s, cell, out_c)
+
+    # integer weights sum exactly, so occupancy is equal too
+    np.testing.assert_array_equal(w_out.astype(np.float64), w_ref)
+    occ = w_ref > 0
+    assert np.all(m_out[~occ] == 0.0)
+    ulp = np.finfo(np.float32).eps
+    assert np.all(np.abs(m_out - m_ref)[occ] <= 4 * ulp * scale[occ])
+    # a column of one input IS that input, mean and weight
+    one = cnt == 1
+    np.testing.assert_array_equal(m_out[one],
+                                  m_ref[one].astype(np.float32))
+    # the E lowest and E highest occupied inputs of every row, verbatim
+    for r in range(mean.shape[0]):
+        live = weight[r] > 0
+        order = np.argsort(mean[r][live], kind="stable")
+        sm, sw = mean[r][live][order], weight[r][live][order]
+        k = min(E, len(sm))
+        np.testing.assert_array_equal(m_out[r, :k], sm[:k])
+        np.testing.assert_array_equal(w_out[r, :k], sw[:k])
+        k_top = min(E, len(sm) - k)
+        if k_top:
+            np.testing.assert_array_equal(m_out[r, out_c - k_top:],
+                                          sm[-k_top:])
+            np.testing.assert_array_equal(w_out[r, out_c - k_top:],
+                                          sw[-k_top:])
+
+
+def test_compress_rows_lowers_without_scatter_or_gather():
+    """The compiled compress_rows holds no scatter and no gather, and
+    nothing the size of the [n, M, out_c] compare: the reduce runs in row
+    blocks, so its temporaries do not grow with n·M·out_c. (The CPU
+    backend does not fuse the compare into the reduce and holds one row
+    block's product, REDUCE_ROW_BLOCK x M x out_c, a few times over; the
+    TPU compiler fuses it — tests/test_tpu_compile.py holds that side.)"""
+    m_len, out_c = 472, 280
+
+    def compiled(n):
+        x = jax.ShapeDtypeStruct((n, m_len), jnp.float32)
+        return jax.jit(
+            lambda m, w: tdigest.compress_rows(m, w, out_c=out_c)
+        ).lower(x, x).compile()
+
+    def op_names(c):
+        return set(re.findall(r"[\s)]([a-z][a-z\-]*)\(", c.as_text()))
+
+    # what one row block's compare costs the CPU backend: four products
+    block = tdigest.REDUCE_ROW_BLOCK * m_len * out_c * 4
+    for n in (64, 4096):
+        c = compiled(n)
+        ops = op_names(c)
+        assert {"sort", "reduce"} <= ops, ops
+        assert not {"scatter", "gather"} & ops, ops
+        # [n, M] arrays and one row block's products: at 4096 rows that is
+        # a twentieth of ONE [n, M, out_c] array
+        assert c.memory_analysis().temp_size_in_bytes < (
+            8 * n * m_len * 4 + 5 * block)
 
 
 def test_cdf_roundtrip():

@@ -127,6 +127,24 @@ def test_packed_ingest_program_compiles_under_1gib(one_chip, default_spec,
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+def test_compaction_compiles_without_scatter_or_gather(one_chip,
+                                                       default_spec):
+    """compact_core over the whole shipped digest table (16384 x 472 ->
+    280): the chip's compiler fuses compress_rows' compare and select into
+    its reduces, so the program holds a few [n, M] arrays and nothing the
+    size of the [n, M, out_c] compare (8.7 GB), and no scatter or gather
+    (the scatter form took 0.57 s a call on the v5e; PERF.md PR 29)."""
+    compiled = jax.jit(partial(step.compact_core, spec=default_spec),
+                       donate_argnums=(0,)).lower(
+        _state_shapes(default_spec, one_chip)).compile()
+    text = compiled.as_text()
+    assert " scatter(" not in text and " gather(" not in text
+    assert " sort(" in text
+    n, m_len = default_spec.histo_capacity, default_spec.total_cells
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 8 * n * m_len * 4, mem
+
+
 def test_live_flush_program_compiles(one_chip, default_spec, monkeypatch):
     """flush_live_in_packed at full-capacity live buckets and the served
     percentiles (0.5/0.75/0.99), on the XLA quantile path (the Pallas

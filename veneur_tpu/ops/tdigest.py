@@ -16,8 +16,18 @@ fixed-shape computation:
      per-row cumulative weight, assign every centroid to the k-cell
      ``floor(cells_per_k · (k1(q_mid) − k1(0)))`` of its weight midpoint, and
      segment-reduce (weighted mean) each cell,
-  3. all reductions use the sort → cumsum → unique-index scatter → running-max
-     → diff pattern, which XLA tiles well on TPU (no serialized scatter-adds).
+  3. the segment-reduce is a masked reduce in two dimensions: one sort that
+     carries (mean, weight) along, then every output column sums the inputs
+     whose cell it is (`where(cell == c, w, 0)` reduced over the row). There
+     is no scatter, no gather and no flattening to one dimension. The form
+     this replaced (argsort + take_along_axis, cumulative sums scattered at
+     run ends through flat `.at[].set/.max`, running-max fill, differences)
+     was claimed here to tile well on TPU; the v5e's trace showed 0.57 s a
+     call at 16384 x 472 -> 280, of which the sort was 12 ms and the rest
+     gathers, relayouts and five flat scatters. The masked reduce does more
+     arithmetic (n·M·out_c compares) and takes 12 ms in all, and it sums each
+     cell's addends directly, where the cumulative differences carried the
+     rounding of the whole row's total (PERF.md §6, PR 29).
 
 Bucketing by unit k-cells satisfies the same Δk ≤ 1 merge invariant the
 reference enforces greedily; ``cells_per_k = 3`` (third-cells) plus
@@ -130,6 +140,67 @@ def _k1(q, compression):
     return compression / (2.0 * jnp.pi) * jnp.arcsin(2.0 * q - 1.0)
 
 
+# Rows the masked segment-reduce takes at a time (`jax.lax.map` over row
+# blocks). The reduce compares every input cell of a row with every output
+# column; the TPU compiler fuses compare, select and reduce and never holds
+# the [rows, M, out_c] product, the CPU backend does not fuse and holds it,
+# so the block bounds its memory there (32 x 472 x 280 f32 = 17 MB an
+# array). On the v5e the blocking is free: one 16384 x 472 -> 280 compress
+# took 12.2 ms in blocks of 32 and 12.1 ms unblocked (PERF.md, PR 29).
+REDUCE_ROW_BLOCK = 32
+
+
+def _sorted_cells(m_in, w_in, *, compression, cells_per_k, out_c,
+                  exact_extremes):
+    """Sort each row of f32[n, M] centroids by mean and give every one its
+    output column: returns (mean, weight, cell), each [n, M] in sorted
+    order, empties last with weight 0 and cell == out_c (no column)."""
+    interior = out_c - 2 * exact_extremes
+    occupied = w_in > 0
+    # ONE sort carries the payload along (an argsort plus take_along_axis
+    # lowers to gathers, which took longer than the sort itself)
+    _, m, w = jax.lax.sort(
+        (jnp.where(occupied, m_in, jnp.inf), m_in,
+         jnp.where(occupied, w_in, 0.0)), dimension=1, num_keys=1)
+
+    tot = jnp.sum(w, axis=1, keepdims=True)
+    cum = jnp.cumsum(w, axis=1)
+    q_mid = (cum - 0.5 * w) / jnp.maximum(tot, jnp.float32(1e-30))
+    k0 = -compression / 4.0  # k1(0)
+    cell = jnp.floor((_k1(q_mid, compression) - k0)
+                     * cells_per_k).astype(jnp.int32)
+    cell = jnp.clip(cell, 0, interior - 1) + exact_extremes
+    if exact_extremes > 0:
+        # Protected extremes go to dedicated end columns: bottom rank r →
+        # column r, top rank r' → column out_c-1-r'. A protected column
+        # holds one input centroid, which is exactly what makes it exact.
+        occ32 = (w > 0).astype(jnp.int32)
+        rnk = jnp.cumsum(occ32, axis=1) - 1      # rank among occupied
+        r_top = jnp.sum(occ32, axis=1, keepdims=True) - 1 - rnk
+        cell = jnp.where(rnk < exact_extremes, rnk,
+                         jnp.where(r_top < exact_extremes,
+                                   out_c - 1 - r_top, cell))
+    return m, w, jnp.where(w > 0, cell, out_c)
+
+
+def _reduce_row(row, *, out_c):
+    """Masked segment-reduce of one row: (mean, weight, cell) f32/i32[M] →
+    (mean', weight') f32[out_c]. Every column sums the inputs whose cell
+    it is, directly: no scatter, no gather, no cumulative difference. A
+    column with ONE input passes its (mean, weight) through bit-exact (one
+    non-zero addend; the max of one element), so the protected extremes
+    stay the raw samples they were, whatever the row's total weight."""
+    m, w, cell = row
+    hit = cell[:, None] == jnp.arange(out_c, dtype=jnp.int32)
+    w_c = jnp.sum(jnp.where(hit, w[:, None], 0.0), axis=0)
+    wm_c = jnp.sum(jnp.where(hit, (w * m)[:, None], 0.0), axis=0)
+    n_c = jnp.sum(hit, axis=0, dtype=jnp.int32)
+    top = jnp.max(jnp.where(hit, m[:, None], -jnp.inf), axis=0)
+    m_c = jnp.where(n_c == 1, top,
+                    jnp.where(w_c > 0, wm_c / jnp.maximum(w_c, 1e-30), 0.0))
+    return m_c, w_c
+
+
 def compress_rows(mean, weight, *, compression: float = DEFAULT_COMPRESSION,
                   cells_per_k: int = DEFAULT_CELLS_PER_K,
                   out_c: int | None = None,
@@ -150,95 +221,17 @@ def compress_rows(mean, weight, *, compression: float = DEFAULT_COMPRESSION,
     """
     if out_c is None:
         out_c = centroid_capacity(compression, cells_per_k, exact_extremes)
-    interior = out_c - 2 * exact_extremes
-    assert interior >= 8, (
+    assert out_c - 2 * exact_extremes >= 8, (
         f"out_c={out_c} leaves no k-cell interior around "
         f"2x{exact_extremes} protected extremes")
     lead = mean.shape[:-1]
-    m_in = mean.reshape((-1, mean.shape[-1]))
-    w_in = weight.reshape((-1, weight.shape[-1]))
-    n, m_len = m_in.shape
-
-    occupied = w_in > 0
-    sort_key = jnp.where(occupied, m_in, jnp.inf)
-    order = jnp.argsort(sort_key, axis=1)
-    m = jnp.take_along_axis(m_in, order, axis=1)
-    w = jnp.where(jnp.take_along_axis(occupied, order, axis=1),
-                  jnp.take_along_axis(w_in, order, axis=1), 0.0)
-
-    tot = jnp.sum(w, axis=1, keepdims=True)
-    cum = jnp.cumsum(w, axis=1)
-    q_mid = (cum - 0.5 * w) / jnp.maximum(tot, jnp.float32(1e-30))
-    k0 = -compression / 4.0  # k1(0)
-    cell = jnp.floor((_k1(q_mid, compression) - k0)
-                     * cells_per_k).astype(jnp.int32)
-    cell = jnp.clip(cell, 0, interior - 1) + exact_extremes
-    if exact_extremes > 0:
-        # Protected extremes scatter to dedicated end columns: bottom
-        # rank r → column r, top rank r' → column out_c-1-r'. Output
-        # columns stay non-decreasing along the sorted row (bottom block
-        # < interior block < top block), so the run-end machinery below
-        # needs no change — and protected runs are single-element, which
-        # is exactly what makes them exact.
-        occ32 = (w > 0).astype(jnp.int32)
-        rnk = jnp.cumsum(occ32, axis=1) - 1      # rank among occupied
-        r_top = jnp.sum(occ32, axis=1, keepdims=True) - 1 - rnk
-        cell = jnp.where(rnk < exact_extremes, rnk,
-                         jnp.where(r_top < exact_extremes,
-                                   out_c - 1 - r_top, cell))
-    # empties → out-of-bounds cell so their scatter is dropped
-    cell = jnp.where(w > 0, cell, out_c)
-
-    # Per-(row, cell) sums via cumulative-scatter-diff: cells are sorted within
-    # each row, so scatter each run's *trailing cumulative* at a unique index,
-    # forward-fill empty cells with a running max, and difference.
-    cum_wm = jnp.cumsum(w * m, axis=1)
-    is_last = jnp.concatenate(
-        [cell[:, :-1] != cell[:, 1:], jnp.ones((n, 1), bool)], axis=1)
-    rows = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, m_len))
-    flat = jnp.where(is_last, rows * out_c + jnp.minimum(cell, out_c - 1),
-                     n * out_c)
-    flat = jnp.where(cell < out_c, flat, n * out_c)
-
-    # in-bounds indices are unique (one per run end) but the drop sentinel
-    # is duplicated, so no unique_indices hint — mode="drop" discards
-    # sentinels. ONE helper so the flat-index/sentinel scheme lives in
-    # one place for all four scatters below.
-    def scatter_at_run_ends(vals):
-        return jnp.zeros((n * out_c,), w.dtype).at[flat.ravel()].set(
-            vals.ravel(), mode="drop").reshape(n, out_c)
-
-    end_w = jnp.zeros((n * out_c,), w.dtype).at[flat.ravel()].max(
-        cum.ravel(), mode="drop").reshape(n, out_c)
-    end_wm = scatter_at_run_ends(cum_wm)
-    # forward-fill: empty cells carry the previous cumulative
-    fill_w = jax.lax.cummax(end_w, axis=1)
-    has = end_w > 0
-    # cum_wm can legitimately be non-monotone only if means are negative; track
-    # occupancy explicitly instead of relying on positivity.
-    end_wm = jnp.where(has, end_wm, 0.0)
-    idx = jax.lax.cummax(jnp.where(has, jnp.arange(out_c, dtype=jnp.int32)[None, :], 0), axis=1)
-    fill_wm = jnp.take_along_axis(end_wm, idx, axis=1)
-    w_out = fill_w - jnp.concatenate(
-        [jnp.zeros((n, 1), w.dtype), fill_w[:, :-1]], axis=1)
-    wm_out = fill_wm - jnp.concatenate(
-        [jnp.zeros((n, 1), w.dtype), fill_wm[:, :-1]], axis=1)
-    # SINGLE-entry runs bypass the cumulative diff entirely: differencing
-    # two ~total-magnitude cumulatives costs f32 ulps of the TOTAL (at a
-    # 2^20-weight row that's ~0.1 absolute on a weight-1 centroid), which
-    # would erode exactly the protected extremes this compress exists to
-    # keep raw. Their (m, w) scatter through VERBATIM — bit-exact, no
-    # multiply/divide round-trip. (cell == out_c entries are already the
-    # drop sentinel in `flat`, so no extra mask is needed.)
-    is_first = jnp.concatenate(
-        [jnp.ones((n, 1), bool), cell[:, 1:] != cell[:, :-1]], axis=1)
-    single = is_first & is_last
-    w_single = scatter_at_run_ends(jnp.where(single, w, 0.0))
-    m_single = scatter_at_run_ends(jnp.where(single, m, 0.0))
-    w_out = jnp.where(w_single > 0, w_single, w_out)
-    m_out = jnp.where(
-        w_single > 0, m_single,
-        jnp.where(w_out > 0, wm_out / jnp.maximum(w_out, 1e-30), 0.0))
+    rows = _sorted_cells(
+        mean.reshape((-1, mean.shape[-1])),
+        weight.reshape((-1, weight.shape[-1])),
+        compression=compression, cells_per_k=cells_per_k, out_c=out_c,
+        exact_extremes=exact_extremes)
+    m_out, w_out = jax.lax.map(partial(_reduce_row, out_c=out_c), rows,
+                               batch_size=REDUCE_ROW_BLOCK)
     return (m_out.reshape(lead + (out_c,)), w_out.reshape(lead + (out_c,)))
 
 
