@@ -510,3 +510,36 @@ def test_set_member_invalid_utf8_survives_python_path():
     assert (b.s_slot[0] < agg.spec.set_capacity
             and b.s_reg[0] == reg
             and b.s_rho[0] == rho), "member bytes must round-trip"
+
+
+@pytest.mark.parametrize("steps", [3, 21])
+def test_dispatch_counts_compactions_and_bounds_steps_in_flight(steps):
+    """_count_step: every compact_every-th step of the interval carries
+    the compaction and hands it the whole digest table. _dispatch_step:
+    the host never has more than _MAX_STEPS_IN_FLIGHT steps queued that
+    it has not seen finish, and the answer is what it was without the
+    bound."""
+    from veneur_tpu.samplers import parser
+    from veneur_tpu.server import aggregator as aggregator_mod
+
+    spec = TableSpec(counter_capacity=64, gauge_capacity=16,
+                     status_capacity=8, set_capacity=16, histo_capacity=32)
+    agg = aggregator_mod.Aggregator(
+        spec, BatchSpec(counter=4, gauge=4, status=4, set=4, histo=4),
+        compact_every=2)
+    seen = []
+    for i in range(steps * 4):
+        agg.process_metric(parser.parse_metric(b"t.%d:%d|ms" % (i % 3, i)))
+        agg.process_metric(parser.parse_metric(b"c.hot:2|c"))
+        seen.append(len(agg._steps_in_flight))
+    assert agg.steps_total >= steps
+    assert max(seen) == min(agg.steps_total,
+                            aggregator_mod._MAX_STEPS_IN_FLIGHT)
+    assert agg.compactions == agg.steps_total // 2
+    assert agg.compact_rows == agg.compactions * spec.histo_capacity
+    out, table = agg.flush([0.5])
+    slot = {m.name: s for s, m in table.get_meta("counter")}["c.hot"]
+    assert float(np.asarray(out["counter"])[slot]) == steps * 4 * 2
+    by_name = {m.name: s for s, m in table.get_meta("histo")}
+    assert sum(float(np.asarray(out["histo_count"])[s])
+               for s in by_name.values()) == steps * 4

@@ -10,9 +10,11 @@ flush math runs on the detached state while new samples accumulate.
 
 from __future__ import annotations
 
+import collections
 import time
 from typing import Dict, List, Tuple
 
+import jax
 import numpy as np
 
 from veneur_tpu.aggregation.host import Batcher, BatchSpec, KeyTable
@@ -41,12 +43,31 @@ def set_member_bytes(value) -> bytes:
 # comment); every backend's dispatch loop shares it
 _SYNC_EVERY = 64
 
+# Ingest steps the host may have dispatched without having seen them
+# finish. Where the device is the slower side (a compaction of a 131072-row
+# digest table is 103 ms on the v5e against ~8 ms to parse a batch) the host
+# otherwise runs ahead until the runtime's own queue is full, ~30 steps or
+# 0.55 s of device work: the 64th-step sync then stops the pipeline thread
+# for all of it, parsing nothing, and a tick waits for whatever part of it
+# it finds queued (PERF.md, PR 30). Four keeps the device fed across a
+# compaction (it needs two) and a tick's wait under one compaction.
+_MAX_STEPS_IN_FLIGHT = 4
+
+# a step's completion, as an array that survives the state's donation to
+# the next step: one element of the smallest leaf, read after the step
+_step_done = jax.jit(lambda stamp: stamp[..., :1])
+
 
 class Aggregator:
     # optional tables.pressure.TablePressure shared across intervals;
     # class attribute so every backend (ShardedAggregator skips this
     # __init__) starts without one
     _pressure = None
+    # steps that carried the in-band compaction and the digest rows
+    # they handed to compress_rows, monotonic like steps_total
+    # (_count_step); class attributes for the same reason
+    compactions = 0
+    compact_rows = 0
 
     def __init__(self, spec: TableSpec, bspec: BatchSpec = BatchSpec(),
                  n_shards: int = 1, compact_every: int = 8):
@@ -81,6 +102,7 @@ class Aggregator:
         self.dispatch_ns = 0
         self.steps_total = 0
         self.steps_synced = 0
+        self._steps_in_flight = collections.deque()
         # persistent pack targets, two per lane-size signature: batch N+1
         # packs into one buffer while batch N's h2d + donated step is
         # still in flight against the other (pack_batch `out` contract)
@@ -166,8 +188,7 @@ class Aggregator:
     def _on_batch(self, batch):
         # one packed H2D transfer per step; compaction rides the same
         # program via the control word (step.py pack_batch rationale)
-        self._steps += 1
-        self.steps_total += 1
+        compacts = self._count_step()
         sizes = batch_sizes(batch)
         bufs = self._pack_bufs.get(sizes)
         if bufs is None:
@@ -180,24 +201,43 @@ class Aggregator:
                 np.zeros(words, np.int32), np.zeros(words, np.int32), 0]
         flat = bufs[bufs[2]]
         bufs[2] ^= 1
-        pack_batch(batch, self._steps % self.compact_every == 0, out=flat)
+        pack_batch(batch, compacts, out=flat)
         self.h2d_bytes += flat.nbytes
         self._dispatch_step(ingest_step_packed, flat, spec=self.spec,
                             sizes=sizes)
+
+    def _count_step(self) -> bool:
+        """Count one more ingest step, and say whether it carries the
+        in-band compaction: every compact_every-th step of the interval
+        does, and hands compress_rows every row of the digest table
+        (spec.histo_capacity: all shards' rows on the sharded backend),
+        live or not."""
+        self._steps += 1
+        self.steps_total += 1
+        compacts = self._steps % self.compact_every == 0
+        if compacts:
+            self.compactions += 1
+            self.compact_rows += self.spec.histo_capacity
+        return compacts
 
     def _dispatch_step(self, step, flat, **static) -> None:
         """The one ingest dispatch every backend's step site goes through
         (here, the native packed and ring emits, the sharded row):
         `self.state = step(self.state, flat, **static)` under the
         `pipeline.dispatch` span, its host time summed into dispatch_ns
-        (a full device queue blocks inside the call, so this is queue
-        wait as much as enqueue), and every _SYNC_EVERY-th step the
-        sampled sync under `pipeline.sampled_sync`. That sync waits for
-        everything queued, so step_ns reads the queue's drain, not one
-        step's device time."""
+        (with _MAX_STEPS_IN_FLIGHT steps already queued it first waits
+        for the oldest to finish, so this is queue wait as much as
+        enqueue), and every _SYNC_EVERY-th step the sampled sync under
+        `pipeline.sampled_sync`. That sync waits for everything queued,
+        so step_ns reads the queue's drain, not one step's device
+        time."""
+        in_flight = self._steps_in_flight
         with hostspans.span("pipeline.dispatch"):
             t0 = time.perf_counter_ns()
+            if len(in_flight) == _MAX_STEPS_IN_FLIGHT:
+                jaxruntime.sync_and_time(in_flight.popleft())
             self.state = step(self.state, flat, **static)
+            in_flight.append(_step_done(self.state.status_stamp))
             dispatch_dt = time.perf_counter_ns() - t0
         self.dispatch_ns += dispatch_dt
         if self.steps_total % _SYNC_EVERY == 0:

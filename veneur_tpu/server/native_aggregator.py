@@ -267,9 +267,7 @@ class NativeAggregator(Aggregator):
         if nc + ng + ns + nh == 0:
             return
         self._pk_idx = 1 - idx
-        self._steps += 1
-        self.steps_total += 1
-        flat[0] = 1 if self._steps % self.compact_every == 0 else 0
+        flat[0] = 1 if self._count_step() else 0
         self.h2d_bytes += flat.nbytes
         self._dispatch_step(ingest_step_packed, flat, spec=self.spec,
                             sizes=self._pk_sizes)
@@ -377,9 +375,7 @@ class NativeAggregator(Aggregator):
         # more often than a step) must leave no record
         hostspans.record("pipeline.emit", t0, time.monotonic_ns())
         self._rg_idx = 1 - idx
-        self._steps += 1
-        self.steps_total += 1
-        arena[0, 0] = 1 if self._steps % self.compact_every == 0 else 0
+        arena[0, 0] = 1 if self._count_step() else 0
         self.h2d_bytes += arena.nbytes
         self._dispatch_step(ingest_step_packed_rings, arena, spec=self.spec,
                             sizes=self._pk_sizes)
@@ -430,8 +426,11 @@ class NativeAggregator(Aggregator):
         """Deep ring/emit telemetry (vr_stats): depth, high-water, pump
         batches/stalls, emit_packed call/ns totals. Any thread. In
         multi-ring mode this is the EXACT cross-ring aggregate (sums;
-        high-water is the per-ring max)."""
-        return self.eng.ring_stats()
+        high-water is the per-ring max). With them the two counts of the
+        steps those emits fed that the engine does not keep: compactions
+        and the digest rows they re-compressed (Aggregator._count_step)."""
+        return {**self.eng.ring_stats(), "compactions": self.compactions,
+                "compact_rows": self.compact_rows}
 
     def ring_stats_per_ring(self) -> List[dict]:
         """Per-ring telemetry rows ([] outside multi-ring mode) — the

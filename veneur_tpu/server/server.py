@@ -825,6 +825,16 @@ class Server:
         M.callback("veneur.device.steps_total",
                    lambda: getattr(self.aggregator, "steps_total", 0),
                    kind="counter", help="device ingest steps dispatched")
+        M.callback("veneur.device.compactions_total",
+                   lambda: getattr(self.aggregator, "compactions", 0),
+                   kind="counter",
+                   help="ingest steps that carried the in-band digest "
+                        "compaction (every tpu_compact_every-th)")
+        M.callback("veneur.device.compact_rows_total",
+                   lambda: getattr(self.aggregator, "compact_rows", 0),
+                   kind="counter",
+                   help="digest rows those compactions re-compressed "
+                        "(the whole table each time)")
         M.callback("veneur.device.steps_synced_total",
                    lambda: getattr(self.aggregator, "steps_synced", 0),
                    kind="counter",

@@ -18,6 +18,7 @@ about device scale-out, not host parse throughput).
 
 from __future__ import annotations
 
+import collections
 from functools import partial
 from typing import List, Tuple
 
@@ -155,6 +156,7 @@ class ShardedAggregator(Aggregator):
         self.dispatch_ns = 0
         self.steps_total = 0
         self.steps_synced = 0
+        self._steps_in_flight = collections.deque()
         self._init_degrade()
 
     # -- slot routing --------------------------------------------------------
@@ -271,9 +273,7 @@ class ShardedAggregator(Aggregator):
         alternate so step N+1 packs while step N's transfer is in
         flight."""
         from veneur_tpu.aggregation.step import pack_batch, packed_layout
-        self._steps += 1
-        self.steps_total += 1
-        dc = self._steps % self.compact_every == 0
+        dc = self._count_step()
         bufs = getattr(self, "_row_bufs", None)
         if bufs is None:
             words = packed_layout(self._sizes)[1]
