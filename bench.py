@@ -581,7 +581,8 @@ def kernel_main():
         dc = not no_compact and (i + 1) % compact_every == 0
         flat = flats[True][i % n_batches] if dc else \
             flats[False][i % n_batches]
-        state = ingest_step_packed(state, flat, spec=spec, sizes=sizes)
+        state, _rows = ingest_step_packed(state, flat, spec=spec,
+                                          sizes=sizes)
         uses[i % n_batches] += 1
         return state
 

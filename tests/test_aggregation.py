@@ -450,16 +450,20 @@ def test_packed_batch_roundtrip_and_ingest_parity():
 
     # full ingest parity, with and without the fused compact branch
     ref = fold_scalars(ingest_step(empty_state(SPEC), b, spec=SPEC))
-    packed = ingest_step_packed(empty_state(SPEC), pack_batch(b),
-                                spec=SPEC, sizes=sizes)
+    packed, rows = ingest_step_packed(empty_state(SPEC), pack_batch(b),
+                                      spec=SPEC, sizes=sizes)
+    assert int(rows) == 0
     for name, a, c in zip(ref._fields, ref, packed):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(c), err_msg=name)
     ref_c = compact(fold_scalars(ingest_step(empty_state(SPEC), b,
                                              spec=SPEC)), spec=SPEC)
-    packed_c = ingest_step_packed(empty_state(SPEC),
-                                  pack_batch(b, do_compact=True),
-                                  spec=SPEC, sizes=sizes)
+    packed_c, rows = ingest_step_packed(empty_state(SPEC),
+                                        pack_batch(b, do_compact=True),
+                                        spec=SPEC, sizes=sizes)
+    assert int(rows) == len(np.unique(
+        b.histo_slot[(b.histo_slot < SPEC.histo_capacity)
+                     & (b.histo_wt > 0)]))
     for name, a, c in zip(ref_c._fields, ref_c, packed_c):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(c), err_msg=name)
@@ -479,8 +483,8 @@ def test_packed_batch_none_stat_lanes():
     sizes = batch_sizes(b)
     assert sizes[-4:] == (0, 0, 0, 0)
     ref = fold_scalars(ingest_step(empty_state(SPEC), b, spec=SPEC))
-    packed = ingest_step_packed(empty_state(SPEC), pack_batch(b),
-                                spec=SPEC, sizes=sizes)
+    packed, _rows = ingest_step_packed(empty_state(SPEC), pack_batch(b),
+                                       spec=SPEC, sizes=sizes)
     for name, a, c in zip(ref._fields, ref, packed):
         np.testing.assert_array_equal(
             np.asarray(a), np.asarray(c), err_msg=name)
@@ -512,10 +516,31 @@ def test_set_member_invalid_utf8_survives_python_path():
             and b.s_rho[0] == rho), "member bytes must round-trip"
 
 
+def _unaliased_pack_bufs(agg):
+    """The CPU backend reads a 64-byte-aligned host array in place, and
+    the aggregator packs step N+2 into step N's buffer while that step
+    may still be queued, so on the CPU (only) a queued step can see a
+    later step's words, the control word among them (ROADMAP D9). Steered
+    here, as perfbench/tests/conftest.py does: packed buffers that cannot
+    be read in place."""
+    from veneur_tpu.aggregation.step import batch_sizes, packed_layout
+    sizes = batch_sizes(Batcher(agg.spec, agg.bspec).force_emit())
+    words = packed_layout(sizes)[1]
+    bufs = []
+    for _ in range(2):
+        raw = np.zeros(words + 32, np.int32)
+        skip = next(k for k in range(1, 17)
+                    if (raw.ctypes.data + 4 * k) % 64)
+        bufs.append(raw[skip:skip + words])
+    agg._pack_bufs[sizes] = bufs + [0]
+    return agg
+
+
 @pytest.mark.parametrize("steps", [3, 21])
 def test_dispatch_counts_compactions_and_bounds_steps_in_flight(steps):
     """_count_step: every compact_every-th step of the interval carries
-    the compaction and hands it the whole digest table. _dispatch_step:
+    the compaction, which compresses the three rows that took samples
+    (the count is settled at the swap at the latest). _dispatch_step:
     the host never has more than _MAX_STEPS_IN_FLIGHT steps queued that
     it has not seen finish, and the answer is what it was without the
     bound."""
@@ -524,9 +549,9 @@ def test_dispatch_counts_compactions_and_bounds_steps_in_flight(steps):
 
     spec = TableSpec(counter_capacity=64, gauge_capacity=16,
                      status_capacity=8, set_capacity=16, histo_capacity=32)
-    agg = aggregator_mod.Aggregator(
+    agg = _unaliased_pack_bufs(aggregator_mod.Aggregator(
         spec, BatchSpec(counter=4, gauge=4, status=4, set=4, histo=4),
-        compact_every=2)
+        compact_every=2))
     seen = []
     for i in range(steps * 4):
         agg.process_metric(parser.parse_metric(b"t.%d:%d|ms" % (i % 3, i)))
@@ -536,10 +561,191 @@ def test_dispatch_counts_compactions_and_bounds_steps_in_flight(steps):
     assert max(seen) == min(agg.steps_total,
                             aggregator_mod._MAX_STEPS_IN_FLIGHT)
     assert agg.compactions == agg.steps_total // 2
-    assert agg.compact_rows == agg.compactions * spec.histo_capacity
     out, table = agg.flush([0.5])
+    assert not agg._steps_in_flight
+    assert agg.compact_rows == agg.compactions * 3
     slot = {m.name: s for s, m in table.get_meta("counter")}["c.hot"]
     assert float(np.asarray(out["counter"])[slot]) == steps * 4 * 2
     by_name = {m.name: s for s, m in table.get_meta("histo")}
     assert sum(float(np.asarray(out["histo_count"])[s])
                for s in by_name.values()) == steps * 4
+
+
+# -- compaction over the dirty rows (PERF.md PR 31) ---------------------------
+
+SMALL = TableSpec(counter_capacity=64, gauge_capacity=32, status_capacity=8,
+                  set_capacity=16, histo_capacity=64, hll_precision=8,
+                  temp_cells=16)
+SMALL_B = BatchSpec(counter=64, gauge=64, status=64, set=64, histo=64)
+
+
+def _whole_table_compact(h_w, h_wm, spec):
+    """The form compact_core had until PR 31, kept as the oracle:
+    compress_rows on every row, whatever it holds."""
+    import jax.numpy as jnp
+    from veneur_tpu.ops import tdigest as td
+    m2, w2 = td.compress_rows(
+        h_wm / jnp.maximum(h_w, 1e-30), h_w, compression=spec.compression,
+        cells_per_k=spec.cells_per_k, out_c=spec.centroids,
+        exact_extremes=spec.exact_extremes)
+    pad = jnp.zeros(w2.shape[:-1] + (spec.temp_cells,), w2.dtype)
+    return (np.asarray(jnp.concatenate([w2, pad], axis=-1)),
+            np.asarray(jnp.concatenate([m2 * w2, pad], axis=-1)))
+
+
+@pytest.mark.parametrize("fused", [False, True],
+                         ids=["xla-chain", "fused-kernel"])
+def test_a_row_changes_only_where_temp_n_marks_it(fused):
+    """What compact_core leans on: between two compactions a row's h_w /
+    h_wm differ from their post-compaction bytes only if its h_temp_n is
+    above 0, in both ingest forms; and h_temp_n is above 0 exactly on the
+    rows that took a sample. Hot rows overflow their temp cells here (16
+    of them), so samples land in estimate cells too."""
+    import jax
+    from functools import partial
+    from veneur_tpu.aggregation.step import compact_core, ingest_core
+    from veneur_tpu.ops import pallas_ingest
+
+    spec, kh = SMALL, SMALL.histo_capacity
+    rng = np.random.default_rng(5)
+    pallas_ingest.set_enabled(fused)
+    try:
+        ingest = jax.jit(partial(ingest_core, spec=spec))
+        compact_rows = jax.jit(partial(compact_core, spec=spec))
+        state = empty_state(spec)
+        for cycle in range(3):
+            w0, wm0 = np.asarray(state.h_w), np.asarray(state.h_wm)
+            assert not np.asarray(state.h_temp_n).any()
+            touched = np.zeros(kh, bool)
+            for _ in range(3):
+                b = _empty_batch(spec, SMALL_B)
+                n = 48
+                # a few hot rows, a sparse rest, and rows no batch names
+                slot = np.where(rng.random(n) < 0.5,
+                                rng.integers(0, 3, n),
+                                rng.integers(0, kh // 2, n)).astype(np.int32)
+                slot[rng.integers(0, n, 4)] = kh + 3        # dropped
+                wt = rng.uniform(0.5, 2, n).astype(np.float32)
+                wt[rng.integers(0, n, 6)] = 0.0             # dropped
+                b.histo_slot[:n] = slot
+                b.histo_val[:n] = rng.gamma(2.0, 15.0, n)
+                b.histo_wt[:n] = wt
+                touched[slot[(slot < kh) & (wt > 0)]] = True
+                state = ingest(state, b)
+                marked = np.asarray(state.h_temp_n) > 0
+                changed = ((np.asarray(state.h_w) != w0).any(axis=1)
+                           | (np.asarray(state.h_wm) != wm0).any(axis=1))
+                assert not (changed & ~marked).any()
+                np.testing.assert_array_equal(marked, touched)
+            assert np.asarray(state.h_temp_n).max() == spec.temp_cells
+            state = compact_rows(state)
+    finally:
+        pallas_ingest.set_enabled(None)
+
+
+def _random_tables(rng, lead, spec, dirty_counts):
+    """Digest tables of arbitrary bytes with h_temp_n marking
+    dirty_counts[i] random rows of the i-th table."""
+    kh, cells = spec.histo_capacity, spec.total_cells
+    w = rng.integers(0, 3, lead + (kh, cells)).astype(np.float32)
+    wm = w * rng.gamma(2.0, 15.0, w.shape).astype(np.float32)
+    tn = np.zeros((len(dirty_counts), kh), np.int32)
+    for row, d in zip(tn, dirty_counts):
+        row[rng.permutation(kh)[:d]] = rng.integers(1, 17, d)
+    return w, wm, tn.reshape(lead + (kh,))
+
+
+@pytest.mark.parametrize("rows,block,dirty", [
+    (64, 16, (0,)), (64, 16, (16,)), (64, 16, (21,)), (64, 16, (64,)),
+    (72, 16, (70,)), (64, 1024, (21,)), (64, 16, (0, 21, 64))],
+    ids=["none-dirty", "one-whole-block", "not-a-multiple-of-the-block",
+         "all-dirty", "table-not-whole-blocks", "block-above-the-table",
+         "vmap-vmap-unequal-shards"])
+def test_compact_core_compresses_the_dirty_rows_and_no_other(
+        rows, block, dirty, monkeypatch):
+    """compact_core against the whole-table form: a dirty row comes out
+    bit-identical to compress_rows' row, a clean row bit-identical to
+    what went in, h_temp_n all zero. The last case is the sharded step's
+    shape, vmap(vmap(compact_core)) over [1, 3] tiles whose dirty counts
+    differ, so the loop runs to the largest."""
+    import dataclasses
+    import jax
+    from functools import partial
+    from veneur_tpu.aggregation import step
+
+    monkeypatch.setattr(step, "COMPACT_ROW_BLOCK", block)
+    spec = dataclasses.replace(SMALL, histo_capacity=rows)
+    lead = (1, len(dirty)) if len(dirty) > 1 else ()
+    w, wm, tn = _random_tables(np.random.default_rng(31), lead, spec, dirty)
+    fn = partial(step.compact_core, spec=spec)
+    state = empty_state(spec)
+    if lead:
+        fn = jax.vmap(jax.vmap(fn))
+        state = jax.tree.map(
+            lambda a: np.broadcast_to(np.asarray(a), lead + a.shape), state)
+    out = jax.jit(fn)(state._replace(h_w=w, h_wm=wm, h_temp_n=tn))
+    want_w, want_wm = _whole_table_compact(w, wm, spec)
+    took = (tn > 0)[..., None]
+    assert [int(d) for d in took.sum(axis=(-2, -1)).ravel()] == list(dirty)
+    np.testing.assert_array_equal(np.asarray(out.h_w),
+                                  np.where(took, want_w, w))
+    np.testing.assert_array_equal(np.asarray(out.h_wm),
+                                  np.where(took, want_wm, wm))
+    assert not np.asarray(out.h_temp_n).any()
+    for name in set(out._fields) - {"h_w", "h_wm", "h_temp_n"}:
+        np.testing.assert_array_equal(np.asarray(getattr(out, name)),
+                                      np.asarray(getattr(state, name)))
+
+
+def test_compressing_a_canonical_row_again_only_coarsens():
+    """The ground for leaving clean rows alone: a row compress_rows made,
+    fed to it again, keeps its total weight and occupies no more cells
+    than it did. The second pass adds no sample; where it differs at all
+    it has folded two neighbours at a cell's edge."""
+    from veneur_tpu.ops import tdigest as td
+    rng = np.random.default_rng(7)
+    n, m_len = 48, SMALL.total_cells
+    w = np.zeros((n, m_len), np.float32)
+    for row, k in zip(w, rng.integers(1, m_len + 1, n)):
+        row[rng.permutation(m_len)[:k]] = rng.integers(1, 9, k)
+    mean = rng.gamma(2.0, 15.0, w.shape).astype(np.float32)
+    kw = dict(compression=SMALL.compression, cells_per_k=SMALL.cells_per_k,
+              out_c=SMALL.centroids, exact_extremes=SMALL.exact_extremes)
+    m1, w1 = td.compress_rows(mean, w, **kw)
+    m2, w2 = td.compress_rows(m1, w1, **kw)
+    w1, w2 = np.asarray(w1), np.asarray(w2)
+    np.testing.assert_array_equal(w2.sum(axis=1), w.sum(axis=1))
+    np.testing.assert_array_equal(w1.sum(axis=1), w.sum(axis=1))
+    assert ((w2 > 0).sum(axis=1) <= (w1 > 0).sum(axis=1)).all()
+
+
+@pytest.mark.parametrize("kind", ["timers", "counters-only"])
+def test_compact_rows_is_the_devices_count_of_rows_that_took_samples(kind):
+    """Over an interval compact_rows equals the distinct timer slots of
+    each compaction group, added up (counted here in NumPy from the
+    stream); a stream with no timer compacts just as often and
+    compresses no row."""
+    from veneur_tpu.samplers import parser
+    from veneur_tpu.server.aggregator import Aggregator
+
+    lane, every, n = 4, 3, 90
+    agg = _unaliased_pack_bufs(Aggregator(
+        SMALL, BatchSpec(counter=lane, gauge=lane, status=lane, set=lane,
+                         histo=lane), compact_every=every))
+    rng = np.random.default_rng(3)
+    names = rng.integers(0, 40, n)
+    for i, name in enumerate(names):
+        line = (b"t.%d:%d|ms" % (name, i) if kind == "timers"
+                else b"c.%d:1|c" % name)
+        agg.process_metric(parser.parse_metric(line))
+    agg.flush([0.5])
+    # a step takes `lane` samples (the swap emits the remainder as one
+    # more); every `every`-th step compacts what its group touched
+    steps = -(-n // lane)
+    assert agg.steps_total == steps
+    assert agg.compactions == steps // every > 2
+    group = lane * every
+    want = sum(len(set(names[g * group:(g + 1) * group]))
+               for g in range(steps // every))
+    assert agg.compact_rows == (want if kind == "timers" else 0)
+    assert want > agg.compactions * 5

@@ -142,9 +142,10 @@ def serve_stream(bench, tmp_path, seed, overrides):
         out.close()
         stats = agg.eng.stats()
         assert stats["dropped"] == 0 and stats["parse_errors"] == 0
-        # where the benchmark reads compact_rows_per_step
-        assert agg.ring_stats()["compact_rows"] == (
-            agg.compactions * HISTO_ROWS)
+        # where the benchmark reads compact_rows_per_step: the device's
+        # count of the rows that took samples, never the table's height
+        assert 0 < agg.ring_stats()["compact_rows"] <= (
+            agg.compactions * TRAFFIC["kinds"]["timer"]["names"])
         assert server.internal_errors == 0
     finally:
         server.shutdown()

@@ -148,21 +148,63 @@ def test_packed_ingest_program_compiles_under_1gib(one_chip, served):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def test_compaction_compiles_without_scatter_or_gather(one_chip, served):
-    """compact_core over the whole digest table (16384 x 472 -> 280 as
-    shipped): the chip's compiler fuses compress_rows' compare and select
-    into its reduces, so the program holds a few [n, M] arrays and nothing
-    the size of the [n, M, out_c] compare (8.7 GB at the shipped height),
-    and no scatter or gather (the scatter form took 0.57 s a call on the
-    v5e; PERF.md PR 29)."""
+def test_compress_rows_compiles_without_scatter_or_gather(one_chip, served):
+    """compress_rows on one block of compact_core's loop, [R, 472] -> 280
+    as shipped: the chip's compiler fuses its compare and select into its
+    reduces, so the program holds a few [R, M] arrays and nothing the
+    size of the [R, M, out_c] compare, and no scatter or gather (the
+    scatter form took 0.57 s a call on the v5e; PERF.md PR 29). The
+    block does not grow with the table, so both heights compile the same
+    program."""
+    from veneur_tpu.ops import tdigest as td
+    spec = served[0]
+    r, m_len = step.COMPACT_ROW_BLOCK, spec.total_cells
+    rows = jax.ShapeDtypeStruct((r, m_len), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(partial(
+        td.compress_rows, compression=spec.compression,
+        cells_per_k=spec.cells_per_k, out_c=spec.centroids,
+        exact_extremes=spec.exact_extremes)).lower(rows, rows).compile()
+    text = compiled.as_text()
+    assert " scatter(" not in text and " gather(" not in text
+    assert " sort(" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 8 * r * m_len * 4, mem
+
+
+def test_compaction_compiles_with_whole_row_copies_only(one_chip, served):
+    """compact_core over the digest table at both heights: the only
+    gathers and scatters of table rows are the loop's copies of whole
+    rows (slice width = total_cells; an element-wise gather inside a row
+    is what PR 29 took out), told that their ids are sorted and unique,
+    and the temporaries stay under a few tables' worth (two of them are
+    the row-major copies the row copies work on; the device keeps the
+    tables column-major)."""
+    import re
     spec = served[0]
     compiled = jax.jit(partial(step.compact_core, spec=spec),
                        donate_argnums=(0,)).lower(
         _state_shapes(spec, one_chip)).compile()
     text = compiled.as_text()
-    assert " scatter(" not in text and " gather(" not in text
-    assert " sort(" in text
     n, m_len = spec.histo_capacity, spec.total_cells
+    table = re.escape(f"f32[{n},{m_len}]")
+    block = re.escape(f"f32[{step.COMPACT_ROW_BLOCK},{m_len}]")
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
+    row_gathers = [ln for ln in gathers if re.search(
+        block + r"\S* gather\(", ln)]
+    assert len(row_gathers) == 2 and len(scatters) == 2, (gathers, scatters)
+    for ln in row_gathers:
+        assert f"slice_sizes={{1,{m_len}}}" in ln, ln
+        assert "indices_are_sorted=true" in ln, ln
+    for ln in scatters:
+        assert re.search(table + r"\S* scatter\(", ln), ln
+        assert "update_window_dims={1}" in ln, ln
+        assert "indices_are_sorted=true" in ln, ln
+        assert "unique_indices=true" in ln, ln
+    # any other gather moves no table cell: none yields a float array
+    for ln in gathers:
+        assert ln in row_gathers or " f32[" not in ln.split(" gather(")[0], ln
+    assert " sort(" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 8 * n * m_len * 4, mem
 

@@ -112,7 +112,11 @@ class DeviceState(NamedTuple):
     # cells holding individual samples since the last compaction.
     h_wm: jax.Array          # f32[Kh, C+T]  sum of weight*mean per cell
     h_w: jax.Array           # f32[Kh, C+T]
-    h_temp_n: jax.Array      # i32[Kh] samples absorbed since last compact
+    # h_temp_n is the dirty mark too: a row's first sample of a cycle always
+    # lands in a temp cell (step._histo_plan), so h_temp_n > 0 exactly on
+    # the rows that took a sample since the last compaction, and those are
+    # the rows step.compact_core compresses.
+    h_temp_n: jax.Array      # i32[Kh] temp cells used since last compact
     h_min: jax.Array         # f32[Kh]
     h_max: jax.Array         # f32[Kh]
     h_count_acc: jax.Array   # f32[Kh] + two-float, like counters

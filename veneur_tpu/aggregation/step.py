@@ -349,13 +349,16 @@ def unpack_batch(flat, sizes: tuple) -> Batch:
 
 
 def packed_step_core(state: DeviceState, flat, *, spec: TableSpec,
-                     sizes: tuple) -> DeviceState:
+                     sizes: tuple):
     """The un-jitted production step: ingest one packed batch; when the
-    control word is set, re-compress the digest rows in the SAME program
-    (lax.cond — only the taken branch executes). Folding compaction in
-    keeps the steady-state hot loop at ONE resident executable and one
-    dispatch per batch. Shared by ingest_step_packed and the driver
-    entry (__graft_entry__.entry)."""
+    control word is set, re-compress the digest rows that took samples
+    in the SAME program (lax.cond — only the taken branch executes).
+    Folding compaction in keeps the steady-state hot loop at ONE resident
+    executable and one dispatch per batch. Returns (state, rows): rows
+    is i32[], the digest rows this step compressed, 0 where the control
+    word is clear — the device's own count behind `compact_rows`, and
+    the small array by which the host knows the step finished. Shared by
+    ingest_step_packed and the driver entry (__graft_entry__.entry)."""
     with jax.named_scope("unpack"):
         batch = unpack_batch(flat[1:], sizes)
     state = ingest_core(state, batch, spec=spec)
@@ -364,10 +367,12 @@ def packed_step_core(state: DeviceState, flat, *, spec: TableSpec,
     # `maybe_compact`, the ops of its taken branch `compact`. (The other
     # branch is the identity and has no op to name.)
     with jax.named_scope("maybe_compact"):
+        do_compact = flat[0] != 0
+        rows = jnp.where(do_compact, dirty_rows(state), 0)
         return jax.lax.cond(
-            flat[0] != 0,
+            do_compact,
             jax.named_scope("compact")(partial(compact_core, spec=spec)),
-            lambda s: s, state)
+            lambda s: s, state), rows
 
 
 ingest_step_packed = partial(
@@ -376,7 +381,7 @@ ingest_step_packed = partial(
 
 
 def packed_rings_core(state: DeviceState, arena, *, spec: TableSpec,
-                      sizes: tuple) -> DeviceState:
+                      sizes: tuple):
     """Multi-ring step: `arena` is i32[R, words] — one packed row per
     reader ring, all shipped in ONE host->device transfer (the multi-ring
     pipeline's whole point: R rings cost one RTT, not R). The loop is
@@ -387,14 +392,15 @@ def packed_rings_core(state: DeviceState, arena, *, spec: TableSpec,
     scalar-prefetch windows unchanged. Idle rings ride as sentinel-only
     rows whose scatters all drop; the host skips the step entirely when
     every ring emitted zero rows. Only row 0 carries the compact control
-    word — one compaction per step, exactly like the single-ring path."""
+    word — one compaction per step, exactly like the single-ring path,
+    and its count of rows is the step's (packed_step_core)."""
     n_rings = arena.shape[0]
-    state = packed_step_core(state, arena[0], spec=spec, sizes=sizes)
+    state, rows = packed_step_core(state, arena[0], spec=spec, sizes=sizes)
     for r in range(1, n_rings):
         with jax.named_scope("unpack"):
             batch = unpack_batch(arena[r][1:], sizes)
         state = ingest_core(state, batch, spec=spec)
-    return state
+    return state, rows
 
 
 ingest_step_packed_rings = partial(
@@ -421,20 +427,68 @@ def _fold_core(state: DeviceState) -> DeviceState:
 fold_scalars = jax.jit(_fold_core)
 
 
+def dirty_rows(state: DeviceState) -> jax.Array:
+    """i32[]: the digest rows that took a sample since the last
+    compaction, which are the rows the next one compresses."""
+    return jnp.sum(state.h_temp_n > 0, dtype=jnp.int32)
+
+
+# Dirty rows one trip of compact_core's loop takes: it gathers that many
+# whole rows of both digest tables, compresses them and writes them back.
+# Chosen on the v5e at 131072 x 472 (PERF.md, PR 31): XLA's row scatter
+# costs a pass over the table it writes, 0.8 ms a call however few the
+# rows, so few large trips beat many small ones (22,400 dirty rows: 153 ms
+# in blocks of 256, 50 in 1024, 27 in 4096, 21 in 8192, 27 in 16384, where
+# the last block's idle rows cost more than a trip saves).
+COMPACT_ROW_BLOCK = 8192
+
+
 def compact_core(state: DeviceState, *, spec: TableSpec) -> DeviceState:
-    """Re-compress every digest row — canonical k-cells AND raw temp cells —
-    into canonical k-cells, emptying temp. Amortized analogue of the
-    reference's mergeAllTemps (merging_digest.go:140)."""
-    mean = state.h_wm / jnp.maximum(state.h_w, 1e-30)
-    m2, w2 = td.compress_rows(mean, state.h_w, compression=spec.compression,
-                              cells_per_k=spec.cells_per_k,
-                              out_c=spec.centroids,
-                              exact_extremes=spec.exact_extremes)
-    pad = jnp.zeros(w2.shape[:-1] + (spec.temp_cells,), w2.dtype)
-    return state._replace(
-        h_wm=jnp.concatenate([m2 * w2, pad], axis=-1),
-        h_w=jnp.concatenate([w2, pad], axis=-1),
-        h_temp_n=jnp.zeros_like(state.h_temp_n))
+    """Re-compress the digest rows that took a sample since the last
+    compaction (canonical k-cells AND raw temp cells into canonical
+    k-cells, emptying temp) and leave every other row's bytes as they
+    are: a row that took none is in canonical form already, and
+    compressing it again merges nothing new. Amortized analogue of the
+    reference's mergeAllTemps (merging_digest.go:140), which likewise
+    returns at once on an empty temp buffer.
+
+    The dirty rows are those with h_temp_n > 0 (see DeviceState). Their
+    ids are sorted to the front and a loop of ceil(n / COMPACT_ROW_BLOCK)
+    trips works through them, so the work follows the rows that took
+    samples and not the table's height; no dirty row, no trip. Under
+    vmap (the sharded step) the loop runs to the largest shard's count."""
+    kh = state.h_w.shape[0]
+    r = min(COMPACT_ROW_BLOCK, kh)
+    n = dirty_rows(state)
+    # Row ids, dirty ones first and ascending. A clean row sorts behind
+    # them under an id beyond the table, as does the pad that rounds the
+    # length up to whole blocks: the read clips such an id and the write
+    # drops it, and every block's ids are unique and ascending.
+    row = jnp.arange(kh, dtype=jnp.int32)
+    ids = jnp.concatenate([
+        jnp.sort(jnp.where(state.h_temp_n > 0, row, kh + row)),
+        2 * kh + jnp.arange(-kh % r, dtype=jnp.int32)])
+    pad = jnp.zeros((r, spec.temp_cells), state.h_w.dtype)
+    take = dict(mode="clip", unique_indices=True, indices_are_sorted=True)
+    put = dict(take, mode="drop")
+
+    def block(i, tables):
+        h_w, h_wm = tables
+        rows = jax.lax.dynamic_slice(ids, (i * r,), (r,))
+        w = h_w.at[rows].get(**take)
+        wm = h_wm.at[rows].get(**take)
+        m2, w2 = td.compress_rows(
+            wm / jnp.maximum(w, 1e-30), w, compression=spec.compression,
+            cells_per_k=spec.cells_per_k, out_c=spec.centroids,
+            exact_extremes=spec.exact_extremes)
+        return (h_w.at[rows].set(jnp.concatenate([w2, pad], axis=-1), **put),
+                h_wm.at[rows].set(jnp.concatenate([m2 * w2, pad], axis=-1),
+                                  **put))
+
+    h_w, h_wm = jax.lax.fori_loop(0, (n + r - 1) // r, block,
+                                  (state.h_w, state.h_wm))
+    return state._replace(h_wm=h_wm, h_w=h_w,
+                          h_temp_n=jnp.zeros_like(state.h_temp_n))
 
 
 compact = partial(jax.jit, static_argnames=("spec",),

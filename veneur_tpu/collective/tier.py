@@ -29,6 +29,7 @@ byte-identical whenever the spread fits, i.e. in practice).
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
 from functools import partial
@@ -123,6 +124,9 @@ class CollectiveGlobalTier(ShardedAggregator):
         self.dispatch_ns = 0
         self.steps_total = 0
         self.steps_synced = 0
+        # the inherited swap settles these; this tier's own dispatch
+        # (_dispatch_row) queues none
+        self._steps_in_flight = collections.deque()
         # always-on phase timers: a private Timer instance until a host
         # server injects its registry-owned one (set_phase_timer), so
         # phase durations accumulate with or without a Server around.
@@ -234,7 +238,7 @@ class CollectiveGlobalTier(ShardedAggregator):
             pack_batch(b, dc, out=flat[0, i])
         self.h2d_bytes += flat.nbytes
         t0 = time.perf_counter_ns()
-        self.state = self._ingest(self.state, flat)
+        self.state, _rows = self._ingest(self.state, flat)
         dispatch_dt = time.perf_counter_ns() - t0
         self.dispatch_ns += dispatch_dt
         if self.steps_total % _SYNC_EVERY == 0:

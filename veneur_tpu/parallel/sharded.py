@@ -123,10 +123,12 @@ def make_sharded_ingest(mesh: Mesh, spec: TableSpec):
 
 def make_sharded_ingest_packed(mesh: Mesh, spec: TableSpec, sizes: tuple):
     """Packed-transfer variant of make_sharded_ingest: (state, flat) ->
-    state where flat is i32[R, S, W] — each tile's batch as ONE bit-packed
-    buffer (aggregation/step.py pack_batch), with the compact control word
-    in-band. Same single-executable / single-transfer rationale as the
-    single-device ingest_step_packed, applied per mesh tile.
+    (state, rows) where flat is i32[R, S, W] — each tile's batch as ONE
+    bit-packed buffer (aggregation/step.py pack_batch), with the compact
+    control word in-band — and rows is i32[R, S], the digest rows each
+    tile's compaction compressed (0 on a step without one). Same
+    single-executable / single-transfer rationale as the single-device
+    ingest_step_packed, applied per mesh tile.
 
     The compact cond sits ABOVE the tile vmaps with a scalar predicate
     (every tile of a dispatch carries the same word): a vmapped cond
@@ -134,7 +136,7 @@ def make_sharded_ingest_packed(mesh: Mesh, spec: TableSpec, sizes: tuple):
     sort-based recompression every step instead of every
     compact_every-th."""
     from veneur_tpu.aggregation.step import (
-        compact_core, ingest_core, unpack_batch)
+        compact_core, dirty_rows, ingest_core, unpack_batch)
 
     def tile_ingest(state, flat):
         # allow_pallas=False: the tile body runs under two vmaps, where
@@ -153,12 +155,15 @@ def make_sharded_ingest_packed(mesh: Mesh, spec: TableSpec, sizes: tuple):
         st = vv_ingest(state, flat)
         do_compact = flat[0, 0, 0] != 0   # scalar: cond stays a branch
         with jax.named_scope("maybe_compact"):
-            return jax.lax.cond(do_compact, vv_compact, lambda s: s, st)
+            rows = jnp.where(do_compact, jax.vmap(jax.vmap(dirty_rows))(st),
+                             0)
+            return jax.lax.cond(do_compact, vv_compact, lambda s: s,
+                                st), rows
 
+    tiles = P(REPLICA_AXIS, SHARD_AXIS)
     fn = _shard_map(
-        sharded_packed_step, mesh=mesh,
-        in_specs=(P(REPLICA_AXIS, SHARD_AXIS), P(REPLICA_AXIS, SHARD_AXIS)),
-        out_specs=P(REPLICA_AXIS, SHARD_AXIS))
+        sharded_packed_step, mesh=mesh, in_specs=(tiles, tiles),
+        out_specs=(tiles, tiles))
     return jax.jit(fn, donate_argnums=(0,))
 
 

@@ -14,7 +14,6 @@ import collections
 import time
 from typing import Dict, List, Tuple
 
-import jax
 import numpy as np
 
 from veneur_tpu.aggregation.host import Batcher, BatchSpec, KeyTable
@@ -53,19 +52,16 @@ _SYNC_EVERY = 64
 # compaction (it needs two) and a tick's wait under one compaction.
 _MAX_STEPS_IN_FLIGHT = 4
 
-# a step's completion, as an array that survives the state's donation to
-# the next step: one element of the smallest leaf, read after the step
-_step_done = jax.jit(lambda stamp: stamp[..., :1])
-
 
 class Aggregator:
     # optional tables.pressure.TablePressure shared across intervals;
     # class attribute so every backend (ShardedAggregator skips this
     # __init__) starts without one
     _pressure = None
-    # steps that carried the in-band compaction and the digest rows
-    # they handed to compress_rows, monotonic like steps_total
-    # (_count_step); class attributes for the same reason
+    # steps that carried the in-band compaction (_count_step) and the
+    # digest rows those compactions compressed, as the device counted
+    # them (_settle_step), monotonic like steps_total; class attributes
+    # for the same reason
     compactions = 0
     compact_rows = 0
 
@@ -209,35 +205,37 @@ class Aggregator:
     def _count_step(self) -> bool:
         """Count one more ingest step, and say whether it carries the
         in-band compaction: every compact_every-th step of the interval
-        does, and hands compress_rows every row of the digest table
-        (spec.histo_capacity: all shards' rows on the sharded backend),
-        live or not."""
+        does. What it compresses is the digest rows that took a sample
+        since the last one (step.compact_core); the device counts those
+        and _settle_step adds them up."""
         self._steps += 1
         self.steps_total += 1
         compacts = self._steps % self.compact_every == 0
         if compacts:
             self.compactions += 1
-            self.compact_rows += self.spec.histo_capacity
         return compacts
 
     def _dispatch_step(self, step, flat, **static) -> None:
         """The one ingest dispatch every backend's step site goes through
         (here, the native packed and ring emits, the sharded row):
-        `self.state = step(self.state, flat, **static)` under the
+        `self.state, rows = step(self.state, flat, **static)` under the
         `pipeline.dispatch` span, its host time summed into dispatch_ns
         (with _MAX_STEPS_IN_FLIGHT steps already queued it first waits
         for the oldest to finish, so this is queue wait as much as
         enqueue), and every _SYNC_EVERY-th step the sampled sync under
         `pipeline.sampled_sync`. That sync waits for everything queued,
         so step_ns reads the queue's drain, not one step's device
-        time."""
+        time. `rows`, the digest rows the step's compaction compressed,
+        is a step's completion too: a small array of its own, which
+        survives the state's donation to the next step."""
         in_flight = self._steps_in_flight
         with hostspans.span("pipeline.dispatch"):
             t0 = time.perf_counter_ns()
             if len(in_flight) == _MAX_STEPS_IN_FLIGHT:
-                jaxruntime.sync_and_time(in_flight.popleft())
-            self.state = step(self.state, flat, **static)
-            in_flight.append(_step_done(self.state.status_stamp))
+                self._settle_step()
+            self.state, rows = step(self.state, flat, **static)
+            # the control word, as the program reads it
+            in_flight.append((rows, flat.flat[0] != 0))
             dispatch_dt = time.perf_counter_ns() - t0
         self.dispatch_ns += dispatch_dt
         if self.steps_total % _SYNC_EVERY == 0:
@@ -245,6 +243,28 @@ class Aggregator:
                 self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
                     self.state)
             self.steps_synced += 1
+
+    def _settle_step(self) -> None:
+        """Wait for the oldest step in flight and, where it compacted,
+        add the rows it compressed to compact_rows: four bytes read from
+        a step that has finished."""
+        rows, compacted = self._steps_in_flight.popleft()
+        jaxruntime.sync_and_time(rows)
+        if compacted:
+            self.compact_rows += int(np.asarray(rows).sum())
+
+    def _await_steps(self) -> None:
+        """The interval boundary's sync, shared by every backend's swap:
+        wait for every step still queued on the device, so that step_ns
+        is never 0 after a flush that ingested even when _SYNC_EVERY
+        never fired, then settle them all, which makes compact_rows
+        exact at each tick."""
+        if self._steps:
+            with hostspans.span("swap.device_wait"):
+                self.step_ns += jaxruntime.sync_and_time(self.state)
+            self.steps_synced += 1
+        while self._steps_in_flight:
+            self._settle_step()
 
     def process_metric(self, m: UDPMetric) -> None:
         """reference worker.go:344 ProcessMetric: switch on type+scope,
@@ -466,13 +486,7 @@ class Aggregator:
             self.batcher.emit()
             while self._hll_slots:
                 self._flush_hll_imports()
-        if self._steps:
-            # interval boundary sync: step_ns is never 0 after a flush
-            # that ingested, even when _SYNC_EVERY never fired. The swap
-            # waits here for every step still queued on the device.
-            with hostspans.span("swap.device_wait"):
-                self.step_ns += jaxruntime.sync_and_time(self.state)
-            self.steps_synced += 1
+        self._await_steps()
         with hostspans.span("swap.reset"):
             state, table = self.state, self.table
             self.state = empty_state_compiled(self.spec)

@@ -428,7 +428,9 @@ class NativeAggregator(Aggregator):
         multi-ring mode this is the EXACT cross-ring aggregate (sums;
         high-water is the per-ring max). With them the two counts of the
         steps those emits fed that the engine does not keep: compactions
-        and the digest rows they re-compressed (Aggregator._count_step)."""
+        (Aggregator._count_step) and the digest rows they compressed, as
+        the device counted them (Aggregator._settle_step: exact at each
+        swap, up to _MAX_STEPS_IN_FLIGHT steps behind between two)."""
         return {**self.eng.ring_stats(), "compactions": self.compactions,
                 "compact_rows": self.compact_rows}
 

@@ -833,8 +833,8 @@ class Server:
         M.callback("veneur.device.compact_rows_total",
                    lambda: getattr(self.aggregator, "compact_rows", 0),
                    kind="counter",
-                   help="digest rows those compactions re-compressed "
-                        "(the whole table each time)")
+                   help="digest rows those compactions compressed "
+                        "(the rows that took a sample since the last)")
         M.callback("veneur.device.steps_synced_total",
                    lambda: getattr(self.aggregator, "steps_synced", 0),
                    kind="counter",

@@ -26,7 +26,7 @@ import numpy as np
 
 from veneur_tpu.aggregation.host import Batcher, BatchSpec, KeyTable
 from veneur_tpu.aggregation.state import TableSpec
-from veneur_tpu.observability import hostspans, jaxruntime
+from veneur_tpu.observability import hostspans
 from veneur_tpu.server.aggregator import Aggregator, set_member_bytes
 
 
@@ -339,11 +339,7 @@ class ShardedAggregator(Aggregator):
         with hostspans.span("swap.emit_staged"):
             self._emit_all()
             self._apply_hll_imports()
-        if self._steps:
-            # interval boundary sync (see Aggregator.swap)
-            with hostspans.span("swap.device_wait"):
-                self.step_ns += jaxruntime.sync_and_time(self.state)
-            self.steps_synced += 1
+        self._await_steps()
         with hostspans.span("swap.reset"):
             state, table = self.state, self.table
             self.state = self._empty()
