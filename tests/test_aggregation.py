@@ -5,6 +5,9 @@ sample-rate weighting, cross-instance merge) and worker_test.go (ProcessMetric
 routing), but against exact numpy oracles.
 """
 
+import collections
+import time
+
 import numpy as np
 import pytest
 
@@ -516,24 +519,102 @@ def test_set_member_invalid_utf8_survives_python_path():
             and b.s_rho[0] == rho), "member bytes must round-trip"
 
 
-def _unaliased_pack_bufs(agg):
-    """The CPU backend reads a 64-byte-aligned host array in place, and
-    the aggregator packs step N+2 into step N's buffer while that step
-    may still be queued, so on the CPU (only) a queued step can see a
-    later step's words, the control word among them (ROADMAP D9). Steered
-    here, as perfbench/tests/conftest.py does: packed buffers that cannot
-    be read in place."""
-    from veneur_tpu.aggregation.step import batch_sizes, packed_layout
-    sizes = batch_sizes(Batcher(agg.spec, agg.bspec).force_emit())
-    words = packed_layout(sizes)[1]
-    bufs = []
-    for _ in range(2):
-        raw = np.zeros(words + 32, np.int32)
-        skip = next(k for k in range(1, 17)
-                    if (raw.ctypes.data + 4 * k) % 64)
-        bufs.append(raw[skip:skip + words])
-    agg._pack_bufs[sizes] = bufs + [0]
-    return agg
+def _feed_python(agg, i):
+    from veneur_tpu.samplers import parser
+    before, k = agg.steps_total, 0
+    while agg.steps_total == before:    # until a lane fills
+        agg.process_metric(
+            parser.parse_metric(b"c.%d:%d|c" % (k % 7, i + 1)))
+        k += 1
+
+
+def _feed_native(agg, i):
+    agg.feed(b"c.%d:%d|c\nt.%d:%d|ms" % (i % 3, i + 1, i % 5, i))
+    agg._emit_native()
+
+
+def _feed_rings(agg, i):
+    done = agg.eng.stats()["processed"] + 1
+    assert agg.eng.rings_inject(i % 2, b"c.%d:%d|c" % (i % 3, i + 1))
+    deadline = time.monotonic() + 30
+    while agg.eng.stats()["processed"] < done:
+        assert time.monotonic() < deadline, "the ring worker stalled"
+        time.sleep(0.0005)
+    assert agg._emit_rings()
+
+
+def _step_site(name):
+    """(aggregator, feed) for one of the step sites that run on the CPU;
+    feed(agg, i) makes it dispatch one step whose words depend on i."""
+    spec = TableSpec(counter_capacity=64, gauge_capacity=16,
+                     status_capacity=8, set_capacity=16, histo_capacity=32)
+    lanes = BatchSpec(counter=4, gauge=4, status=4, set=4, histo=4)
+    if name == "aggregator":
+        from veneur_tpu.server.aggregator import Aggregator
+        return Aggregator(spec, lanes, compact_every=2), _feed_python
+    if name == "sharded-row":
+        from veneur_tpu.server.sharded_aggregator import ShardedAggregator
+        return (ShardedAggregator(spec, lanes, n_shards=2, compact_every=2),
+                _feed_python)
+    if name == "tier-row":
+        from veneur_tpu.collective.tier import CollectiveGlobalTier
+        return (CollectiveGlobalTier(spec, lanes, n_shards=2, n_replicas=2,
+                                     compact_every=2), _feed_python)
+    from veneur_tpu.server.native_aggregator import NativeAggregator
+    agg = NativeAggregator(spec, BatchSpec(counter=8, gauge=8, status=4,
+                                           set=8, histo=8), compact_every=2)
+    if name == "native-packed":
+        return agg, _feed_native
+    agg.rings_start(2)
+    return agg, _feed_rings
+
+
+@pytest.mark.parametrize("site", ["aggregator", "native-packed",
+                                  "native-rings", "sharded-row", "tier-row"])
+def test_no_packed_buffer_is_written_before_its_step_is_settled(site):
+    """The invariant of Aggregator._init_step_site, at every step site:
+    a runtime may read a host buffer in place for as long as its step is
+    queued (the CPU client does), so the buffer a step was given holds
+    the words it was dispatched with until _settle_step has popped that
+    step. The step is replaced by one that keeps each buffer and a copy
+    of its words."""
+    from veneur_tpu.server.aggregator import _MAX_STEPS_IN_FLIGHT
+
+    agg, feed = _step_site(site)
+    kept = collections.deque()      # (buffer, its words) of unsettled steps
+    dispatch, settle = agg._dispatch_step, agg._settle_step
+
+    def unwritten():
+        for n, (flat, words) in enumerate(kept):
+            assert np.array_equal(flat, words), (
+                f"the buffer of the step {len(kept) - n} back was written "
+                f"while that step was in flight")
+
+    def keeping_dispatch(step, flat, *args, **static):
+        def keeping_step(state, flat, **static):
+            unwritten()
+            kept.append((flat, flat.copy()))
+            return step(state, flat, **static)
+        dispatch(keeping_step, flat, *args, **static)
+
+    def checking_settle():
+        unwritten()
+        settle()
+        kept.popleft()
+
+    agg._dispatch_step, agg._settle_step = keeping_dispatch, checking_settle
+    try:
+        steps = 3 * (_MAX_STEPS_IN_FLIGHT + 1) + 2
+        for i in range(steps):
+            feed(agg, i)
+            assert len(kept) == len(agg._steps_in_flight)
+        assert agg.steps_total == steps
+        assert len(kept) == _MAX_STEPS_IN_FLIGHT
+        agg.swap()
+        assert not kept
+    finally:
+        if site == "native-rings":
+            agg.readers_stop()
 
 
 @pytest.mark.parametrize("steps", [3, 21])
@@ -549,9 +630,9 @@ def test_dispatch_counts_compactions_and_bounds_steps_in_flight(steps):
 
     spec = TableSpec(counter_capacity=64, gauge_capacity=16,
                      status_capacity=8, set_capacity=16, histo_capacity=32)
-    agg = _unaliased_pack_bufs(aggregator_mod.Aggregator(
+    agg = aggregator_mod.Aggregator(
         spec, BatchSpec(counter=4, gauge=4, status=4, set=4, histo=4),
-        compact_every=2))
+        compact_every=2)
     seen = []
     for i in range(steps * 4):
         agg.process_metric(parser.parse_metric(b"t.%d:%d|ms" % (i % 3, i)))
@@ -729,9 +810,9 @@ def test_compact_rows_is_the_devices_count_of_rows_that_took_samples(kind):
     from veneur_tpu.server.aggregator import Aggregator
 
     lane, every, n = 4, 3, 90
-    agg = _unaliased_pack_bufs(Aggregator(
+    agg = Aggregator(
         SMALL, BatchSpec(counter=lane, gauge=lane, status=lane, set=lane,
-                         histo=lane), compact_every=every))
+                         histo=lane), compact_every=every)
     rng = np.random.default_rng(3)
     names = rng.integers(0, 40, n)
     for i, name in enumerate(names):

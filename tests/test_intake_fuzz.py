@@ -71,16 +71,19 @@ def test_ssf_udp_random_datagrams_keep_reader_alive():
             n = int(rng.integers(0, 400))
             s.sendto(bytes(rng.integers(0, 256, n).astype(np.uint8)),
                      srv.local_addr())
-        # a valid span afterward proves the reader survived
+        # a valid span afterward proves the reader survived. UDP may drop
+        # it with the garbage: a starved reader lets the socket buffer
+        # fill (the server then counts fewer than the 500 it was sent, and
+        # goes on reading), so the probe is sent again on every turn
         from veneur_tpu.proto import ssf_pb2
         sp = ssf_pb2.SSFSpan(version=0, trace_id=9, id=9, service="alive",
                              name="ok", start_timestamp=1, end_timestamp=2)
-        s.sendto(sp.SerializeToString(), srv.local_addr())
-        s.close()
         deadline = time.time() + 60
         while time.time() < deadline and not any(
                 x.name == "ok" for x in ssink.spans):
+            s.sendto(sp.SerializeToString(), srv.local_addr())
             time.sleep(0.05)
+        s.close()
         assert any(x.name == "ok" for x in ssink.spans), "reader died"
     finally:
         srv.shutdown()

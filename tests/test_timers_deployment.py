@@ -11,7 +11,6 @@ percentiles are exact; every name flushes six rows. The chip run at the
 published width is the cell `agent-100k-timers` (PERF.md).
 """
 
-import importlib.util
 import json
 import os
 import socket
@@ -62,21 +61,13 @@ SEPARATED = {"p50_rank_wmean", "p75_rank_wmean"}
 @pytest.fixture(scope="module")
 def bench():
     """The benchmark's own harness, traffic generator and reference, by
-    the plain names run.py imports them under, and the CPU-only fix of its
-    tests (perfbench/tests/conftest.py misalign: the CPU backend aliases
-    aligned host buffers and the program reuses its two packed buffers
-    while steps are in flight; PERF.md section 6)."""
+    the plain names run.py imports them under."""
     sys.path.insert(0, BENCH)
     try:
         import harness
         import reference
         import traffic
-        spec = importlib.util.spec_from_file_location(
-            "perfbench_tests_conftest",
-            os.path.join(BENCH, "tests", "conftest.py"))
-        fix = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(fix)
-        yield harness, reference, traffic, fix.misalign
+        yield harness, reference, traffic
     finally:
         while BENCH in sys.path:
             sys.path.remove(BENCH)
@@ -87,7 +78,7 @@ def serve_stream(bench, tmp_path, seed, overrides):
     through the harness's build_server) fed BOUNDS' intervals over UDP;
     returns the reference's numbers over both intervals and the rows of
     each flush."""
-    harness, reference, traffic, misalign = bench
+    harness, reference, traffic = bench
     with open(os.path.join(BENCH, "configs", "agent-timers-1chip.json")) as f:
         cfgf = json.load(f)
     pool = traffic.build_pool(TRAFFIC, seed)
@@ -101,9 +92,9 @@ def serve_stream(bench, tmp_path, seed, overrides):
     datagrams = pool.datagrams()
     sizes = pool.datagram_sizes()
     sink = harness.make_sink()
-    server = misalign(harness.build_server(
+    server = harness.build_server(
         cfgf, str(tmp_path), sink,
-        dict(overrides, tpu_histo_capacity=HISTO_ROWS)))
+        dict(overrides, tpu_histo_capacity=HISTO_ROWS))
     server.start()
     numbers, examples, rows = reference.new_numbers(PERCENTILES), [], []
     try:

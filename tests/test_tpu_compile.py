@@ -73,8 +73,8 @@ def default_spec() -> TableSpec:
 def _packed_sizes(spec):
     """Packed lane sizes of the shipped batch config: the compile key of
     the packed ingest program, derived as the aggregators derive it
-    (sharded_aggregator.py; native_aggregator._alloc_packed_buffers
-    builds the same tuple)."""
+    (sharded_aggregator.py; NativeAggregator._pk_sizes is the same
+    tuple)."""
     from veneur_tpu.aggregation.host import Batcher
     cfg = Config()
     bspec = BatchSpec(counter=cfg.tpu_batch_counter,

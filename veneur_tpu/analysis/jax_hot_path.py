@@ -72,8 +72,8 @@ HOT_FUNCS: Dict[str, List[str]] = {
         "_dispatch_row", "_on_shard_batch", "_emit_all",
         "_apply_hll_imports", "swap", "query_snapshot"],
     "veneur_tpu/collective/tier.py": [
-        "_dispatch_row", "_dispatch_routed", "_on_stage_batch",
-        "absorb_raw", "swap", "query_snapshot"],
+        "_dispatch_routed", "_on_stage_batch", "absorb_raw", "swap",
+        "query_snapshot"],
     "veneur_tpu/query/engine.py": [
         "_launch", "_launch_on_pipeline", "_launch_combined"],
     # history ring maintenance runs inside the flush's dispatch window

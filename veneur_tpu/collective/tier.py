@@ -29,11 +29,10 @@ byte-identical whenever the spread fits, i.e. in practice).
 
 from __future__ import annotations
 
-import collections
 import threading
 import time
 from functools import partial
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -43,9 +42,7 @@ from veneur_tpu.aggregation.step import Batch
 from veneur_tpu.collective.keytable import CollectiveKeyTable
 from veneur_tpu.observability import jaxruntime
 from veneur_tpu.observability.registry import Timer
-from veneur_tpu.server.aggregator import _SYNC_EVERY
-from veneur_tpu.server.sharded_aggregator import (
-    ShardedAggregator, per_shard_spec)
+from veneur_tpu.server.sharded_aggregator import ShardedAggregator
 
 # -- process-local tier registry -------------------------------------------
 # Co-located servers living in one process (the deployment shape the
@@ -75,34 +72,14 @@ class CollectiveGlobalTier(ShardedAggregator):
     def __init__(self, spec: TableSpec, bspec: BatchSpec = BatchSpec(),
                  n_shards: int = 2, n_replicas: int = 1,
                  compact_every: int = 8):
-        import jax  # noqa: F401  (device availability surfaces early)
-        from veneur_tpu.aggregation.step import batch_sizes
         from veneur_tpu.collective.router import (
             make_merged_state, make_routed_ingest, shard_axis_is_physical)
-        from veneur_tpu.parallel import (
-            make_mesh, make_merged_flush, make_sharded_ingest_packed,
-            sharded_empty_state)
 
-        self.spec = spec
-        self.pspec = per_shard_spec(spec, n_shards)
-        self.bspec = bspec
-        self.n_shards = n_shards
         self.n_replicas = max(1, int(n_replicas))
-        self.compact_every = compact_every
-
-        self.mesh = make_mesh(self.n_replicas, n_shards)
-        self._sizes = batch_sizes(Batcher(self.pspec, bspec).force_emit())
-        self._ingest = make_sharded_ingest_packed(self.mesh, self.pspec,
-                                                  self._sizes)
-        self._flush = make_merged_flush(self.mesh, self.pspec)
+        super().__init__(spec, bspec, n_shards, compact_every)
         self._merge = make_merged_state(self.mesh, self.pspec)
-        self._empty = partial(sharded_empty_state, self.pspec,
-                              self.n_replicas, n_shards, self.mesh)
-        self.state = self._empty()
+        # the collective tier routes by key identity
         self.table = CollectiveKeyTable(spec, n_shards)
-        # direct traffic (process_metric / import_metric / restore)
-        # stages into replica row 0 through the inherited batchers
-        self.batchers = self._make_batchers()
         # absorb staging: one Batcher per (replica row, source column,
         # owner shard); the routed all_to_all delivers buckets to owners
         self._route_device = shard_axis_is_physical(self.mesh, n_shards)
@@ -113,20 +90,6 @@ class CollectiveGlobalTier(ShardedAggregator):
         self._next_participant = 0
         self._routed_steps = 0
         self.absorbed_rows = 0
-        self._hll_slots: List[Tuple[int, int]] = []
-        self._hll_rows: List[np.ndarray] = []
-        self._restore_residuals: list = []
-        self._steps = 0
-        self.processed = 0
-        self.dropped_capacity = 0
-        self.h2d_bytes = 0
-        self.step_ns = 0
-        self.dispatch_ns = 0
-        self.steps_total = 0
-        self.steps_synced = 0
-        # the inherited swap settles these; this tier's own dispatch
-        # (_dispatch_row) queues none
-        self._steps_in_flight = collections.deque()
         # always-on phase timers: a private Timer instance until a host
         # server injects its registry-owned one (set_phase_timer), so
         # phase durations accumulate with or without a Server around.
@@ -141,7 +104,6 @@ class CollectiveGlobalTier(ShardedAggregator):
         # the local->global span tree; the trace client rides along.
         self._last_absorb = None
         self._trace_client = None
-        self._init_degrade()
 
     def set_phase_timer(self, timer) -> None:
         """Adopt a registry-owned phase-duration Timer (the host Server
@@ -174,6 +136,12 @@ class CollectiveGlobalTier(ShardedAggregator):
             else self._stage_grid[rr][jj][dd].force_emit())
 
     def _dispatch_routed(self, get):
+        """One routed step over the whole stage grid. The one step site
+        outside _dispatch_step and its bound on steps in flight: the
+        routed program returns the donated state and nothing else, so
+        there is nothing to wait on for one step without another device
+        op, and its batch is built anew each time, so no host buffer is
+        reused under it. It shares the sampled sync."""
         nested = []
         for r in range(self.n_replicas):
             row = []
@@ -192,10 +160,7 @@ class CollectiveGlobalTier(ShardedAggregator):
         self.dispatch_ns += dispatch_dt
         self._phase_timer.observe(dispatch_dt, phase="all_to_all_route")
         self.steps_total += 1
-        if self.steps_total % _SYNC_EVERY == 0:
-            self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
-                self.state)
-            self.steps_synced += 1
+        self._sampled_sync(dispatch_dt)
         # absorbed digest rows land in temp cells like any other ingest;
         # ride the packed program's in-band compact word at the same
         # cadence as direct traffic so they recompress
@@ -212,39 +177,6 @@ class CollectiveGlobalTier(ShardedAggregator):
             return
         self._dispatch_routed(
             lambda r, j, d: self._stage_grid[r][j][d].force_emit())
-
-    # -- direct dispatch over the [R, S] mesh --------------------------------
-    def _dispatch_row(self, row, force_compact: bool = False):
-        """Direct-traffic twin of ShardedAggregator._dispatch_row for an
-        R-row mesh: row 0 carries the packed shard batches, rows 1..R-1
-        carry a constant all-padding packed row (absorbed traffic reaches
-        them through the routed path instead)."""
-        from veneur_tpu.aggregation.step import pack_batch, packed_layout
-        self._steps += 1
-        self.steps_total += 1
-        dc = force_compact or (self._steps % self.compact_every == 0)
-        bufs = getattr(self, "_row_bufs", None)
-        if bufs is None:
-            words = packed_layout(self._sizes)[1]
-            pad = np.zeros(words, np.int32)
-            pack_batch(Batcher(self.pspec, self.bspec).force_emit(),
-                       False, out=pad)
-            base = np.broadcast_to(
-                pad, (self.n_replicas, self.n_shards, words)).copy()
-            bufs = self._row_bufs = [base, base.copy(), 0]
-        flat = bufs[bufs[2]]
-        bufs[2] ^= 1
-        for i, b in enumerate(row):
-            pack_batch(b, dc, out=flat[0, i])
-        self.h2d_bytes += flat.nbytes
-        t0 = time.perf_counter_ns()
-        self.state, _rows = self._ingest(self.state, flat)
-        dispatch_dt = time.perf_counter_ns() - t0
-        self.dispatch_ns += dispatch_dt
-        if self.steps_total % _SYNC_EVERY == 0:
-            self.step_ns += dispatch_dt + jaxruntime.sync_and_time(
-                self.state)
-            self.steps_synced += 1
 
     # -- zero-serialization absorb -------------------------------------------
     def assign_participant(self) -> int:

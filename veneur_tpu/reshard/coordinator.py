@@ -272,8 +272,8 @@ class ReshardCoordinator:
             # server-lifetime counters, not per-aggregator ones
             new_agg.processed = old_agg.processed
             new_agg.dropped_capacity = old_agg.dropped_capacity
-            new_agg.h2d_bytes = getattr(old_agg, "h2d_bytes", 0)
-            new_agg.last_set_shift = getattr(old_agg, "last_set_shift", 0)
+            new_agg.h2d_bytes = old_agg.h2d_bytes
+            new_agg.last_set_shift = old_agg.last_set_shift
             srv.aggregator = new_agg
             srv._native = native
         except Exception:

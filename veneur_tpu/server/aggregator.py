@@ -19,7 +19,7 @@ import numpy as np
 from veneur_tpu.aggregation.host import Batcher, BatchSpec, KeyTable
 from veneur_tpu.aggregation.state import (TableSpec, empty_state_compiled)
 from veneur_tpu.aggregation.step import (
-    batch_sizes, ingest_step_packed, pack_batch)
+    batch_sizes, ingest_step_packed, pack_batch, packed_layout)
 from veneur_tpu.observability import hostspans, jaxruntime
 from veneur_tpu.samplers.parser import UDPMetric
 from veneur_tpu.utils.hashing import fnv1a_64, splitmix64
@@ -38,8 +38,8 @@ def set_member_bytes(value) -> bytes:
         "utf-8", "surrogateescape")
 
 
-# sampled device-sync cadence for step_ns (see __init__ accounting
-# comment); every backend's dispatch loop shares it
+# sampled device-sync cadence for step_ns (_sampled_sync); every backend's
+# dispatch shares it
 _SYNC_EVERY = 64
 
 # Ingest steps the host may have dispatched without having seen them
@@ -54,17 +54,6 @@ _MAX_STEPS_IN_FLIGHT = 4
 
 
 class Aggregator:
-    # optional tables.pressure.TablePressure shared across intervals;
-    # class attribute so every backend (ShardedAggregator skips this
-    # __init__) starts without one
-    _pressure = None
-    # steps that carried the in-band compaction (_count_step) and the
-    # digest rows those compactions compressed, as the device counted
-    # them (_settle_step), monotonic like steps_total; class attributes
-    # for the same reason
-    compactions = 0
-    compact_rows = 0
-
     def __init__(self, spec: TableSpec, bspec: BatchSpec = BatchSpec(),
                  n_shards: int = 1, compact_every: int = 8):
         self.spec = spec
@@ -74,6 +63,26 @@ class Aggregator:
         self.table = KeyTable(spec, n_shards)
         self.batcher = Batcher(spec, bspec, on_batch=self._on_batch)
         self.state = empty_state_compiled(spec)
+        self._init_step_site()
+
+    def _init_step_site(self) -> None:
+        """The host side of an ingest step, which this class owns for every
+        backend: each constructor calls this once (the sharded backends
+        build their own state and do not run Aggregator.__init__). A step
+        site asks _step_buffer for the host buffer to pack into and hands
+        it to _dispatch_step; the accounting, the steps in flight and the
+        buffers live here.
+
+        The invariant the buffers keep: a packed host buffer is not
+        written again until the step that read it has been settled in
+        _settle_step. A runtime may read a host array in place for as long
+        as the step is queued (the CPU client does, for 64-byte-aligned
+        arrays), so a buffer written earlier hands a queued step a later
+        step's words, the compaction control word among them. While step n
+        is packed, steps n-_MAX_STEPS_IN_FLIGHT .. n-1 may be unsettled
+        (_dispatch_step settles the oldest only once step n is ready to
+        go), so a ring of one more buffer than that is the fewest that
+        keeps it."""
         self._steps = 0
         # staged HLL import rows (merged via ops.hll.merge_rows)
         self._hll_slots: list = []
@@ -81,6 +90,8 @@ class Aggregator:
         # checkpoint-restore residuals: (batcher, slot, lo) counter tails
         # applied in a SECOND ingest step (restore_flush)
         self._restore_residuals: list = []
+        # optional tables.pressure.TablePressure shared across intervals
+        self._pressure = None
         # stats (reference self-telemetry counters)
         self.processed = 0
         self.dropped_capacity = 0
@@ -98,17 +109,18 @@ class Aggregator:
         self.dispatch_ns = 0
         self.steps_total = 0
         self.steps_synced = 0
+        # steps that carried the in-band compaction (_count_step) and the
+        # digest rows those compactions compressed, as the device counted
+        # them (_settle_step), monotonic like steps_total
+        self.compactions = 0
+        self.compact_rows = 0
         self._steps_in_flight = collections.deque()
-        # persistent pack targets, two per lane-size signature: batch N+1
-        # packs into one buffer while batch N's h2d + donated step is
-        # still in flight against the other (pack_batch `out` contract)
-        self._pack_bufs: dict = {}
+        # shape key -> ring of host buffers, next to be packed first
+        self._step_bufs: dict = {}
         self._init_degrade()
 
     def _init_degrade(self) -> None:
-        """Degraded-aggregation state (reliability/overload.py). Every
-        backend __init__ must call this — ShardedAggregator builds its
-        own state and does not run Aggregator.__init__.
+        """Degraded-aggregation state (reliability/overload.py).
 
         Under SHEDDING+ the OverloadController pushes these knobs; the
         defaults (1.0 / 0) are branch-predicted no-ops on the hot path.
@@ -186,49 +198,55 @@ class Aggregator:
         # program via the control word (step.py pack_batch rationale)
         compacts = self._count_step()
         sizes = batch_sizes(batch)
-        bufs = self._pack_bufs.get(sizes)
-        if bufs is None:
-            from veneur_tpu.aggregation.step import packed_layout
-            words = packed_layout(sizes)[1]
-            # [buf_a, buf_b, next_index]: allocated once per size
-            # signature, alternated every step (double buffering — the
-            # step dispatched last turn may still be reading its buffer)
-            bufs = self._pack_bufs[sizes] = [
-                np.zeros(words, np.int32), np.zeros(words, np.int32), 0]
-        flat = bufs[bufs[2]]
-        bufs[2] ^= 1
+        flat = self._step_buffer(
+            sizes, lambda: np.zeros(packed_layout(sizes)[1], np.int32))
         pack_batch(batch, compacts, out=flat)
-        self.h2d_bytes += flat.nbytes
-        self._dispatch_step(ingest_step_packed, flat, spec=self.spec,
+        self._dispatch_step(ingest_step_packed, flat, sizes, spec=self.spec,
                             sizes=sizes)
 
-    def _count_step(self) -> bool:
+    def _count_step(self, force_compact: bool = False) -> bool:
         """Count one more ingest step, and say whether it carries the
         in-band compaction: every compact_every-th step of the interval
-        does. What it compresses is the digest rows that took a sample
-        since the last one (step.compact_core); the device counts those
-        and _settle_step adds them up."""
+        does, and one a caller forces. What it compresses is the digest
+        rows that took a sample since the last one (step.compact_core);
+        the device counts those and _settle_step adds them up."""
         self._steps += 1
         self.steps_total += 1
-        compacts = self._steps % self.compact_every == 0
+        compacts = force_compact or self._steps % self.compact_every == 0
         if compacts:
             self.compactions += 1
         return compacts
 
-    def _dispatch_step(self, step, flat, **static) -> None:
+    def _step_buffer(self, key, make):
+        """The host buffer the next step of shape `key` is packed into
+        (the lane-size signature, the native engine's packed buffer or
+        its (rings, words) arena, the mesh's [R, S, W] row): whatever
+        `make` builds, one buffer with what belongs to it, built
+        _MAX_STEPS_IN_FLIGHT + 1 times on first use. The same one comes
+        back until _dispatch_step has sent a step from it, so a site that
+        finds nothing to send has used up nothing. The ring's length is
+        the invariant of _init_step_site."""
+        ring = self._step_bufs.get(key)
+        if ring is None:
+            ring = self._step_bufs[key] = collections.deque(
+                make() for _ in range(_MAX_STEPS_IN_FLIGHT + 1))
+        return ring[0]
+
+    def _dispatch_step(self, step, flat, key, **static) -> None:
         """The one ingest dispatch every backend's step site goes through
-        (here, the native packed and ring emits, the sharded row):
+        (here, the native packed and ring emits, the mesh row of the
+        sharded backends and the collective tier), for the buffer `flat`
+        that _step_buffer(key) handed out:
         `self.state, rows = step(self.state, flat, **static)` under the
         `pipeline.dispatch` span, its host time summed into dispatch_ns
         (with _MAX_STEPS_IN_FLIGHT steps already queued it first waits
         for the oldest to finish, so this is queue wait as much as
-        enqueue), and every _SYNC_EVERY-th step the sampled sync under
-        `pipeline.sampled_sync`. That sync waits for everything queued,
-        so step_ns reads the queue's drain, not one step's device
-        time. `rows`, the digest rows the step's compaction compressed,
-        is a step's completion too: a small array of its own, which
-        survives the state's donation to the next step."""
+        enqueue), then the sampled sync. `rows`, the digest rows the
+        step's compaction compressed, is a step's completion too: a
+        small array of its own, which survives the state's donation to
+        the next step."""
         in_flight = self._steps_in_flight
+        self.h2d_bytes += flat.nbytes
         with hostspans.span("pipeline.dispatch"):
             t0 = time.perf_counter_ns()
             if len(in_flight) == _MAX_STEPS_IN_FLIGHT:
@@ -237,7 +255,14 @@ class Aggregator:
             # the control word, as the program reads it
             in_flight.append((rows, flat.flat[0] != 0))
             dispatch_dt = time.perf_counter_ns() - t0
+        self._step_bufs[key].rotate(-1)
         self.dispatch_ns += dispatch_dt
+        self._sampled_sync(dispatch_dt)
+
+    def _sampled_sync(self, dispatch_dt: int) -> None:
+        """Every _SYNC_EVERY-th step, wait for the device under
+        `pipeline.sampled_sync`. The wait is for everything queued, so
+        step_ns reads the queue's drain, not one step's device time."""
         if self.steps_total % _SYNC_EVERY == 0:
             with hostspans.span("pipeline.sampled_sync"):
                 self.step_ns += dispatch_dt + jaxruntime.sync_and_time(

@@ -45,7 +45,7 @@ from veneur_tpu.aggregation.host import (
     Batcher, BatchSpec, KeyTable, SlotMeta, _KindTable)
 from veneur_tpu.aggregation.state import TableSpec
 from veneur_tpu.aggregation.step import (
-    ingest_step_packed, ingest_step_packed_rings)
+    ingest_step_packed, ingest_step_packed_rings, packed_layout)
 from veneur_tpu.native import NativeIngest
 from veneur_tpu.observability import hostspans
 from veneur_tpu.server.aggregator import Aggregator
@@ -141,110 +141,20 @@ class NativeKeyTable:
         self._finalized = True
 
 
-class NativeAggregator(Aggregator):
-    def __init__(self, spec: TableSpec, bspec: BatchSpec = BatchSpec(),
-                 n_shards: int = 1, compact_every: int = 8, engine=None):
-        super().__init__(spec, bspec, n_shards, compact_every)
+class _NativeFeed:
+    """What a backend fed by the C++ parse/key/stage engine does whatever
+    device backend it stands on: both native backends inherit this beside
+    theirs (Aggregator, ShardedAggregator) and define _emit_native and
+    _emit_rings, which move the engine's staged rows into device steps."""
+
+    def _init_native(self, engine) -> None:
         # live resharding passes the OLD aggregator's engine: the C++
         # reader rings/sockets keep feeding the same handle across the
         # rebuild (its staged shard map was applied by the reset inside
         # the drain swap), so ingest never restarts
         self.eng = engine if engine is not None \
-            else NativeIngest(spec, bspec, n_shards)
-        self.table = NativeKeyTable(spec, self.eng, n_shards)
-        self._alloc_packed_buffers()
-        if engine is not None and self.eng.n_rings:
-            # engine reuse across a live reshard / table grow with the
-            # multi-ring readers still running: rings_start (which
-            # normally allocates the per-ring arenas) will not run again
-            # on the rebuilt backend, so allocate them here
-            self._alloc_ring_arenas(self.eng.n_rings)
-
-    def _alloc_ring_arenas(self, n_rings: int):
-        """Per-ring staging plan: two (rings, words) i32 arenas — one row
-        per ring in the exact packed layout — double-buffered like the
-        single-ring pair. Every ring's emit lands in its own row and the
-        WHOLE arena crosses host->device as one donated transfer per step
-        (ingest_step_packed_rings), so R rings cost one h2d RTT, not R.
-        Row sentinels and per-row prev counts follow the vt_emit_packed
-        incremental-restore contract per ring."""
-        from veneur_tpu.aggregation.step import packed_layout
-        layout, words = packed_layout(self._pk_sizes)
-        self._rg_bufs = []
-        self._rg_prev = []
-        for _ in range(2):
-            arena = np.zeros((n_rings, words), np.int32)
-            for r in range(n_rings):
-                self._init_packed_sentinels(arena[r], layout, self.spec)
-            self._rg_bufs.append(arena)
-            self._rg_prev.append(np.zeros((n_rings, 4), np.uint32))
-        self._rg_idx = 0
-
-    def _alloc_packed_buffers(self):
-        """Two flat i32 host buffers in the exact pack_batch device layout,
-        plus the lane word-offsets vt_emit_packed writes at. The native
-        emit is zero-copy: C++ writes staged rows straight into one of
-        these (double-buffered — the engine stages batch N+1 while batch
-        N's h2d + donated step is in flight) and the buffer goes to
-        ingest_step_packed as-is; no Batch pytree, no per-lane copies, no
-        Python repack. All 16 lanes are present at the Python Batcher's
-        sizes so the compile key (spec, sizes) matches the Python path
-        and ONE compiled ingest program serves both — the status and
-        histo_stat lanes never ride the native wire path and stay
-        Python-initialized constant sentinel regions that C++ never
-        touches."""
-        from veneur_tpu.aggregation.step import packed_layout
-        b, spec = self.bspec, self.spec
-        # lane sizes in Batch._fields order — identical to batch_sizes()
-        # of a Python Batcher emit, which is what keys the compiled step
-        sizes = (b.counter, b.counter, b.gauge, b.gauge,
-                 b.status, b.status, b.set, b.set, b.set,
-                 b.histo, b.histo, b.histo,
-                 b.histo_stat, b.histo_stat, b.histo_stat, b.histo_stat)
-        layout, words = packed_layout(sizes)
-        self._pk_sizes = sizes
-        # the ten lanes the C++ engine stages, in vt_emit_packed's
-        # argument order; the interleaved status/histo_stat lane offsets
-        # stay Python-owned
-        self._pk_offs = np.asarray(
-            [layout[name][0] for name in (
-                "counter_slot", "counter_inc", "gauge_slot", "gauge_val",
-                "set_slot", "set_reg", "set_rho", "histo_slot",
-                "histo_val", "histo_wt")], np.int32)
-        self._pk_bufs = []
-        self._pk_prev = []
-        for _ in range(2):
-            flat = np.zeros(words, np.int32)
-            self._init_packed_sentinels(flat, layout, spec)
-            self._pk_bufs.append(flat)
-            # per-buffer staged-row counts from that buffer's previous
-            # emit — vt_emit_packed's incremental sentinel-restore bound
-            self._pk_prev.append(np.zeros(4, np.uint32))
-        self._pk_idx = 0
-
-    @staticmethod
-    def _init_packed_sentinels(flat, layout, spec):
-        """One-time sentinel fill of a fresh packed buffer: every slot
-        lane at its table capacity (scatter mode='drop' padding), weight
-        lanes 0, histo-stat min/max at +/-inf — the state Batcher.emit's
-        partial reset maintains on the Python path. After this, the six
-        C++-maintained lanes are kept in this state incrementally by
-        vt_emit_packed and the status/histo_stat regions are never
-        written again."""
-
-        def lane(name, value, f32=False):
-            off, n, _ = layout[name]
-            view = flat[off:off + n]
-            (view.view(np.float32) if f32 else view)[:] = value
-
-        lane("counter_slot", spec.counter_capacity)
-        lane("gauge_slot", spec.gauge_capacity)
-        lane("set_slot", spec.set_capacity)
-        lane("histo_slot", spec.histo_capacity)
-        lane("status_slot", spec.status_capacity)
-        lane("histo_stat_slot", spec.histo_capacity)
-        lane("histo_stat_min", np.inf, f32=True)
-        lane("histo_stat_max", -np.inf, f32=True)
+            else NativeIngest(self.spec, self.bspec, self.n_shards)
+        self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
 
     # -- wire path -----------------------------------------------------------
     def feed(self, data: bytes) -> List[bytes]:
@@ -258,71 +168,8 @@ class NativeAggregator(Aggregator):
             full, off = self.eng.feed(data, off)
         return self.eng.drain_specials()
 
-    def _emit_native(self):
-        idx = self._pk_idx
-        flat = self._pk_bufs[idx]
-        with hostspans.span("pipeline.emit"):
-            nc, ng, ns, nh = self.eng.emit_packed(flat, self._pk_offs,
-                                                  self._pk_prev[idx])
-        if nc + ng + ns + nh == 0:
-            return
-        self._pk_idx = 1 - idx
-        flat[0] = 1 if self._count_step() else 0
-        self.h2d_bytes += flat.nbytes
-        self._dispatch_step(ingest_step_packed, flat, spec=self.spec,
-                            sizes=self._pk_sizes)
-
     def extra_parse_errors(self) -> int:
         return self.eng.stats()["parse_errors"]
-
-    # -- native import path (global tier) ------------------------------
-    def import_pb_bytes(self, data: bytes):
-        """Decode + stage a serialized forwardrpc.MetricList with the
-        C++ engine (VERDICT r04 #5: the gRPC decode→slot path batched
-        the way wire ingest staging is; reference importsrv/server.go:97
-        SendMetrics). Counters/gauges/digests stage natively; sets,
-        valueless metrics, and oneof/type mismatches fall back to the
-        Python import_into path so error accounting matches the
-        reference's per-metric semantics. Returns (metrics, errors)."""
-        from veneur_tpu.forward.convert import import_into
-        from veneur_tpu.proto import metricpb_pb2 as mpb
-        total = 0
-        errors = 0
-        off = 0
-        while off < len(data):
-            staged, new_off, spans, lane_full = \
-                self.eng.import_metriclist(data, off)
-            total += staged + len(spans)
-            for so, sl in spans:
-                try:
-                    import_into(self, mpb.Metric.FromString(
-                        data[so:so + sl]))
-                except Exception as e:
-                    errors += 1
-                    log.warning("bad imported metric (native path): %s",
-                                e)
-            if new_off >= len(data):
-                break
-            if not lane_full and new_off == off and staged == 0 \
-                    and not spans:
-                # undecodable at a top-level boundary (NOT a lane stop):
-                # the Python deserializer would reject the whole request
-                # — count one error and drop the remainder
-                errors += 1
-                log.warning("undecodable MetricList tail at offset %d "
-                            "(%d bytes dropped)", off, len(data) - off)
-                break
-            # staging filled (or the fallback buffer did): free the
-            # lanes, then re-enter at the reported boundary
-            self._emit_native()
-            off = new_off
-        # per-digest exact min/max/recip ride the Python stats lane —
-        # scatter min/max/add are order-independent vs the centroid
-        # re-add, so batch boundaries don't matter
-        slots, mns, mxs, rcs = self.eng.drain_import_stats()
-        if len(slots):
-            self.batcher.add_histo_stats_bulk(slots, mns, mxs, rcs)
-        return total, errors
 
     # -- native UDP reader group ---------------------------------------------
     def readers_start(self, fds, max_len: int = 65536,
@@ -350,36 +197,9 @@ class NativeAggregator(Aggregator):
     def rings_start(self, n_rings: int, fds=None, max_len: int = 65536,
                     ring_cap: int = 65536, pin_cores=None) -> None:
         """Multi-ring engine start (fd-less rings accept rings_inject only
-        — bench/test entry). Allocates the per-ring arena pair."""
+        — bench/test entry)."""
         self.eng.rings_start(n_rings, fds=fds, max_len=max_len,
                              ring_cap=ring_cap, pin_cores=pin_cores)
-        self._alloc_ring_arenas(n_rings)
-
-    def _emit_rings(self) -> bool:
-        """Drain every ring's staging into the current arena's rows and
-        run ONE device step over the whole arena. Returns False (no step)
-        when all rings were empty — the common idle poll. The compact
-        control word rides row 0 only."""
-        idx = self._rg_idx
-        arena = self._rg_bufs[idx]
-        prev = self._rg_prev[idx]
-        total = 0
-        t0 = time.monotonic_ns()
-        for r in range(self.eng.n_rings):
-            counts = self.eng.rings_emit(r, arena[r], self._pk_offs,
-                                         prev[r])
-            total += counts[0] + counts[1] + counts[2] + counts[3]
-        if total == 0:
-            return False
-        # stamped after the fact: an empty poll (one per pump call, far
-        # more often than a step) must leave no record
-        hostspans.record("pipeline.emit", t0, time.monotonic_ns())
-        self._rg_idx = 1 - idx
-        arena[0, 0] = 1 if self._count_step() else 0
-        self.h2d_bytes += arena.nbytes
-        self._dispatch_step(ingest_step_packed_rings, arena, spec=self.spec,
-                            sizes=self._pk_sizes)
-        return True
 
     def pump(self, max_wait_ms: int, max_emits: int = 8) -> List[bytes]:
         """Drain the C++ datagram ring(s) into staging (GIL released while
@@ -535,7 +355,166 @@ class NativeAggregator(Aggregator):
         return super().query_snapshot()
 
 
-class NativeShardedAggregator(ShardedAggregator):
+class NativeAggregator(_NativeFeed, Aggregator):
+    def __init__(self, spec: TableSpec, bspec: BatchSpec = BatchSpec(),
+                 n_shards: int = 1, compact_every: int = 8, engine=None):
+        super().__init__(spec, bspec, n_shards, compact_every)
+        self._init_native(engine)
+        # The native emit is zero-copy: C++ writes staged rows straight
+        # into a host buffer in the exact pack_batch device layout
+        # (_new_packed, or one row of _new_arena) and the buffer goes to
+        # the ingest program as-is; no Batch pytree, no per-lane copies,
+        # no Python repack. All 16 lanes are present at the Python
+        # Batcher's sizes, in Batch._fields order, so the compile key
+        # (spec, sizes) matches the Python path and ONE compiled ingest
+        # program serves both — the status and histo_stat lanes never
+        # ride the native wire path and stay Python-initialized constant
+        # sentinel regions that C++ never touches.
+        b = bspec
+        self._pk_sizes = (b.counter, b.counter, b.gauge, b.gauge,
+                          b.status, b.status, b.set, b.set, b.set,
+                          b.histo, b.histo, b.histo,
+                          b.histo_stat, b.histo_stat, b.histo_stat,
+                          b.histo_stat)
+        self._pk_layout, self._pk_words = packed_layout(self._pk_sizes)
+        # word offsets of the ten lanes the C++ engine stages, in
+        # vt_emit_packed's argument order; the interleaved status and
+        # histo_stat lanes stay Python-owned
+        self._pk_offs = np.asarray(
+            [self._pk_layout[name][0] for name in (
+                "counter_slot", "counter_inc", "gauge_slot", "gauge_val",
+                "set_slot", "set_reg", "set_rho", "histo_slot",
+                "histo_val", "histo_wt")], np.int32)
+
+    def _new_packed(self):
+        """One flat packed buffer, and beside it the staged-row counts of
+        that buffer's previous emit — vt_emit_packed's incremental
+        sentinel-restore bound."""
+        flat = np.zeros(self._pk_words, np.int32)
+        self._init_packed_sentinels(flat, self._pk_layout, self.spec)
+        return flat, np.zeros(4, np.uint32)
+
+    def _new_arena(self):
+        """One (rings, words) arena — a row per ring in the exact packed
+        layout — and its per-row previous counts. Every ring's emit lands
+        in its own row and the WHOLE arena crosses host->device as one
+        donated transfer per step (ingest_step_packed_rings), so R rings
+        cost one h2d RTT, not R."""
+        n_rings = self.eng.n_rings
+        arena = np.zeros((n_rings, self._pk_words), np.int32)
+        for row in arena:
+            self._init_packed_sentinels(row, self._pk_layout, self.spec)
+        return arena, np.zeros((n_rings, 4), np.uint32)
+
+    @staticmethod
+    def _init_packed_sentinels(flat, layout, spec):
+        """One-time sentinel fill of a fresh packed buffer: every slot
+        lane at its table capacity (scatter mode='drop' padding), weight
+        lanes 0, histo-stat min/max at +/-inf — the state Batcher.emit's
+        partial reset maintains on the Python path. After this, the six
+        C++-maintained lanes are kept in this state incrementally by
+        vt_emit_packed and the status/histo_stat regions are never
+        written again."""
+
+        def lane(name, value, f32=False):
+            off, n, _ = layout[name]
+            view = flat[off:off + n]
+            (view.view(np.float32) if f32 else view)[:] = value
+
+        lane("counter_slot", spec.counter_capacity)
+        lane("gauge_slot", spec.gauge_capacity)
+        lane("set_slot", spec.set_capacity)
+        lane("histo_slot", spec.histo_capacity)
+        lane("status_slot", spec.status_capacity)
+        lane("histo_stat_slot", spec.histo_capacity)
+        lane("histo_stat_min", np.inf, f32=True)
+        lane("histo_stat_max", -np.inf, f32=True)
+
+    def _emit_native(self):
+        flat, prev = self._step_buffer("packed", self._new_packed)
+        with hostspans.span("pipeline.emit"):
+            nc, ng, ns, nh = self.eng.emit_packed(flat, self._pk_offs, prev)
+        if nc + ng + ns + nh == 0:
+            return
+        flat[0] = 1 if self._count_step() else 0
+        self._dispatch_step(ingest_step_packed, flat, "packed",
+                            spec=self.spec, sizes=self._pk_sizes)
+
+    # -- native import path (global tier) ------------------------------
+    def import_pb_bytes(self, data: bytes):
+        """Decode + stage a serialized forwardrpc.MetricList with the
+        C++ engine (VERDICT r04 #5: the gRPC decode→slot path batched
+        the way wire ingest staging is; reference importsrv/server.go:97
+        SendMetrics). Counters/gauges/digests stage natively; sets,
+        valueless metrics, and oneof/type mismatches fall back to the
+        Python import_into path so error accounting matches the
+        reference's per-metric semantics. Returns (metrics, errors)."""
+        from veneur_tpu.forward.convert import import_into
+        from veneur_tpu.proto import metricpb_pb2 as mpb
+        total = 0
+        errors = 0
+        off = 0
+        while off < len(data):
+            staged, new_off, spans, lane_full = \
+                self.eng.import_metriclist(data, off)
+            total += staged + len(spans)
+            for so, sl in spans:
+                try:
+                    import_into(self, mpb.Metric.FromString(
+                        data[so:so + sl]))
+                except Exception as e:
+                    errors += 1
+                    log.warning("bad imported metric (native path): %s",
+                                e)
+            if new_off >= len(data):
+                break
+            if not lane_full and new_off == off and staged == 0 \
+                    and not spans:
+                # undecodable at a top-level boundary (NOT a lane stop):
+                # the Python deserializer would reject the whole request
+                # — count one error and drop the remainder
+                errors += 1
+                log.warning("undecodable MetricList tail at offset %d "
+                            "(%d bytes dropped)", off, len(data) - off)
+                break
+            # staging filled (or the fallback buffer did): free the
+            # lanes, then re-enter at the reported boundary
+            self._emit_native()
+            off = new_off
+        # per-digest exact min/max/recip ride the Python stats lane —
+        # scatter min/max/add are order-independent vs the centroid
+        # re-add, so batch boundaries don't matter
+        slots, mns, mxs, rcs = self.eng.drain_import_stats()
+        if len(slots):
+            self.batcher.add_histo_stats_bulk(slots, mns, mxs, rcs)
+        return total, errors
+
+    def _emit_rings(self) -> bool:
+        """Drain every ring's staging into the current arena's rows and
+        run ONE device step over the whole arena. Returns False (no step)
+        when all rings were empty — the common idle poll. The compact
+        control word rides row 0 only."""
+        n_rings = self.eng.n_rings
+        key = ("rings", n_rings)
+        arena, prev = self._step_buffer(key, self._new_arena)
+        total = 0
+        t0 = time.monotonic_ns()
+        for r in range(n_rings):
+            counts = self.eng.rings_emit(r, arena[r], self._pk_offs,
+                                         prev[r])
+            total += counts[0] + counts[1] + counts[2] + counts[3]
+        if total == 0:
+            return False
+        # stamped after the fact: an empty poll (one per pump call, far
+        # more often than a step) must leave no record
+        hostspans.record("pipeline.emit", t0, time.monotonic_ns())
+        arena[0, 0] = 1 if self._count_step() else 0
+        self._dispatch_step(ingest_step_packed_rings, arena, key,
+                            spec=self.spec, sizes=self._pk_sizes)
+        return True
+
+
+class NativeShardedAggregator(_NativeFeed, ShardedAggregator):
     """Mesh-sharded backend fed by the C++ parse/key/stage engine.
 
     The engine's slot space is shard-aware (dogstatsd.cpp KindTable:
@@ -549,12 +528,7 @@ class NativeShardedAggregator(ShardedAggregator):
                  n_shards: int = 2, compact_every: int = 8,
                  preshard: bool = False, engine=None):
         super().__init__(spec, bspec, n_shards, compact_every)
-        # engine reuse across a live reshard — see NativeAggregator
-        self.eng = engine if engine is not None \
-            else NativeIngest(spec, bspec, n_shards)
-        self.table = NativeKeyTable(spec, self.eng, n_shards)
-        self._py_processed = 0
-        self._py_dropped = 0
+        self._init_native(engine)
         self.preshard = preshard
         self._ps_bounds = np.zeros(4 * (n_shards + 1), np.int32)
         self._alloc_emit_buffers()
@@ -577,12 +551,6 @@ class NativeShardedAggregator(ShardedAggregator):
         self._h_slot = np.empty(b.histo, np.int32)
         self._h_val = np.zeros(b.histo, np.float32)
         self._h_wt = np.zeros(b.histo, np.float32)
-
-    # engine-backed stats (same split as NativeAggregator)
-    extra_parse_errors = NativeAggregator.extra_parse_errors
-    processed = NativeAggregator.processed
-    dropped_capacity = NativeAggregator.dropped_capacity
-    feed = NativeAggregator.feed
 
     _PER_SHARD_FIELD = {"counter": "counter_capacity",
                         "gauge": "gauge_capacity",
@@ -724,24 +692,6 @@ class NativeShardedAggregator(ShardedAggregator):
     # the packed and pre-sharded drains per ring; flush output is
     # byte-identical to the _split_shards path either way — pinned by
     # tests/test_native_preshard.py).
-    readers_start = NativeAggregator.readers_start
-    admission_set = NativeAggregator.admission_set
-    admission_drain = NativeAggregator.admission_drain
-    tenant_config = NativeAggregator.tenant_config
-    tenant_params = NativeAggregator.tenant_params
-    tenant_table = NativeAggregator.tenant_table
-    tenant_restore = NativeAggregator.tenant_restore
-    tenant_rows_drain = NativeAggregator.tenant_rows_drain
-    reader_counters = NativeAggregator.reader_counters
-    ring_stats = NativeAggregator.ring_stats
-    ring_stats_per_ring = NativeAggregator.ring_stats_per_ring
-    readers_stop = NativeAggregator.readers_stop
-
-    def rings_start(self, n_rings: int, fds=None, max_len: int = 65536,
-                    ring_cap: int = 65536, pin_cores=None) -> None:
-        self.eng.rings_start(n_rings, fds=fds, max_len=max_len,
-                             ring_cap=ring_cap, pin_cores=pin_cores)
-
     def _emit_rings(self) -> bool:
         emitted = False
         for r in range(self.eng.n_rings):
@@ -751,53 +701,3 @@ class NativeShardedAggregator(ShardedAggregator):
                 self._stage_presharded(nc, ng, ns, nh)
                 emitted = True
         return emitted
-
-    def pump(self, max_wait_ms: int, max_emits: int = 8) -> List[bytes]:
-        """Multi-ring drain into the per-shard batchers (see
-        NativeAggregator.pump for the bounding rationale)."""
-        if self.eng.n_rings:
-            self._pump(max_wait_ms)
-            for _ in range(max_emits):
-                if not self._emit_rings():
-                    break
-            return self.eng.drain_specials()
-        full = self._pump(max_wait_ms)
-        for _ in range(max_emits):
-            if not full:
-                break
-            self._emit_native()
-            full = self._pump(0)
-        if full:
-            self._emit_native()
-        return self.eng.drain_specials()
-
-    _pump = NativeAggregator._pump
-
-    def swap(self):
-        rings = bool(self.eng.n_rings)
-        with hostspans.span("swap.emit_staged"):
-            if rings:
-                self.eng.rings_pause()
-                self._emit_rings()
-            self._emit_native()
-        detached = self.table
-        # SlotMeta for every key of the interval, rebuilt from the
-        # engine's new-key records: host work in proportion to the live
-        # keys, on the pipeline thread
-        with hostspans.span("swap.finalize"):
-            detached.finalize()
-        state, _ = super().swap()
-        with hostspans.span("swap.reset"):
-            self.eng.reset()
-            self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
-            if rings:
-                self.eng.rings_resume()
-        return state, detached
-
-    def query_snapshot(self):
-        """See NativeAggregator.query_snapshot — same discipline over
-        the per-shard staging batchers."""
-        if self.eng.n_rings:
-            self._emit_rings()
-        self._emit_native()
-        return super().query_snapshot()

@@ -809,34 +809,34 @@ class Server:
                    lambda: self.span_pipeline.spans_received, kind="counter",
                    help="SSF spans accepted by the span pipeline")
         M.callback("veneur.device.h2d_bytes_total",
-                   lambda: getattr(self.aggregator, "h2d_bytes", 0),
+                   lambda: self.aggregator.h2d_bytes,
                    kind="counter",
                    help="packed ingest bytes shipped host-to-device")
         M.callback("veneur.device.step_ns_total",
-                   lambda: getattr(self.aggregator, "step_ns", 0),
+                   lambda: self.aggregator.step_ns,
                    kind="counter",
                    help="device ingest-step wall time including the "
                         "sampled block_until_ready sync (host side)")
         M.callback("veneur.device.dispatch_ns_total",
-                   lambda: getattr(self.aggregator, "dispatch_ns", 0),
+                   lambda: self.aggregator.dispatch_ns,
                    kind="counter",
                    help="device ingest-step dispatch-only wall time — "
                         "async enqueue cost, no sync (host side)")
         M.callback("veneur.device.steps_total",
-                   lambda: getattr(self.aggregator, "steps_total", 0),
+                   lambda: self.aggregator.steps_total,
                    kind="counter", help="device ingest steps dispatched")
         M.callback("veneur.device.compactions_total",
-                   lambda: getattr(self.aggregator, "compactions", 0),
+                   lambda: self.aggregator.compactions,
                    kind="counter",
                    help="ingest steps that carried the in-band digest "
                         "compaction (every tpu_compact_every-th)")
         M.callback("veneur.device.compact_rows_total",
-                   lambda: getattr(self.aggregator, "compact_rows", 0),
+                   lambda: self.aggregator.compact_rows,
                    kind="counter",
                    help="digest rows those compactions compressed "
                         "(the rows that took a sample since the last)")
         M.callback("veneur.device.steps_synced_total",
-                   lambda: getattr(self.aggregator, "steps_synced", 0),
+                   lambda: self.aggregator.steps_synced,
                    kind="counter",
                    help="ingest steps that ran a block_until_ready sync "
                         "(1-in-N sample plus swap boundaries)")
@@ -1557,7 +1557,7 @@ class Server:
         stats = {
             "seq": seq,
             "swap_ns": swap_ns,
-            "h2d_bytes": getattr(self.aggregator, "h2d_bytes", 0),
+            "h2d_bytes": self.aggregator.h2d_bytes,
             "packets_received": self.packets_received,
             "packets_dropped": self.packets_dropped,
             "packets_toolong": self.packets_toolong,

@@ -18,14 +18,14 @@ about device scale-out, not host parse throughput).
 
 from __future__ import annotations
 
-import collections
 from functools import partial
-from typing import List, Tuple
+from typing import Tuple
 
 import numpy as np
 
 from veneur_tpu.aggregation.host import Batcher, BatchSpec, KeyTable
 from veneur_tpu.aggregation.state import TableSpec
+from veneur_tpu.aggregation.step import batch_sizes, pack_batch
 from veneur_tpu.observability import hostspans
 from veneur_tpu.server.aggregator import Aggregator, set_member_bytes
 
@@ -114,9 +114,13 @@ _gather_sharded_raw = _jax.jit(_gather_sharded_raw_impl)
 
 
 class ShardedAggregator(Aggregator):
+    # replica rows of the mesh: one here; the collective tier
+    # (collective/tier.py), which is this backend over a mesh with a real
+    # replica axis, sets its own before it runs this constructor
+    n_replicas = 1
+
     def __init__(self, spec: TableSpec, bspec: BatchSpec = BatchSpec(),
                  n_shards: int = 2, compact_every: int = 8):
-        import jax
         from veneur_tpu.parallel import (
             make_mesh, make_merged_flush, make_sharded_ingest_packed,
             sharded_empty_state)
@@ -127,37 +131,23 @@ class ShardedAggregator(Aggregator):
         self.n_shards = n_shards
         self.compact_every = compact_every
 
-        self.mesh = make_mesh(1, n_shards)
+        self.mesh = make_mesh(self.n_replicas, n_shards)
         # packed ingest: each tile's batch ships as one i32 buffer with
         # the compact word in-band — mirrors the single-device backend
         # (one executable, one transfer per step per tile)
-        from veneur_tpu.aggregation.step import batch_sizes
         self._sizes = batch_sizes(Batcher(self.pspec, bspec).force_emit())
         self._ingest = make_sharded_ingest_packed(self.mesh, self.pspec,
                                                   self._sizes)
         self._flush = make_merged_flush(self.mesh, self.pspec)
-        self._empty = partial(sharded_empty_state, self.pspec, 1, n_shards,
-                              self.mesh)
+        self._empty = partial(sharded_empty_state, self.pspec,
+                              self.n_replicas, n_shards, self.mesh)
         self.state = self._empty()
         self.table = KeyTable(spec, n_shards)
+        # direct traffic (process_metric / import_metric / restore)
+        # stages into replica row 0
         self.batchers = self._make_batchers()
-        self._hll_slots: List[Tuple[int, int]] = []  # (shard, local_slot)
-        self._hll_rows: List[np.ndarray] = []
-        self._restore_residuals: list = []  # (batcher, local, lo) tails
-        self._steps = 0
-        self.processed = 0
-        self.dropped_capacity = 0
-        # same device-step accounting surface as the single-device
-        # Aggregator (observability callbacks read these by getattr):
-        # dispatch_ns = host-side dispatch, step_ns = sampled synced
-        # wall time (see Aggregator.__init__)
-        self.h2d_bytes = 0
-        self.step_ns = 0
-        self.dispatch_ns = 0
-        self.steps_total = 0
-        self.steps_synced = 0
-        self._steps_in_flight = collections.deque()
-        self._init_degrade()
+        # (its _hll_slots hold (shard, local_slot) pairs in this backend)
+        self._init_step_site()
 
     # -- slot routing --------------------------------------------------------
     def _local(self, kind: str, slot: int) -> Tuple[int, int]:
@@ -264,28 +254,26 @@ class ShardedAggregator(Aggregator):
                         on_batch=partial(self._on_shard_batch, i))
                 for i in range(self.n_shards)]
 
-    def _dispatch_row(self, row):
-        """Pack each shard's batch straight into its row of a persistent
-        [1, S, W] buffer (pack_batch `out`: no per-step allocation, no
+    def _new_row(self):
+        """One [R, S, W] host buffer, every tile an all-padding packed
+        batch. A step rewrites the tiles of replica row 0; rows 1..R-1
+        of the collective tier's mesh stay padding (absorbed traffic
+        reaches them through its routed path instead)."""
+        pad = pack_batch(Batcher(self.pspec, self.bspec).force_emit())
+        return np.broadcast_to(
+            pad, (self.n_replicas, self.n_shards) + pad.shape).copy()
+
+    def _dispatch_row(self, row, force_compact: bool = False):
+        """Pack each shard's batch straight into its tile of a persistent
+        [R, S, W] buffer (pack_batch `out`: no per-step allocation, no
         np.stack pass) and run the fused mesh step; compaction rides the
         in-band control word at the same cadence as the single-device
-        backend (Aggregator._on_batch). Two whole [1, S, W] buffers
-        alternate so step N+1 packs while step N's transfer is in
-        flight."""
-        from veneur_tpu.aggregation.step import pack_batch, packed_layout
-        dc = self._count_step()
-        bufs = getattr(self, "_row_bufs", None)
-        if bufs is None:
-            words = packed_layout(self._sizes)[1]
-            bufs = self._row_bufs = [
-                np.zeros((1, self.n_shards, words), np.int32),
-                np.zeros((1, self.n_shards, words), np.int32), 0]
-        flat = bufs[bufs[2]]
-        bufs[2] ^= 1
+        backend (Aggregator._on_batch)."""
+        dc = self._count_step(force_compact)
+        flat = self._step_buffer("row", self._new_row)
         for i, b in enumerate(row):
             pack_batch(b, dc, out=flat[0, i])
-        self.h2d_bytes += flat.nbytes
-        self._dispatch_step(self._ingest, flat)
+        self._dispatch_step(self._ingest, flat, "row")
 
     def _on_shard_batch(self, shard: int, batch):
         self._dispatch_row([batch if i == shard else b.force_emit()

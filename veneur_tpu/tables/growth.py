@@ -86,9 +86,9 @@ def grow_swap(server, new_spec) -> Tuple[object, object, object]:
     # lifetime-counter continuity (same set the reshard drain carries)
     new_agg.processed = old.processed
     new_agg.dropped_capacity = old.dropped_capacity
-    new_agg.h2d_bytes = getattr(old, "h2d_bytes", 0)
-    new_agg.last_set_shift = getattr(old, "last_set_shift", 0)
-    if getattr(old, "_pressure", None) is not None:
+    new_agg.h2d_bytes = old.h2d_bytes
+    new_agg.last_set_shift = old.last_set_shift
+    if old._pressure is not None:
         new_agg.set_pressure(old._pressure)
     server.aggregator = new_agg
     server._native = native
