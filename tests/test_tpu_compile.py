@@ -15,6 +15,7 @@ shape is built in a fixture or a test, never at import.
 """
 
 import dataclasses
+import re
 from functools import partial
 
 import jax
@@ -112,6 +113,22 @@ def _state_shapes(spec, sharding, lead=()):
                                        sharding=sharding), shapes)
 
 
+def _assert_digest_tables_in_rows(compiled, spec) -> int:
+    """Wherever a digest table is a parameter or a result of the compiled
+    program it lies in rows ('{1,0:T(8,128)}'), and the program holds no
+    copy of a whole table. Returns how many parameters and results of a
+    table's shape the entry computation has."""
+    text = compiled.as_text()
+    table = re.escape(f"f32[{spec.histo_capacity},{spec.stored_cells}]")
+    entry = re.search(r"entry_computation_layout=\{(.*?)\}, \w+=", text)
+    layouts = re.findall(table + r"(\{[^}]*\})", entry.group(1))
+    assert all(ly.startswith("{1,0") for ly in layouts), layouts
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(table + r"\S* copy\(", ln)]
+    assert not copies, copies
+    return len(layouts)
+
+
 def _flat(sizes, sharding, lead=()):
     words = step.packed_layout(sizes)[1]
     return jax.ShapeDtypeStruct(lead + (words,), jnp.int32,
@@ -126,6 +143,7 @@ def test_default_spec_is_the_shipped_one(default_spec, default_sizes):
                                              16384)
     assert default_spec.hll_precision == 14
     assert default_spec.total_cells == 472
+    assert default_spec.stored_cells == 512
     assert (default_sizes[0], default_sizes[2], default_sizes[4],
             default_sizes[6], default_sizes[9]) == (8192, 2048, 256, 4096,
                                                     8192)
@@ -148,17 +166,44 @@ def test_packed_ingest_program_compiles_under_1gib(one_chip, served):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+def test_packed_step_keeps_the_digest_tables_in_rows(one_chip, served,
+                                                     monkeypatch):
+    """ingest_step_packed as the chip runs it (the fused kernel
+    selected): a digest row is stored stored_cells = 512 wide, so the
+    device's default layout of both tables is rows and the program takes
+    them, works on them and hands them back so. It holds no copy of a
+    whole table, where it held six and ran four a step while the tables
+    were 472 wide and lay column-major by default (3.08 of 9.24 ms a step
+    at 131072 rows: PERF.md, PR 33), and its temporaries stay under a
+    quarter of the chain's bound. Nothing is stated to the compiler; the
+    program keeps the name the trace readers look for."""
+    from veneur_tpu.ops import pallas_ingest
+    monkeypatch.setattr(pallas_ingest, "active", lambda: True)
+    monkeypatch.setattr(pallas_ingest, "interpret_mode", lambda: False)
+    spec, sizes, scale = served
+    compiled = step.ingest_step_packed.lower(
+        _state_shapes(spec, one_chip), _flat(sizes, one_chip),
+        spec=spec, sizes=sizes).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_packed_step_core,"), text[:80]
+    assert "tpu_custom_call" in text
+    # h_wm and h_w, in and out
+    assert _assert_digest_tables_in_rows(compiled, spec) == 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < scale * GIB // 4, mem
+
+
 def test_compress_rows_compiles_without_scatter_or_gather(one_chip, served):
-    """compress_rows on one block of compact_core's loop, [R, 472] -> 280
-    as shipped: the chip's compiler fuses its compare and select into its
-    reduces, so the program holds a few [R, M] arrays and nothing the
-    size of the [R, M, out_c] compare, and no scatter or gather (the
-    scatter form took 0.57 s a call on the v5e; PERF.md PR 29). The
-    block does not grow with the table, so both heights compile the same
-    program."""
+    """compress_rows on one block of compact_core's loop, [R, 512] (a
+    stored row: 472 cells and their pad) -> 280 as shipped: the chip's
+    compiler fuses its compare and select into its reduces, so the
+    program holds a few [R, M] arrays and nothing the size of the
+    [R, M, out_c] compare, and no scatter or gather (the scatter form
+    took 0.57 s a call on the v5e; PERF.md PR 29). The block does not
+    grow with the table, so both heights compile the same program."""
     from veneur_tpu.ops import tdigest as td
     spec = served[0]
-    r, m_len = step.COMPACT_ROW_BLOCK, spec.total_cells
+    r, m_len = step.COMPACT_ROW_BLOCK, spec.stored_cells
     rows = jax.ShapeDtypeStruct((r, m_len), jnp.float32, sharding=one_chip)
     compiled = jax.jit(partial(
         td.compress_rows, compression=spec.compression,
@@ -174,18 +219,19 @@ def test_compress_rows_compiles_without_scatter_or_gather(one_chip, served):
 def test_compaction_compiles_with_whole_row_copies_only(one_chip, served):
     """compact_core over the digest table at both heights: the only
     gathers and scatters of table rows are the loop's copies of whole
-    rows (slice width = total_cells; an element-wise gather inside a row
-    is what PR 29 took out), told that their ids are sorted and unique,
-    and the temporaries stay under a few tables' worth (two of them are
-    the row-major copies the row copies work on; the device keeps the
-    tables column-major)."""
-    import re
-    spec = served[0]
+    rows (slice width = stored_cells; an element-wise gather inside a row
+    is what PR 29 took out), told that their ids are sorted and unique.
+    The tables lie in rows by default (stored_cells is a multiple of 128
+    lanes), so the row copies work on them in place: no copy of a whole
+    table, where two went in and two came out while a row was 472 wide,
+    and the temporaries are a few blocks of rows, under one table's
+    worth at the shipped height."""
+    spec, _sizes, scale = served
     compiled = jax.jit(partial(step.compact_core, spec=spec),
                        donate_argnums=(0,)).lower(
         _state_shapes(spec, one_chip)).compile()
     text = compiled.as_text()
-    n, m_len = spec.histo_capacity, spec.total_cells
+    n, m_len = spec.histo_capacity, spec.stored_cells
     table = re.escape(f"f32[{n},{m_len}]")
     block = re.escape(f"f32[{step.COMPACT_ROW_BLOCK},{m_len}]")
     gathers = [ln for ln in text.splitlines() if " gather(" in ln]
@@ -205,8 +251,9 @@ def test_compaction_compiles_with_whole_row_copies_only(one_chip, served):
     for ln in gathers:
         assert ln in row_gathers or " f32[" not in ln.split(" gather(")[0], ln
     assert " sort(" in text
+    assert _assert_digest_tables_in_rows(compiled, spec) == 4
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 8 * n * m_len * 4, mem
+    assert mem.temp_size_in_bytes < 8 * n * m_len * 4 // scale, mem
 
 
 def test_live_flush_program_compiles(one_chip, served, monkeypatch):
@@ -229,6 +276,27 @@ def test_live_flush_program_compiles(one_chip, served, monkeypatch):
             _state_shapes(spec, one_chip), flat).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < scale * GIB, mem
+
+
+def test_live_flush_program_takes_the_digest_tables_in_rows(one_chip,
+                                                            served):
+    """flush.gather wants rows too, and gets the tables so: no copy of a
+    table on the way in (two, a pass over each table, while a row was 472
+    wide). The digest bucket is half the table, so that nothing else in
+    the program has a table's shape."""
+    spec = served[0]
+    buckets = (spec.counter_capacity, spec.gauge_capacity,
+               spec.status_capacity, spec.set_capacity,
+               spec.histo_capacity // 2)
+    n_q = 3
+    flat = jax.ShapeDtypeStruct((n_q + sum(buckets),), jnp.int32,
+                                sharding=one_chip)
+    compiled = step.flush_live_in_packed.lower(
+        _state_shapes(spec, one_chip), flat, spec=spec, n_q=n_q,
+        buckets=buckets).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__flush_live_in_packed_core,")
+    assert _assert_digest_tables_in_rows(compiled, spec) == 2
 
 
 def test_digest_quantile_kernel_compiles(one_chip):
@@ -263,11 +331,12 @@ def test_history_merge_kernel_compiles(one_chip):
 def test_fused_ingest_kernel_compiles(one_chip, served):
     """The kernel alone (plus the fold), every state leaf aliased in
     place: no temporaries beyond the sorted streams, as long as the two
-    digest tables fit the chip's 128 MiB of VMEM together (2 x 31 MB as
-    shipped). The compiler keeps them column-major between programs and
-    the kernel reads rows, so each is copied into the kernel's layout and
-    back; above that size the copies are HBM temporaries, one table of
-    rows x 512 lanes each (2 x 268 MB at 131072 rows)."""
+    digest tables fit the chip's 128 MiB of VMEM together (2 x 34 MB as
+    shipped). The tables lie in rows by default (a stored row is 512
+    wide) and the kernel reads rows, so nothing is copied into a layout
+    of the kernel's own: at 472 wide each table went into rows and back,
+    through HBM temporaries of rows x 512 lanes above that size (2 x 268
+    MB at 131072 rows)."""
     from veneur_tpu.ops import pallas_ingest
     assert pallas_ingest.ENABLED
     spec, sizes, scale = served
@@ -281,9 +350,8 @@ def test_fused_ingest_kernel_compiles(one_chip, served):
         _state_shapes(spec, one_chip), _flat(sizes, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
-    tables = 2 * spec.histo_capacity * spec.total_cells * 4
-    relayout = 0 if tables < 128 << 20 else 2 * spec.histo_capacity * 512 * 4
-    assert mem.temp_size_in_bytes < relayout + (scale * 64 << 20), mem
+    assert _assert_digest_tables_in_rows(compiled, spec) == 4
+    assert mem.temp_size_in_bytes < scale * 64 << 20, mem
 
 
 @pytest.fixture(scope="module")

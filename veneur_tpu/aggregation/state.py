@@ -75,6 +75,26 @@ class TableSpec:
         return self.centroids + self.temp_cells
 
     @property
+    def stored_cells(self) -> int:
+        """Columns a digest row is STORED in: total_cells rounded up to
+        whole 128-lane tiles (472 -> 512). Columns [total_cells,
+        stored_cells) are never written and hold zero weight, which every
+        reader of a row already takes for an empty cell.
+
+        The width decides which way the table lies on a TPU: of the two
+        (8, 128)-tiled layouts the device's default is the one that pads
+        less, and f32[Kh, 472] pads 472 to 512 lanes in rows but nothing
+        in columns, so by default it lay column-major between programs
+        while everything in the step wants rows (the plan's row gather,
+        the fused kernel's row blocks, the compaction's row copies): every
+        ingest step turned both tables into rows and back, four passes
+        over a whole table, 3.08 of 9.24 ms at 131072 rows (PERF.md,
+        PR 33). At a multiple of 128 rows pad nothing, the default is
+        row-major and the copies are gone; the row occupies the 512
+        lanes it occupied in rows before."""
+        return -(-self.total_cells // 128) * 128
+
+    @property
     def registers(self) -> int:
         return hll.num_registers(self.hll_precision)
 
@@ -109,9 +129,10 @@ class DeviceState(NamedTuple):
     hll: jax.Array           # i32[Ks, W] where W = ceil(R*6/32)
     # histograms / timers: digest as (wm, w) + exact scalar aggregates.
     # Columns [0, C) are canonical k-cells; columns [C, C+T) are raw temp
-    # cells holding individual samples since the last compaction.
-    h_wm: jax.Array          # f32[Kh, C+T]  sum of weight*mean per cell
-    h_w: jax.Array           # f32[Kh, C+T]
+    # cells holding individual samples since the last compaction; columns
+    # [C+T, W) are the pad up to TableSpec.stored_cells, never written.
+    h_wm: jax.Array          # f32[Kh, W]  sum of weight*mean per cell
+    h_w: jax.Array           # f32[Kh, W]
     # h_temp_n is the dirty mark too: a row's first sample of a cycle always
     # lands in a temp cell (step._histo_plan), so h_temp_n > 0 exactly on
     # the rows that took a sample since the last compaction, and those are
@@ -140,7 +161,7 @@ def empty_state_compiled(spec: TableSpec) -> DeviceState:
 def empty_state(spec: TableSpec) -> DeviceState:
     f = jnp.float32
     kc, kg, kst = spec.counter_capacity, spec.gauge_capacity, spec.status_capacity
-    ks, kh, c = spec.set_capacity, spec.histo_capacity, spec.total_cells
+    ks, kh, c = spec.set_capacity, spec.histo_capacity, spec.stored_cells
     z = jnp.zeros
     return DeviceState(
         counter_acc=z((kc,), f), counter_hi=z((kc,), f), counter_lo=z((kc,), f),

@@ -132,7 +132,7 @@ def _histo_plan(state: DeviceState, slot, val, wt, spec: TableSpec):
     # mass of the current digest below each sample value (temp cells
     # participate: their "means" are raw sample values)
     sc = jnp.minimum(s, kh - 1)
-    row_w = state.h_w[sc]                     # f32[B, C+T]
+    row_w = state.h_w[sc]                     # f32[B, W]
     row_wm = state.h_wm[sc]
     row_mean = row_wm / jnp.maximum(row_w, 1e-30)
     w_main = jnp.sum(row_w, axis=-1)
@@ -468,7 +468,8 @@ def compact_core(state: DeviceState, *, spec: TableSpec) -> DeviceState:
     ids = jnp.concatenate([
         jnp.sort(jnp.where(state.h_temp_n > 0, row, kh + row)),
         2 * kh + jnp.arange(-kh % r, dtype=jnp.int32)])
-    pad = jnp.zeros((r, spec.temp_cells), state.h_w.dtype)
+    pad = jnp.zeros((r, state.h_w.shape[-1] - spec.centroids),
+                    state.h_w.dtype)
     take = dict(mode="clip", unique_indices=True, indices_are_sorted=True)
     put = dict(take, mode="drop")
 
@@ -599,8 +600,10 @@ def flush_live_core(state: DeviceState, qs: jax.Array, cidx, gidx, stidx,
     if want_raw:
         # forwarding needs the mergeable sketch state of live rows
         out["raw_hll"] = hll_rows
-        out["raw_h_mean"] = mean
-        out["raw_h_weight"] = w
+        # the digest rows leave with their total_cells columns, without
+        # the stored row's pad (TableSpec.stored_cells)
+        out["raw_h_mean"] = mean[:, :spec.total_cells]
+        out["raw_h_weight"] = w[:, :spec.total_cells]
     return out
 
 

@@ -96,7 +96,8 @@ def digest_axis_merge(wm, w, axis: str = REPLICA_AXIS, *,
     concatenate along the centroid axis, re-compress to canonical cells
     (the fixed-shape analogue of Histo.Merge digest re-add,
     samplers/samplers.go:726). Returns (h_wm, h_w) in the state's
-    [C + temp] column layout with the temp cells emptied."""
+    column layout (TableSpec.stored_cells wide) with the temp cells
+    emptied."""
     wm = jax.lax.all_gather(wm, axis)   # [Rg, r_local, s, K, C]
     w = jax.lax.all_gather(w, axis)
     wm = jnp.moveaxis(wm.reshape((-1,) + wm.shape[2:]), 0, -2)  # [s,K,R,C]
@@ -109,7 +110,7 @@ def digest_axis_merge(wm, w, axis: str = REPLICA_AXIS, *,
                               cells_per_k=spec.cells_per_k,
                               out_c=spec.centroids,
                               exact_extremes=spec.exact_extremes)
-    pad = jnp.zeros(w2.shape[:-1] + (spec.temp_cells,), w2.dtype)
+    pad = jnp.zeros(w2.shape[:-1] + (c - spec.centroids,), w2.dtype)
     w2 = jnp.concatenate([w2, pad], axis=-1)
     wm2 = jnp.concatenate([m2 * w2[..., :spec.centroids], pad], axis=-1)
     return wm2, w2

@@ -373,7 +373,8 @@ class CollectiveGlobalTier(ShardedAggregator):
         merge_synced_dt = time.perf_counter_ns() - t0
         self._phase_timer.observe(merge_synced_dt, phase="replica_merge")
         r = unpack_flush(
-            np.asarray(_gather_sharded_raw(merged, setidx, hidx)),
+            np.asarray(_gather_sharded_raw(
+                merged, setidx, hidx, cells=self.pspec.total_cells)),
             _sharded_raw_shapes(self.pspec, len(setidx), len(hidx)))
         raw = {
             "counter": result["counter"],

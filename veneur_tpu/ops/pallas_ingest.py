@@ -112,7 +112,7 @@ def _tiles(spec: TableSpec):
     return (_tile_1d(spec.counter_capacity), _tile_1d(spec.gauge_capacity),
             _tile_1d(spec.status_capacity),
             _row_tile(spec.set_capacity, (1 << 18) // spec.hll_words),
-            _row_tile(spec.histo_capacity, (1 << 17) // spec.total_cells),
+            _row_tile(spec.histo_capacity, (1 << 17) // spec.stored_cells),
             _tile_1d(spec.histo_capacity))
 
 
@@ -181,7 +181,7 @@ def fused_ingest_core(state: DeviceState, batch, *, spec: TableSpec,
     lc, lg, lst, lh = (_lanes(caps[0]), _lanes(caps[1]), _lanes(caps[2]),
                        _lanes(caps[4]))
     w_words = spec.hll_words
-    cells = spec.total_cells
+    cells = spec.stored_cells
 
     # the XLA chain's scope names (step.ingest_core) on each kind's
     # stream preparation; the kernel itself is `fused_ingest`

@@ -65,10 +65,12 @@ def _gather_sharded_impl(out, cidx, gidx, stidx, setidx, hidx):
                             for k in sorted(out)])
 
 
-def _gather_sharded_raw_impl(st, setidx, hidx):
+def _gather_sharded_raw_impl(st, setidx, hidx, *, cells: int):
     """Raw sketch state of live rows, packed like the flush gather (one
     transfer; 6-bit packed i32 HLL rows ride as bitcast f32 words — safe
-    for the same run-of-set-bits reason as step._pack_outputs)."""
+    for the same run-of-set-bits reason as step._pack_outputs). A digest
+    row leaves with its `cells` = TableSpec.total_cells columns, without
+    the stored row's pad."""
     import jax
     import jax.numpy as jnp
 
@@ -76,11 +78,11 @@ def _gather_sharded_raw_impl(st, setidx, hidx):
         flat = x.reshape((-1,) + x.shape[3:])   # drop [R=1, S]
         return jnp.take(flat, i, axis=0, mode="clip")
 
-    w = take(st.h_w, hidx)
+    w = take(st.h_w, hidx)[:, :cells]
     out = {
         "hll": take(st.hll, setidx),
         "h_weight": w,
-        "h_mean": take(st.h_wm, hidx) / jnp.maximum(w, 1e-30),
+        "h_mean": take(st.h_wm, hidx)[:, :cells] / jnp.maximum(w, 1e-30),
         "h_min": take(st.h_min, hidx),
         "h_max": take(st.h_max, hidx),
         "recip_hi": take(st.h_recip_hi, hidx),
@@ -110,7 +112,8 @@ def _sharded_raw_shapes(pspec, n_set, n_h):
 import jax as _jax
 
 _gather_sharded = _jax.jit(_gather_sharded_impl)
-_gather_sharded_raw = _jax.jit(_gather_sharded_raw_impl)
+_gather_sharded_raw = _jax.jit(_gather_sharded_raw_impl,
+                               static_argnames=("cells",))
 
 
 class ShardedAggregator(Aggregator):
@@ -396,7 +399,8 @@ class ShardedAggregator(Aggregator):
             from veneur_tpu.aggregation.step import unpack_flush as _unpack
             with hostspans.span("flush_dispatch"):
                 gathered = _gather_sharded_raw(
-                    state, idx["set"], idx["histogram"])
+                    state, idx["set"], idx["histogram"],
+                    cells=self.pspec.total_cells)
             with hostspans.span("flush_d2h"):
                 r = _unpack(np.asarray(gathered), _sharded_raw_shapes(
                     self.pspec, len(idx["set"]), len(idx["histogram"])))
