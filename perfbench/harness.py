@@ -32,6 +32,7 @@ import traffic as T
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 INTERVAL_S = 10.0
+STALL_NS = 250_000_000      # a round of the credit's publisher this long is said
 
 
 class RunError(RuntimeError):
@@ -204,6 +205,7 @@ class Run:
         self.server = self.child = self.ctl = self.mm = None
         self._publisher = None
         self._stop_publish = threading.Event()
+        self.pub = {"n": 0, "gap_max_ns": 0, "ring_empty": 0, "ahead": 0}
         self._shut = True
 
     # the control block
@@ -221,9 +223,36 @@ class Run:
         return True
 
     def _publish(self):
+        """Copies the engine's count into the control block about once a
+        millisecond, and keeps for the per-interval line what the credit's
+        round trip looked like while the sender was running: how many
+        times it published, the longest time between two, how often the
+        ring stood empty and how far ahead the sender was. A round of
+        over STALL_NS starves the sender of credit for a real part of a
+        stretch, so it is said at once, with where the time went: asleep
+        or waiting for the interpreter, or in eng.stats() (the engine's key
+        lock, and the interpreter again on the way back)."""
         eng, ctl, base = self.server.aggregator.eng, self.ctl, self.base
+        pub, clock = self.pub, time.monotonic_ns
+        last = clock()
         while not self._stop_publish.is_set():
-            ctl[S.PROCESSED] = eng.stats()["processed"] - base
+            t_woke = clock()
+            done = eng.stats()["processed"] - base
+            ctl[S.PROCESSED] = done
+            now = clock()
+            if ctl[S.CMD] == S.RUN:
+                pub["n"] += 1
+                pub["gap_max_ns"] = max(pub["gap_max_ns"], now - last)
+                pub["ring_empty"] += eng.reader_counters()["ring_depth"] == 0
+                pub["ahead"] += ctl[S.SENT] - done
+                if now - last > STALL_NS:
+                    say(f"credit: not published for {(now - last) / 1e6:.0f} "
+                        f"ms ({(t_woke - last) / 1e6:.0f} asleep or waiting "
+                        f"for the interpreter, {(now - t_woke) / 1e6:.0f} in "
+                        "eng.stats() and back), "
+                        f"{(now - self.phases[-1][2]) / 1e9:.2f} s after the "
+                        "last resume")
+            last = clock()
             time.sleep(0.001)
 
     def _processed(self) -> int:
@@ -245,6 +274,9 @@ class Run:
         tick's record; the sender is running again when this returns."""
         t_pause = time.monotonic_ns()
         b, sent, t_last = self._pause()
+        blocked_ns, pub = self.ctl[S.BLOCKED_NS], dict(self.pub)
+        pub["poll_max_ns"], self.ctl[S.POLL_MAX_NS] = self.ctl[S.POLL_MAX_NS], 0
+        self.pub["gap_max_ns"] = 0
         drained = self._wait(self._drained, "the engine to drain", 20)
         over = self._processed() - self.ctl[S.SENT]
         if over:
@@ -265,7 +297,8 @@ class Run:
         t_swap = time.monotonic_ns()
         rec = {"b": b, "sent": sent, "t_ns": t_last, "req": req,
                "drained": drained, "swapped": swapped, "t_pause": t_pause,
-               "t_req": t_req, "t_swap": t_swap}
+               "t_req": t_req, "t_swap": t_swap, "blocked_ns": blocked_ns,
+               "pub": pub}
         if wait_flush and not req.wait(900):
             raise RunError(f"the warm-up flush failed: {req.detail}")
         self.ctl[S.CMD] = S.RUN
@@ -386,29 +419,30 @@ class Run:
         self.tick_log.append(tick0)
         c_start = counters(self.server)
         p_start = phase_totals(self.server)
-        blocked0 = ctl[S.BLOCKED_NS]
         say(f"setup: {setup_s:.3f} s, compiles {c_start['compiles_total']} "
             f"({(c_start['compile_ns'] - c0['compile_ns']) / 1e9:.2f} s "
             f"compiling or loading)")
 
-        # the window: tick k at t0 + 10 k seconds
-        trace_tick = min(2, self.ticks) if self.trace else 0
+        # the window: tick k at t0 + 10 k seconds. A traced run profiles
+        # the last tick, from 3 s before it until its flush is out, and
+        # stops the profiler once the window has closed: stop_trace holds
+        # the interpreter for seconds (4 to 20 by the cell), and inside the
+        # window that made the next tick late and its interval half as
+        # long again as the others (PERF.md, section 6)
         trace_t0 = None
         for k in range(1, self.ticks + 1):
             due = t0 + int(k * INTERVAL_S * 1e9)
-            if k == trace_tick:
+            if self.trace and k == self.ticks:
                 time.sleep(max(0.0, (due - 3e9 - time.monotonic_ns()) / 1e9))
                 trace_t0 = self._trace_start()
             time.sleep(max(0.0, (due - time.monotonic_ns()) / 1e9))
             rec = self._tick(wait_flush=False)
             self.tick_log.append(rec)
-            if k == self.ticks:
-                blocked1 = ctl[S.BLOCKED_NS]
-                c_end = counters(self.server)
-            if k == trace_tick:
-                rec["req"].done.wait(8)
-                time.sleep(0.5)
-                self._trace_stop(trace_t0)
+        c_end = counters(self.server)
+        if self.trace:
+            rec["req"].done.wait(8)
+            time.sleep(0.5)
+            self._trace_stop(trace_t0)
         # The window ends as it began, at a swap: the engine has parsed
         # all that was sent and the device has worked off its queue (the
         # swap waits for it), so the samples counted are the work done in
@@ -431,11 +465,10 @@ class Run:
         say("serve: clean shutdown")
 
         return self._judge(t0, window_s, setup_s, c_start, c_end, c_after,
-                           p_start, p_end, blocked1 - blocked0, peak,
-                           digest_child)
+                           p_start, p_end, peak, digest_child)
 
     def _judge(self, t0, window_s, setup_s, c_start, c_end, c_after, p_start,
-               p_end, blocked_ns, peak, digest_child) -> dict:
+               p_end, peak, digest_child) -> dict:
         cell, cfgf = self.cell, self.cell["config_file"]
         log, frames = self.tick_log, self.sink.handed
         attempted = log[-1]["sent"] - log[0]["sent"]
@@ -444,6 +477,7 @@ class Run:
             raise RunError("the sender's pool is not the reference's pool")
         percentiles = cfgf["expect"]["percentiles"]
         numbers, examples = reference.new_numbers(percentiles), []
+        widest = {}                   # per percentile: the timer behind _rank_max
         failed, latencies = 0, []
         # flushes are emitted in order: the frames are those of the
         # warm-up, of tick 0 and of each tick whose request succeeded
@@ -478,14 +512,19 @@ class Run:
                     if name.startswith((self.prefix + ".c.",
                                         self.prefix + ".s.")):
                         got[name] = low[name]
+            here = {}
             reference.compare(got, tags, twice, want, timers, percentiles,
-                              self.prefix, numbers, examples)
+                              self.prefix, numbers, examples, here)
+            for name, w in here.items():
+                if w["err"] >= widest.get(name, w)["err"]:
+                    widest[name] = dict(w, interval=k)
             say(f"interval {k}: {sent_k} samples, {cycles:.2f} pool cycles, "
                 f"{len(got)} rows, pause "
                 f"{(rec['t_resume'] - rec['t_pause']) / 1e6:.1f} ms (drain "
                 f"{(rec['t_req'] - rec['t_pause']) / 1e6:.1f}, swap "
                 f"{(rec['t_swap'] - rec['t_req']) / 1e6:.1f}), "
-                f"tick to sink {latencies[-1]:.3f} s")
+                f"tick to sink {latencies[-1]:.3f} s; "
+                + stretch_line(prev, rec, sent_k))
         dropped = ((c_after["eng.dropped"] - c_start["eng.dropped"])
                    + lines_of(c_after, c_start, "ring.ring_dropped", pool)
                    + lines_of(c_after, c_start, "packets_dropped", pool)
@@ -503,6 +542,13 @@ class Run:
                        if k not in reference.EXACT))
         for line in examples[:8]:
             say("  " + line)
+        for name, w in widest.items():
+            say(f"  {name} widest: interval {w['interval']}, timer {w['timer']} "
+                f"of {w['n']} samples emitted {w['got']!r} for an exact "
+                f"{w['exact']!r} (its largest sample {w['max']!r}), rank "
+                f"error {w['err']:.3e}; {w['timers_near']} timers over half "
+                f"of that, {100 * w['their_sample_share']:.2f} % of the "
+                "samples")
 
         harness = {
             "samples_per_s": attempted / window_s if window_s > 0 else None,
@@ -511,8 +557,17 @@ class Run:
                                     if latencies else None),
             "setup_s": setup_s,
         }
+        # the window is its K sending stretches (resume to pause) and what
+        # lies between them: the ticks' pauses, as far as they are inside
+        t1 = log[-1]["t_swap"]
+        paused_ns = sum(max(0, min(end, t1) - max(start, t0))
+                        for name, start, end in self.phases
+                        if name == "tick_pause")
         pseudo = {"window_samples": attempted, "window_ns": window_s * 1e9,
-                  "sender_blocked_ns": blocked_ns}
+                  "sender_blocked_ns": (log[-1]["blocked_ns"]
+                                        - log[0]["blocked_ns"]),
+                  "tick_pause_ns": paused_ns,
+                  "send_stretch_ns": window_s * 1e9 - paused_ns}
         ctx = {
             "pool": pool, "harness": harness, "info": self.info,
             "counters_start": {**c_start, **{k: 0 for k in pseudo}},
@@ -528,8 +583,28 @@ class Run:
                 loaded, self.trace_span, self.trace_mark_ns, self.phases)
         return {"ctx": ctx, "correct": bool(ok), "attempted": int(attempted),
                 "failed": int(failed), "compared": rows, "numbers": numbers,
+                "widest": widest,
                 "harness": harness,
                 "memory_peak_bytes": peak}
+
+
+def stretch_line(prev: dict, rec: dict, sent: int) -> str:
+    """The interval's sending stretch, resume to pause: its own rate, how
+    long the sender waited for credit in it, and the credit's round trip
+    as the publisher saw it (Run._publish)."""
+    stretch_ns = rec["t_pause"] - prev["t_resume"]
+    blocked_ns = rec["blocked_ns"] - prev["blocked_ns"]
+    n = max(1, rec["pub"]["n"] - prev["pub"]["n"])
+    empty = rec["pub"]["ring_empty"] - prev["pub"]["ring_empty"]
+    ahead = rec["pub"]["ahead"] - prev["pub"]["ahead"]
+    return (f"stretch {stretch_ns / 1e9:.3f} s at "
+            f"{sent / (stretch_ns / 1e9):.0f} samples/s, sender blocked "
+            f"{blocked_ns / 1e6:.1f} ms ({100 * blocked_ns / stretch_ns:.1f} %), "
+            f"credit published {n} times, longest gap "
+            f"{rec['pub']['gap_max_ns'] / 1e6:.1f} ms (the sender's longest "
+            f"look for credit {rec['pub']['poll_max_ns'] / 1e6:.1f} ms), ring "
+            f"empty at {100 * empty / n:.1f} % of them, sender ahead "
+            f"{ahead / n:.0f} samples on average")
 
 
 def lines_of(after: dict, before: dict, key: str, pool) -> int:

@@ -148,7 +148,8 @@ def rank_errors(t: TimerSamples, got: np.ndarray, q: float) -> np.ndarray:
     linear interpolation `hazen` inverts, and with the pool cycled c times
     (every value tied c times) it does not charge an interpolated value
     for the half step of c / n that no estimate can resolve. Below the
-    least sample the rank is 0, above the largest 1."""
+    least sample the rank is 0, above the largest 1. The value is first
+    taken for the sample it lies within four float32 units of, if any."""
     n_runs, total = len(t.starts), len(t.values)
     if np.any(t.values < 0):
         raise ValueError("timer values are latencies, not negative")
@@ -158,6 +159,18 @@ def rank_errors(t: TimerSamples, got: np.ndarray, q: float) -> np.ndarray:
     base = np.arange(n_runs) * span + 1.0
     g = np.where(np.isfinite(got), got, span)
     g = np.clip(g, -1.0, span - 3.0)
+    # The answer is a float32: one within four units in its last place of
+    # a sample is that sample. A digest's mean of tied samples rounds, so
+    # a percentile that is the largest sample can come out a unit or two
+    # above it, which is no rank error of 1 - q (PERF.md, section 2).
+    tol = 4.0 * np.spacing(np.abs(g).astype(np.float32)).astype(np.float64)
+    at = np.searchsorted(keys, base + g, "left") - t.starts
+    under = t.values[np.clip(t.starts + at - 1, 0, total - 1)]
+    over = t.values[np.clip(t.starts + at, 0, total - 1)]
+    d_under = np.where(at > 0, g - under, np.inf)
+    d_over = np.where(at < t.lens, over - g, np.inf)
+    g = np.where((d_over <= tol) & (d_over <= d_under), over,
+                 np.where(d_under <= tol, under, g))
     lo = np.searchsorted(keys, base + g, "left") - t.starts     # samples < g
     hi = np.searchsorted(keys, base + g, "right") - t.starts    # samples <= g
     a = t.values[np.clip(t.starts + lo - 1, 0, total - 1)]
@@ -229,10 +242,16 @@ def new_numbers(percentiles) -> dict:
 
 
 def compare(got: dict, tags: dict, twice: int, want: dict, timers,
-            percentiles, prefix: str, numbers: dict, examples: list) -> None:
+            percentiles, prefix: str, numbers: dict, examples: list,
+            widest: dict | None = None) -> None:
     """Hold one interval's rows to the reference (`want` and `timers` as
     `expected` gives them); the worst of each number over the intervals
-    compared so far is kept in `numbers`.
+    compared so far is kept in `numbers`. `widest`, where one is given,
+    gets for each percentile the timer behind this interval's widest rank
+    error: what it emitted beside the exact value and the timer's largest
+    sample, and how many timers lie over half that error with what share
+    of the samples, so that a reading over its limit says at once whether
+    one timer or a block of them carries it.
 
     Counters, gauges and a timer's count, min and max are exact. A
     percentile is held in rank space (`rank_errors`): `_rank_max` is the
@@ -280,6 +299,18 @@ def compare(got: dict, tags: dict, twice: int, want: dict, timers,
         worst(f"{pname(q)}_rank_wmean",
               np.sum(err * timers.lens) / np.sum(timers.lens))
         worst(f"{pname(q)}_rank_max", err.max())
+        if widest is not None:
+            j = int(err.argmax())
+            near = err > 0.5 * err[j]
+            widest[pname(q)] = {
+                "err": float(err[j]), "timer": int(timers.ids[j]),
+                "n": int(timers.lens[j]), "got": float(g[j]),
+                "exact": float(w[j]),
+                "max": float(timers.values[timers.starts[j]
+                                           + timers.lens[j] - 1]),
+                "timers_near": int(near.sum()),
+                "their_sample_share": float(timers.lens[near].sum()
+                                            / timers.lens.sum())}
         worst(f"{pname(q)}_rel_max", np.nanmax(np.abs(g - w) / np.abs(w)))
     if set_errs:
         worst("set_err_mean", np.mean(set_errs))

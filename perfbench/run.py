@@ -51,6 +51,12 @@ def result_line(cell: dict, out: dict, device: dict, trace: bool) -> dict:
         device["window_s"] = reduced["window_s"]
         line["breakdown"] = {"device_ops": reduced["device_ops"],
                              "idle_gaps": reduced["idle_gaps"]}
+    # the timer behind each percentile's widest rank error, short, so that
+    # the end of the line says what a reading over its limit was made of
+    line["widest"] = {
+        name: [w["interval"], w["timer"], w["n"], w["got"], w["exact"],
+               w["max"], w["timers_near"], round(w["their_sample_share"], 5)]
+        for name, w in out["widest"].items()}
     line["compared"] = {name: {"value": value, "limit": limit}
                         for name, value, limit, _ok in out["compared"]}
     return line

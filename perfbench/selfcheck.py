@@ -110,6 +110,10 @@ def check_reference():
 def brute_rank_error(x, g, q):
     """x: one timer's sorted samples. See reference.rank_errors."""
     n = len(x)
+    tol = 4.0 * float(np.spacing(np.float32(abs(g))))
+    near = min(x, key=lambda v: (abs(v - g), -v))
+    if abs(near - g) <= tol:
+        g = near
     below = [i for i in range(n) if x[i] < g]
     equal = [i for i in range(n) if x[i] == g]
     if equal:
@@ -143,8 +147,13 @@ def check_ranks(timers, want, seed):
         # the reference's own answer, answers off by a little and by a lot,
         # and answers that are samples of the timer
         picks = timers.values[timers.starts + rng.integers(0, timers.lens)]
+        # ... a sample one float32 unit up or two down (it is that sample)
+        p32 = picks.astype(np.float32)
+        up = np.nextafter(p32, np.float32(np.inf)).astype(np.float64)
+        down = np.nextafter(np.nextafter(p32, np.float32(-np.inf)),
+                            np.float32(-np.inf)).astype(np.float64)
         for got in (exact, exact * 1.003, exact + rng.normal(0, 8, len(rows)),
-                    picks, np.full(len(rows), np.nan)):
+                    picks, up, down, np.full(len(rows), np.nan)):
             errs = reference.rank_errors(timers, got, q)
             for j, (s0, n) in enumerate(zip(timers.starts, timers.lens)):
                 x = timers.values[s0:s0 + n].tolist()
@@ -152,6 +161,13 @@ def check_ranks(timers, want, seed):
                 assert abs(errs[j] - brute_rank_error(x, g, q)) < 1e-12, (
                     seed, q, j, got[j], errs[j], brute_rank_error(x, g, q))
         assert reference.rank_errors(timers, exact, q).max() < 1e-6
+        on = reference.rank_errors(timers, picks, q)
+        assert np.array_equal(reference.rank_errors(timers, up, q), on)
+        largest = timers.values[timers.starts + timers.lens - 1]
+        assert reference.rank_errors(
+            timers, np.nextafter(largest.astype(np.float32),
+                                 np.float32(np.inf)).astype(np.float64),
+            0.99).max() < 1 - 0.99
 
 
 def check_trace_reduce():

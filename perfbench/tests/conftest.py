@@ -18,7 +18,6 @@ BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
 sys.path.insert(1, os.path.dirname(BENCH))
 
-import numpy as np          # noqa: E402
 import pytest               # noqa: E402
 
 import harness              # noqa: E402
@@ -39,26 +38,6 @@ SMALL = {"prefix": "pb", "lines_per_datagram": 30, "kinds": {
 SMALL_LIMITS = {"set_err_mean": 8e-4}
 
 
-def misalign(server):
-    """The CPU backend aliases 64-byte-aligned host buffers (zero copy) and
-    the program reuses its two packed buffers while steps are in flight,
-    which corrupts batches on the CPU only. Steered here, in the test: give
-    the aggregator packed buffers that cannot be aliased."""
-    from veneur_tpu.aggregation.step import packed_layout
-    agg = server.aggregator
-    layout, words = packed_layout(agg._pk_sizes)
-    bufs = []
-    for _ in range(2):
-        raw = np.zeros(words + 32, np.int32)
-        skip = next(k for k in range(1, 17)
-                    if (raw.ctypes.data + 4 * k) % 64)
-        flat = raw[skip:skip + words]
-        agg._init_packed_sentinels(flat, layout, agg.spec)
-        bufs.append(flat)
-    agg._pk_bufs = bufs
-    return server
-
-
 def _small(name, tmp_path, monkeypatch):
     cell = harness.load_cell(name)
     path = tmp_path / "small.json"
@@ -69,10 +48,6 @@ def _small(name, tmp_path, monkeypatch):
         cell["config_file"],
         limits=dict(cell["config_file"]["limits"], **SMALL_LIMITS))
     monkeypatch.setattr(harness, "INTERVAL_S", 4.0)
-    build = harness.build_server
-    monkeypatch.setattr(
-        harness, "build_server",
-        lambda *a, **k: misalign(build(*a, **k)))
     return cell
 
 
@@ -83,8 +58,8 @@ def _cells():
 
 @pytest.fixture()
 def small_cell(tmp_path, monkeypatch):
-    """The first cell of BENCHMARK.json with the small pool in its place,
-    4 s intervals, and the CPU's buffer fix."""
+    """The first cell of BENCHMARK.json with the small pool in its place
+    and 4 s intervals."""
     return _small(_cells()[0]["name"], tmp_path, monkeypatch)
 
 

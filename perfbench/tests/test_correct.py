@@ -62,18 +62,69 @@ def test_altered_answer_is_not_correct(small_cell, monkeypatch, row, factor,
 
 def test_step_left_out_is_not_correct(small_cell, monkeypatch):
     """A step that returns its state unchanged: every fifth ingest step of
-    the timed path drops its batch."""
-    from veneur_tpu.aggregation import step
-    real, calls = step.ingest_step_packed, [0]
+    the timed path drops its batch. The step runs (it returns the pair
+    the aggregator takes: state and the rows compacted) and the state it
+    was given is put back, from a copy, since the step donates it."""
+    import jax
+    import jax.numpy as jnp
 
-    def lossy(state, flat, **kw):
+    from veneur_tpu.server import native_aggregator
+    real, calls = native_aggregator.ingest_step_packed, [0]
+
+    def lossy(state, flat, *a, **kw):
         calls[0] += 1
-        if calls[0] % 5 == 0:
-            return state
-        return real(state, flat, **kw)
+        if calls[0] % 5:
+            return real(state, flat, *a, **kw)
+        kept = jax.tree_util.tree_map(jnp.copy, state)
+        _state, rows = real(state, flat, *a, **kw)
+        return kept, rows
 
-    monkeypatch.setattr(step, "ingest_step_packed", lossy)
+    monkeypatch.setattr(native_aggregator, "ingest_step_packed", lossy)
     out = conftest.run(small_cell, 8)
     assert calls[0] >= 5
     assert not out["correct"]
     assert numbers(out)["exact_mismatch"] + numbers(out)["rows_missing"] >= 1
+
+
+def test_one_float32_unit_over_the_largest_sample_is_that_sample(
+        small_cell, monkeypatch):
+    """A digest's mean of tied samples rounds, so a percentile that is the
+    timer's largest sample can leave the program a float32 unit or two above it
+    (on the chip: an interval of over 64 pool cycles). That is no rank
+    error of 1 - q: the run stays correct. Eight units above, it is one."""
+    import numpy as np
+    make = conftest.harness.make_sink
+    prefix = small_cell["traffic_file"]["prefix"]
+    units, moved = [1], [0]
+
+    def nudged():
+        sink = make()
+        flush = sink.flush_frame
+
+        def flush_frame(frame):
+            at = {n: (seg, i) for seg in frame.segments
+                  for i, n in enumerate(seg.names)
+                  if n.startswith(prefix + ".t.")}
+            for name, (seg, i) in at.items():
+                if not name.endswith("99percentile"):
+                    continue
+                top, j = at[name[:-len("99percentile")] + "max"]
+                if seg.values[i] == top.values[j]:
+                    v = np.float32(seg.values[i])
+                    for _ in range(units[0]):
+                        v = np.nextafter(v, np.float32(np.inf))
+                    seg.values[i] = v
+                    moved[0] += 1
+            flush(frame)
+        sink.flush_frame = flush_frame
+        return sink
+
+    monkeypatch.setattr(conftest.harness, "make_sink", nudged)
+    out = conftest.run(small_cell, 9)
+    assert moved[0] > 100
+    assert out["correct"], out["compared"]
+    units[0], moved[0] = 8, 0
+    out = conftest.run(small_cell, 9)
+    assert moved[0] > 100
+    over = {name for name, value, limit, ok in out["compared"] if not ok}
+    assert over == {"p99_rank_wmean", "p99_rank_max"}, out["compared"]
