@@ -188,14 +188,28 @@ def test_batch_full_backpressure():
     assert nc2 == 10
 
 
-def test_reset_clears_keys():
+def test_reset_keeps_keys_and_empties_the_live_list():
+    """A flush boundary keeps a key and its slot: the second interval
+    leaves no new-key record, and the key is live in it only once it has
+    arrived again."""
     eng = mk()
-    eng.feed(b"a:1|c")
-    eng.drain_new_keys()
+    eng.feed(b"a:1|c\nb:1|c")
+    first = eng.drain_new_keys()
+    assert [(k, n) for k, _s, _sc, n, _t, _i in first] == [
+        ("counter", "a"), ("counter", "b")]
+    slot_of = {n: s for _k, s, _sc, n, _t, _i in first}
+    assert eng.live_keys("counter")[0].tolist() == [slot_of["a"],
+                                                    slot_of["b"]]
     eng.reset()
-    eng.feed(b"a:1|c")
-    keys = eng.drain_new_keys()
-    assert len(keys) == 1  # re-allocated after reset
+    assert eng.live_keys("counter")[0].tolist() == []
+    assert eng.table_stats()["counter"][0] == 0
+    eng.feed(b"b:1|c")
+    assert eng.drain_new_keys() == []
+    assert eng.live_keys("counter")[0].tolist() == [slot_of["b"]]
+    assert eng.table_stats()["counter"][0] == 1
+    eng.reset()
+    assert eng.key_counters() == {"keys_live": 3, "keys_new": 2,
+                                  "keys_evicted": 0}
 
 
 def test_native_udp_reader_group_lossless_and_counted():

@@ -141,7 +141,6 @@ def test_native_import_imported_only_marking():
     nat.feed(b"wire.c:1|c")        # wire-created slot first
     nat.import_pb_bytes(_mk_list(rng).SerializeToString())
     table = nat.table
-    table._drain()
     assert all(m.imported_only for _s, m in table.get_meta("histogram"))
     by_name = {m.name: m for _s, m in table.get_meta("counter")}
     assert not by_name["wire.c"].imported_only

@@ -1,0 +1,357 @@
+"""The native key table across flush intervals.
+
+The C++ engine's key table outlives the interval (dogstatsd.cpp
+KindTable): a key keeps its slot, a swap hands the interval's live keys
+over as arrays, and capacity is counted in the interval's own keys. What
+an interval emits must stay what the flush-scoped Python KeyTable
+(aggregation/host.py, behind the plain Aggregator) emits from the same
+datagrams: interval by interval, row for row and in order. Every case
+runs over the single-ring `vr_*` engine behind a real loopback socket and
+over the `vrm_*` engine at two rings, at one and at four table shards.
+"""
+
+import socket
+import time
+
+import numpy as np
+import pytest
+
+from veneur_tpu import native
+from veneur_tpu.aggregation.host import BatchSpec
+from veneur_tpu.aggregation.state import TableSpec
+from veneur_tpu.samplers import parser
+
+pytestmark = pytest.mark.skipif(not native.available(),
+                                reason="native engine unavailable")
+
+SPEC = TableSpec(counter_capacity=16, gauge_capacity=8, status_capacity=8,
+                 set_capacity=8, histo_capacity=8)
+BSPEC = BatchSpec(counter=64, gauge=32, status=8, set=32, histo=64,
+                  histo_stat=16)
+KINDS = ("counter", "gauge", "set", "histogram")
+VALUE_OF = {"counter": "counter", "gauge": "gauge", "set": "set_estimate",
+            "histogram": "histo_count"}
+
+ENGINES = [pytest.param(("vr", 1), id="vr-1shard"),
+           pytest.param(("vr", 4), id="vr-4shards"),
+           pytest.param(("vrm", 1), id="vrm2-1shard"),
+           pytest.param(("vrm", 4), id="vrm2-4shards")]
+
+
+class Pair:
+    """A NativeAggregator behind one engine and the plain Aggregator, fed
+    the same datagrams one at a time (each parsed before the next is
+    sent, so first-arrival order is the order sent on every engine)."""
+
+    def __init__(self, engine: str, n_shards: int, spec=SPEC):
+        from veneur_tpu.server.aggregator import Aggregator
+        from veneur_tpu.server.native_aggregator import NativeAggregator
+        self.engine, self.n_shards = engine, n_shards
+        self.py = Aggregator(spec, BSPEC, n_shards=n_shards)
+        self.nat = NativeAggregator(spec, BSPEC, n_shards=n_shards)
+        self.lines = 0
+        self.sent = 0
+        self.rx = self.tx = None
+        if engine == "vr":
+            self.rx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+            self.rx.bind(("127.0.0.1", 0))
+            self.tx = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+            self.tx.connect(self.rx.getsockname())
+            self.nat.readers_start([self.rx.fileno()])
+        else:
+            self.nat.rings_start(2)
+
+    def close(self):
+        self.nat.readers_stop()
+        for s in (self.rx, self.tx):
+            if s is not None:
+                s.close()
+
+    def rebuild(self, spec, n_shards):
+        """What reshard/coordinator.py and tables/growth.py do after the
+        swap that applied a staged change: new backends around the SAME
+        engine."""
+        from veneur_tpu.server.aggregator import Aggregator
+        from veneur_tpu.server.native_aggregator import NativeAggregator
+        self.n_shards = n_shards
+        self.py = Aggregator(spec, BSPEC, n_shards=n_shards)
+        self.nat = NativeAggregator(spec, BSPEC, n_shards=n_shards,
+                                    engine=self.nat.eng)
+
+    def send(self, *lines: bytes):
+        dgram = b"\n".join(lines)
+        if self.engine == "vr":
+            self.tx.send(dgram)
+        else:
+            assert self.nat.eng.rings_inject(self.sent % 2, dgram) == \
+                native.INJECT_OK
+        self.sent += 1
+        self.lines += len(lines)
+        deadline = time.time() + 60
+        while True:
+            self.nat.pump(5)
+            st = self.nat.eng.stats()
+            if st["processed"] + st["dropped"] >= self.lines:
+                break
+            assert time.time() < deadline, (st, self.lines)
+        for ln in lines:
+            self.py.process_metric(parser.parse_metric(ln))
+
+    def import_list(self, ml):
+        from veneur_tpu.forward.convert import import_into
+        total, errors = self.nat.import_pb_bytes(ml.SerializeToString())
+        assert (total, errors) == (len(ml.metrics), 0)
+        self.lines += total     # the engine counts an import as processed
+        for m in ml.metrics:
+            import_into(self.py, m)
+
+    def flush(self):
+        """Both backends' interval as rows, and the native interval's
+        detached keys."""
+        out = []
+        for agg in (self.py, self.nat):
+            res, table = agg.flush([0.5])
+            out.append((rows_of(res, table), table))
+        (py_rows, _), (nat_rows, nat_table) = out
+        return py_rows, nat_rows, nat_table
+
+
+def rows_of(res, table):
+    rows = []
+    for kind in KINDS:
+        vals = np.asarray(res[VALUE_OF[kind]], np.float64)
+        for i, (_slot, m) in enumerate(table.get_meta(kind)):
+            rows.append((m.kind, m.name, m.tags, m.scope, m.imported_only,
+                         round(float(vals[i]), 3)))
+    return rows
+
+
+@pytest.fixture(params=ENGINES)
+def pair(request):
+    engine, n_shards = request.param
+    p = Pair(engine, n_shards)
+    yield p
+    p.close()
+
+
+def fresh_counters(pair, n, avoid=(), prefix="k"):
+    """n counter names per table shard (n * n_shards in all) that are not
+    in `avoid`, dealt round-robin over the shards so a prefix fills every
+    shard evenly."""
+    by_shard = [[] for _ in range(pair.n_shards)]
+    i = 0
+    while min(len(b) for b in by_shard) < n:
+        name = f"{prefix}{i}"
+        i += 1
+        if name in avoid:
+            continue
+        m = parser.parse_metric(f"{name}:1|c".encode())
+        b = by_shard[m.digest % pair.n_shards]
+        if len(b) < n:
+            b.append(name)
+    return [b[j] for j in range(n) for b in by_shard]
+
+
+def key_counters(pair):
+    st = pair.nat.ring_stats()
+    return st["keys_live"], st["keys_new"], st["keys_evicted"]
+
+
+MIXED = (b"a.count:2|c|#env:prod,az:b", b"a.gauge:7.5|g",
+         b"a.set:member|s|#env:prod", b"a.timer:12|ms|#svc:api",
+         b"a.histo:3|h", b"a.count:3|c|#az:b,env:prod")
+
+
+def test_a_key_in_every_interval(pair):
+    for k in range(3):
+        pair.send(*MIXED[:3])
+        pair.send(*MIXED[3:])
+        py, nat, _ = pair.flush()
+        assert nat == py
+        assert len(nat) == 5
+        # paid for once: the later swaps find no new-key record
+        assert key_counters(pair) == (5 * (k + 1), 5, 0)
+    assert pair.nat.ring_stats()["keys_reused"] == 10
+    assert pair.nat.dropped_capacity == pair.py.dropped_capacity == 0
+
+
+def test_a_key_that_stays_away_is_not_emitted_and_returns(pair):
+    pair.send(b"stay:1|c", b"away:1|c|#veneurlocalonly,t:1", b"away.g:4|g")
+    py1, nat1, table1 = pair.flush()
+    slot_of = {m.name: s for s, m in table1.get_meta("counter")}
+    pair.send(b"stay:5|c")
+    py2, nat2, _ = pair.flush()
+    assert nat2 == py2
+    assert [r[1] for r in nat2] == ["stay"]
+    # back on its old terms: the same rows as in its first interval, from
+    # the slot it kept, with nothing allocated
+    pair.send(b"away.g:4|g", b"away:1|c|#t:1,veneurlocalonly", b"stay:1|c")
+    py3, nat3, table3 = pair.flush()
+    assert nat3 == py3
+    assert sorted(nat3) == sorted(nat1) and nat1 == py1
+    assert [m.name for _s, m in table3.get_meta("counter")] == \
+        ["away", "stay"]
+    assert {m.name: s for s, m in table3.get_meta("counter")} == slot_of
+    assert key_counters(pair) == (3 + 1 + 3, 3, 0)
+
+
+def test_capacity_is_counted_in_the_intervals_own_keys(pair):
+    per_shard = SPEC.counter_capacity // pair.n_shards
+    first = fresh_counters(pair, per_shard)
+    pair.send(*[f"{n}:1|c".encode() for n in first])
+    py, nat, _ = pair.flush()
+    assert nat == py and len(nat) == SPEC.counter_capacity
+    # the table is full of last interval's keys: as many new ones, and
+    # none is dropped
+    second = fresh_counters(pair, per_shard, avoid=set(first), prefix="n")
+    half = len(second) // 2
+    pair.send(*[f"{n}:2|c".encode() for n in second[:half]])
+    pair.send(*[f"{n}:2|c".encode() for n in second[half:]])
+    assert pair.nat.dropped_capacity == pair.py.dropped_capacity == 0
+    assert pair.nat.eng.table_stats()["counter"][0] == SPEC.counter_capacity
+    # one key more drops exactly one, on both
+    pair.send(b"one.more:1|c")
+    assert pair.nat.dropped_capacity == pair.py.dropped_capacity == 1
+    # and a key of the interval is still found, not dropped
+    pair.send(f"{second[0]}:2|c".encode())
+    assert pair.nat.dropped_capacity == 1
+    py, nat, _ = pair.flush()
+    assert nat == py
+    assert [r[1] for r in nat] == second
+    live, new, evicted = key_counters(pair)
+    assert (live, new) == (2 * SPEC.counter_capacity,
+                           2 * SPEC.counter_capacity)
+    assert evicted == SPEC.counter_capacity
+
+
+def test_a_key_that_returns_with_another_scope(pair):
+    pair.send(b"sc:1|c|#veneurlocalonly,a:1", b"sc.t:1|ms|#veneurglobalonly")
+    py1, nat1, table1 = pair.flush()
+    assert nat1 == py1
+    pair.send(b"sc:1|c|#a:1,veneurglobalonly", b"sc.t:1|ms")
+    py2, nat2, table2 = pair.flush()
+    assert nat2 == py2
+    assert [(r[1], r[3]) for r in nat1] == [("sc", 1), ("sc.t", 2)]
+    assert [(r[1], r[3]) for r in nat2] == [("sc", 2), ("sc.t", 0)]
+    # the first interval's view still says what it said
+    assert [m.scope for _s, m in table1.get_meta("counter")] == [1]
+    assert [m.scope for _s, m in table1.get_meta("timer")] == [2]
+    assert table1.get_meta("counter")[0][1] is not \
+        table2.get_meta("counter")[0][1]
+    assert key_counters(pair) == (4, 2, 0)
+
+
+def _timer_list(name="imp.t"):
+    from veneur_tpu.proto import forwardrpc_pb2 as fpb
+    from veneur_tpu.proto import metricpb_pb2 as mpb
+    ml = fpb.MetricList()
+    m = ml.metrics.add()
+    m.name = name
+    m.tags.append("svc:api")
+    m.type = mpb.Timer
+    td = m.histogram.t_digest
+    for mean, weight in ((1.0, 1.0), (3.5, 2.0), (8.0, 1.0)):
+        c = td.main_centroids.add()
+        c.mean, c.weight = mean, weight
+    td.min, td.max = 1.0, 8.0
+    td.reciprocalSum = 1.0 + 2.0 / 3.5 + 1.0 / 8.0
+    return ml
+
+
+def test_a_histogram_first_imported_then_sampled_directly(pair):
+    pair.import_list(_timer_list())
+    py1, nat1, table1 = pair.flush()
+    assert nat1 == py1
+    assert [(r[1], r[4]) for r in nat1] == [("imp.t", True)]
+    pair.send(b"imp.t:5|ms|#svc:api")
+    py2, nat2, _ = pair.flush()
+    assert nat2 == py2
+    assert [(r[1], r[4], r[5]) for r in nat2] == [("imp.t", False, 1.0)]
+    # imported alone again, and the first view is as it was
+    pair.import_list(_timer_list())
+    py3, nat3, _ = pair.flush()
+    assert nat3 == py3 == nat1
+    assert table1.get_meta("timer")[0][1].imported_only is True
+    assert key_counters(pair) == (3, 1, 0)
+
+
+def test_a_direct_sample_from_python_ends_imported_only(pair):
+    """process_metric on an import-created slot (a span-extracted timer)
+    clears imported_only for that interval only, on both tables."""
+    ln = b"imp.t:5|ms|#svc:api"
+    for agg in (pair.py, pair.nat):
+        from veneur_tpu.forward.convert import import_into
+        for m in _timer_list().metrics:
+            import_into(agg, m)
+        agg.process_metric(parser.parse_metric(ln))
+    py1, nat1, _ = pair.flush()
+    assert nat1 == py1
+    assert [(r[1], r[4]) for r in nat1] == [("imp.t", False)]
+    pair.import_list(_timer_list())
+    py2, nat2, _ = pair.flush()
+    assert nat2 == py2
+    assert [(r[1], r[4]) for r in nat2] == [("imp.t", True)]
+
+
+def test_a_detached_view_outlives_the_reuse_of_its_slots(pair):
+    per_shard = SPEC.counter_capacity // pair.n_shards
+    first = fresh_counters(pair, per_shard)
+    pair.send(*[f"{n}:1|c|#i:1".encode() for n in first])
+    state1, view1 = pair.nat.swap()
+    second = fresh_counters(pair, per_shard, avoid=set(first), prefix="n")
+    pair.send(*[f"{n}:2|c|#i:2".encode() for n in second])
+    live_view = pair.nat.table.get_meta("counter")   # the interval so far
+    state2, view2 = pair.nat.swap()
+    assert pair.nat.ring_stats()["keys_evicted"] == SPEC.counter_capacity
+    # every slot of the first view now belongs to a key of the second
+    slots1 = sorted(s for s, _m in view1.get_meta("counter"))
+    assert slots1 == sorted(s for s, _m in view2.get_meta("counter"))
+    assert [(m.name, m.tags) for _s, m in view1.get_meta("counter")] == \
+        [(n, ("i:1",)) for n in first]
+    assert [(m.name, m.tags) for _s, m in view2.get_meta("counter")] == \
+        [(n, ("i:2",)) for n in second]
+    assert [(s, m.name) for s, m in live_view] == \
+        [(s, m.name) for s, m in view2.get_meta("counter")]
+    for view, names in ((view1, first), (view2, second)):
+        slot, m = view.get_meta("counter")[3]
+        assert view.meta_for_slot("counter", slot) is m
+        assert m.name == names[3]
+    # and the values sit at the slots the views name
+    for state, view, v in ((state1, view1, 1.0), (state2, view2, 2.0)):
+        acc = (np.asarray(state.counter_acc) + np.asarray(state.counter_hi)
+               + np.asarray(state.counter_lo)).reshape(-1)
+        assert [float(acc[s]) for s, _m in view.get_meta("counter")] == \
+            [v] * SPEC.counter_capacity
+
+
+def test_a_staged_shard_map_and_capacity_change(pair):
+    """The one place that needs the tables empty: the reset that applies
+    a staged map or capacity clears them, every key is allocated again
+    under the new layout, and persistence goes on from there."""
+    from veneur_tpu.reshard.quiesce import shard_map_swap
+    import dataclasses
+    names = fresh_counters(pair, 3)
+    lines = [f"{n}:1|c|#t:x".encode() for n in names] + [b"g.one:2|g"]
+    pair.send(*lines)
+    new_shards = 4 if pair.n_shards == 1 else 2
+    new_spec = dataclasses.replace(SPEC, counter_capacity=32)
+    pair.nat.eng.capacity_set(32, SPEC.gauge_capacity, SPEC.set_capacity,
+                              SPEC.histo_capacity)
+    state, table = shard_map_swap(pair.nat, new_shards)
+    res = pair.nat.compute_flush(state, table, [0.5])[0]
+    py_res, py_table = pair.py.flush([0.5])
+    assert rows_of(res, table) == rows_of(py_res, py_table)
+    pair.rebuild(new_spec, new_shards)
+    assert pair.nat.eng.table_stats()["counter"] == (0, 0, 32)
+    for k in range(2):
+        pair.send(*reversed(lines))
+        py, nat, nat_table = pair.flush()
+        assert nat == py
+        assert [r[1] for r in nat] == list(reversed(names)) + ["g.one"]
+        per = 32 // new_shards
+        for slot, m in nat_table.get_meta("counter"):
+            d = parser.parse_metric(f"{m.name}:1|c|#t:x".encode()).digest
+            assert slot // per == d % new_shards
+    n = len(lines)
+    # allocated twice (before the change and after), reused once
+    assert key_counters(pair) == (3 * n, 2 * n, 0)

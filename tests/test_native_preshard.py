@@ -92,7 +92,7 @@ def test_preshard_state_byte_identical_to_split_shards():
     for agg in aggs:
         state, table = agg.swap()
         states.append(state)
-        assert table.by_slot["counter"]   # corpus actually landed
+        assert table.get_meta("counter")   # corpus actually landed
     for a, b in zip(_state_leaves(states[0]), _state_leaves(states[1])):
         assert a.dtype == b.dtype and a.shape == b.shape
         assert np.array_equal(a, b)
@@ -181,21 +181,21 @@ def _per_key(state, table):
     acc, hi, lo = (np.asarray(state.counter_acc),
                    np.asarray(state.counter_hi),
                    np.asarray(state.counter_lo))
-    for slot, m in table.by_slot["counter"].items():
+    for slot, m in table.get_meta("counter"):
         out[("counter", m.name, m.joined_tags)] = float(
             acc[slot] + hi[slot] + lo[slot])
     g = np.asarray(state.gauge)
-    for slot, m in table.by_slot["gauge"].items():
+    for slot, m in table.get_meta("gauge"):
         out[("gauge", m.name, m.joined_tags)] = float(g[slot])
     hll = np.asarray(state.hll)
-    for slot, m in table.by_slot["set"].items():
+    for slot, m in table.get_meta("set"):
         out[("set", m.name, m.joined_tags)] = hll[slot].tobytes()
     cnt = (np.asarray(state.h_count_acc) + np.asarray(state.h_count_hi)
            + np.asarray(state.h_count_lo))
     sm = (np.asarray(state.h_sum_acc) + np.asarray(state.h_sum_hi)
           + np.asarray(state.h_sum_lo))
     mn, mx = np.asarray(state.h_min), np.asarray(state.h_max)
-    for slot, m in table.by_slot["histo"].items():
+    for slot, m in table.get_meta("histogram"):
         out[("histo", m.name, m.joined_tags)] = (
             float(cnt[slot]), float(sm[slot]),
             float(mn[slot]), float(mx[slot]))

@@ -37,11 +37,8 @@ def pm(agg, kind, name, value, scope=SCOPE_GLOBAL, tags=(), rate=1.0):
 
 def counter_meta(table):
     """(slot, SlotMeta) pairs of a detached table's counter kind —
-    Python KeyTable or finalized NativeKeyTable alike."""
-    tables = getattr(table, "tables", None)
-    if tables is not None:
-        return list(tables["counter"].meta)
-    return list(table.by_slot["counter"].items())
+    Python KeyTable or a native interval's keys alike."""
+    return list(table.get_meta("counter"))
 
 
 def counter_values(state, table):

@@ -170,6 +170,13 @@ class KeyTable:
     def meta_for_slot(self, kind: str, slot: int) -> Optional[SlotMeta]:
         return self.tables[self._table_name(kind)].by_slot.get(slot)
 
+    def sampled_directly(self, kind: str, slot: int) -> None:
+        """A histo slot took a directly-sampled value: it is no longer
+        imported_only in this interval (SlotMeta.imported_only)."""
+        mt = self.meta_for_slot(kind, slot)
+        if mt is not None and mt.imported_only:
+            mt.imported_only = False
+
     def dropped(self) -> int:
         return sum(t.dropped for t in self.tables.values())
 

@@ -97,6 +97,13 @@ def _build_and_load():
             ctypes.c_void_p, ctypes.c_int, ctypes.c_int, ctypes.c_char_p,
             ctypes.c_int, ctypes.c_char_p, ctypes.c_int, ctypes.c_uint32,
             ctypes.POINTER(ctypes.c_int)]
+        lib.vt_sampled_directly.argtypes = [ctypes.c_void_p, ctypes.c_int32]
+        lib.vt_live_keys.restype = ctypes.c_int
+        lib.vt_live_keys.argtypes = [
+            ctypes.c_void_p, ctypes.c_int, ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int]
+        lib.vt_key_counters.argtypes = [ctypes.c_void_p,
+                                        ctypes.POINTER(ctypes.c_uint64)]
         lib.vt_reset.argtypes = [ctypes.c_void_p]
         lib.vt_shard_map_set.argtypes = [ctypes.c_void_p, ctypes.c_uint32]
         lib.vt_capacity_set.argtypes = [ctypes.c_void_p] + \
@@ -228,6 +235,10 @@ def available() -> bool:
 KIND_NAMES = {0: "counter", 1: "gauge", 2: "histogram", 3: "set",
               4: "timer"}
 KIND_IDS = {v: k for k, v in KIND_NAMES.items()}
+# the engine's four key tables, in vt_live_keys / vt_table_stats order
+LIVE_TABLES = ("counter", "gauge", "set", "histo")
+# beside a scope in one byte: the key's slot came by the import path
+IMPORTED_BIT = 0x80
 
 
 def hash64_batch(members: List[bytes]) -> "np.ndarray":
@@ -379,29 +390,66 @@ class NativeIngest:
         return _lib.vt_pending(self._h)
 
     def slot_for(self, kind: str, name: str, joined_tags: str, scope: int,
-                 digest: int):
+                 digest: int, imported: bool = False):
         """(slot, was_new) for a Python-side caller sharing the native slot
-        space; slot is None at capacity."""
+        space; slot is None at capacity. `imported` marks a caller on the
+        import path: it is what the interval's first arrival of the key
+        records beside its scope (live_keys)."""
         was_new = ctypes.c_int(0)
         name_b = name.encode("utf-8", "surrogateescape")
         tags_b = joined_tags.encode("utf-8", "surrogateescape")
         slot = _lib.vt_slot_for(
-            self._h, KIND_IDS[kind], scope, name_b, len(name_b),
-            tags_b, len(tags_b), digest & 0xFFFFFFFF,
+            self._h, KIND_IDS[kind], scope | (IMPORTED_BIT if imported else 0),
+            name_b, len(name_b), tags_b, len(tags_b), digest & 0xFFFFFFFF,
             ctypes.byref(was_new))
         return (None if slot < 0 else slot), bool(was_new.value)
 
+    def sampled_directly(self, slot: int) -> None:
+        """A histo slot took a directly-sampled value: it stops being
+        imported-only in this interval."""
+        _lib.vt_sampled_directly(self._h, slot)
+
+    def live_keys(self, table: str) -> tuple:
+        """(slots int32[n], first uint8[n]) of one table (LIVE_TABLES) in
+        this interval so far: the slots its keys were touched at, in
+        first-arrival order, and a row each the scope of the interval's
+        first arrival, IMPORTED_BIT set where that came by the import
+        path. Keys outlive the interval (dogstatsd.cpp KindTable); this
+        list is what an interval owns."""
+        t = LIVE_TABLES.index(table)
+        cap = 0
+        while True:
+            slots = np.empty(cap, np.int32)
+            first = np.empty(cap, np.uint8)
+            n = _lib.vt_live_keys(
+                self._h, t,
+                slots.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+                first.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), cap)
+            if n >= 0:
+                return slots[:n], first[:n]
+            # the count, then the copy; ring workers may add between the two
+            cap = -n + 1024
+
+    def key_counters(self) -> dict:
+        """What the intervals closed so far (reset()) held, over the four
+        tables: keys_live, of them keys_new (allocated in their
+        interval), and the keys_evicted for them."""
+        s = (ctypes.c_uint64 * 3)()
+        _lib.vt_key_counters(self._h, s)
+        return {"keys_live": s[0], "keys_new": s[1], "keys_evicted": s[2]}
+
     def drain_new_keys(self) -> List[tuple]:
         """[(kind, slot, scope, name, joined_tags, imported)] allocated
-        since the last drain. The scope byte's bit 7 marks slots first
-        created by the native import path (imported_only labeling)."""
+        since the last drain: a key's first allocation, or its next after
+        an eviction. The scope byte's bit 7 marks slots first created by
+        the native import path (imported_only labeling)."""
         n = _lib.vt_new_keys(self._h, self._keybuf,
                              len(self._keybuf))
         if n < 0:
             self._keybuf = ctypes.create_string_buffer(-n * 2)
             n = _lib.vt_new_keys(self._h, self._keybuf, len(self._keybuf))
         out = []
-        raw = self._keybuf.raw[:n]
+        raw = ctypes.string_at(self._keybuf, n)
         off = 0
         while off < n:
             kind = raw[off]
@@ -486,9 +534,12 @@ class NativeIngest:
         return out
 
     def reset(self):
+        """Flush boundary: the next interval's live lists start empty; the
+        keys and their slots stay (a staged shard map or capacity empties
+        the tables instead)."""
         r = getattr(self, "_rings", None)
         if r:
-            # clears the master tables AND every ring's key-replica cache;
+            # the master tables AND every ring's key-replica cache;
             # callers hold the rings_pause() quiesce across this
             _lib.vrm_reset(r)
         else:
@@ -521,8 +572,8 @@ class NativeIngest:
 
     def table_stats(self) -> dict:
         """Per-kind key-table occupancy for the growth planner:
-        {kind: (allocated, dropped, capacity)} over the engine's four
-        tables. Locks the key tables shared — safe alongside ring
+        {kind: (keys live in this interval, dropped, capacity)} over the
+        engine's four tables. Locks the key tables shared — safe alongside ring
         parsing."""
         s = (ctypes.c_uint64 * 12)()
         r = getattr(self, "_rings", None)
@@ -530,9 +581,8 @@ class NativeIngest:
             _lib.vrm_table_stats(r, s)
         else:
             _lib.vt_table_stats(self._h, s)
-        kinds = ("counter", "gauge", "set", "histo")
         return {k: (int(s[i * 3]), int(s[i * 3 + 1]), int(s[i * 3 + 2]))
-                for i, k in enumerate(kinds)}
+                for i, k in enumerate(LIVE_TABLES)}
 
     def stats(self) -> dict:
         s = (ctypes.c_uint64 * 3)()

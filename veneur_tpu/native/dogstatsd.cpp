@@ -12,8 +12,9 @@
 //   - 32-bit FNV-1a digest over name+type+joined-tags = shard key
 //   - set members hashed MetroHash64 seed 1337 (utils/hashing.py
 //     hll_reg_rho; the reference sketch's member hash)
-//   - slot = shard*per_shard + next_free[shard], shard = digest % n_shards
-//     (aggregation/host.py KeyTable.slot_for / _KindTable.alloc)
+//   - slot = shard*per_shard + local, shard = digest % n_shards
+//     (aggregation/host.py KeyTable.slot_for / _KindTable.alloc); a key
+//     keeps its slot across flush intervals (KindTable below)
 //
 // Events (_e{) and service checks (_sc) are rare; they are handed back to
 // Python verbatim (vt_next_special).
@@ -298,28 +299,109 @@ inline bool tenant_allow(TenantTable& tt, TenantEntry& e,
   return false;
 }
 
+// One kind's key table. It outlives the flush interval: a key keeps its
+// slot from interval to interval, and what an interval owns is the LIVE
+// LIST, the slots touched in it in first-arrival order. Capacity is
+// counted in this interval's keys, as when the table was cleared at every
+// flush: a slot whose key was not touched in this interval is free for
+// the taking (allocate() evicts such keys when a shard has handed out its
+// whole range), so a key is dropped only when every slot of its shard
+// was touched in this interval.
 struct KindTable {
   uint32_t capacity = 0;
   uint32_t n_shards = 1;
   uint32_t per_shard = 0;
   std::unordered_map<std::string, int32_t> by_key;
-  std::vector<uint32_t> next_free;
+  std::vector<uint32_t> next_free;      // per shard: local slots handed out
+  std::vector<std::vector<uint32_t>> evicted_free;  // per shard: reusable
+  std::vector<uint32_t> live_in_shard;  // per shard: touched this interval
+  // per slot, grown as slots are handed out (a ring parser's own tables
+  // hand out none): the interval it was last touched in, the scope (bit 7:
+  // imported) of that interval's first arrival, and its key in by_key
+  std::vector<uint32_t> seen;
+  std::vector<uint8_t> first;
+  std::vector<const std::string*> key_of;
+  std::vector<int32_t> live;  // slots touched this interval, arrival order
+  uint32_t interval = 1;
+  uint32_t new_keys = 0, evicted = 0;  // this interval's
   uint64_t dropped = 0;
 
+  // (re)size to an EMPTY table: the one place a key's slot may change
   void init(uint32_t cap, uint32_t shards) {
     capacity = cap;
     n_shards = shards;
     per_shard = cap / shards;
-    next_free.assign(shards, 0);
-  }
-  void reset() {
     by_key.clear();
-    next_free.assign(n_shards, 0);
+    next_free.assign(shards, 0);
+    evicted_free.assign(shards, {});
+    live_in_shard.assign(shards, 0);
+    seen.clear();
+    first.clear();
+    key_of.clear();
+    live.clear();
+  }
+
+  // flush boundary: the keys stay, the interval's live list starts empty
+  void next_interval() {
+    interval++;
+    live.clear();
+    live_in_shard.assign(n_shards, 0);
+    new_keys = evicted = 0;
+  }
+
+  bool touched(int32_t slot) const { return seen[slot] == interval; }
+
+  void touch(int32_t slot, uint8_t scope_imported) {
+    seen[slot] = interval;
+    first[slot] = scope_imported;
+    live.push_back(slot);
+    live_in_shard[(uint32_t)slot / per_shard]++;
+  }
+
+  // A slot of digest's shard for a key by_key does not hold, or -1 when
+  // every slot of the shard was touched in this interval.
+  int32_t allocate(uint32_t digest) {
+    uint32_t shard = digest % n_shards;
+    if (live_in_shard[shard] >= per_shard) {
+      dropped++;
+      return -1;
+    }
+    uint32_t base = shard * per_shard, local;
+    if (next_free[shard] < per_shard) {
+      local = next_free[shard]++;
+    } else {
+      auto& fr = evicted_free[shard];
+      if (fr.empty()) {
+        // one sweep frees every slot of the shard that this interval has
+        // not touched (there is one: live_in_shard < per_shard)
+        for (uint32_t l = per_shard; l-- > 0;) {
+          uint32_t s = base + l;
+          if (seen[s] == interval || !key_of[s]) continue;
+          by_key.erase(by_key.find(*key_of[s]));
+          key_of[s] = nullptr;
+          fr.push_back(l);
+          evicted++;
+        }
+      }
+      local = fr.back();
+      fr.pop_back();
+    }
+    uint32_t slot = base + local;
+    if (slot >= seen.size()) {
+      size_t n = std::min<size_t>(capacity,
+                                  std::max<size_t>(slot + 1, seen.size() * 2));
+      seen.resize(n, 0);
+      first.resize(n, 0);
+      key_of.resize(n, nullptr);
+    }
+    new_keys++;
+    return (int32_t)slot;
   }
 };
 
-// serialized record of a newly-allocated slot, drained by Python for
-// flush-time labeling (SlotMeta)
+// serialized record of a slot's allocation to a key (its first, or again
+// after it was evicted), drained by Python for flush-time labeling
+// (SlotMeta); a key seen in an earlier interval leaves none
 struct NewKey {
   uint8_t kind;
   int32_t slot;
@@ -349,8 +431,8 @@ struct Parser {
   // staged per-kind capacity change (live key-table growth,
   // veneur_tpu/tables/growth.py): counter/gauge/set/histo, 0 = nothing
   // staged. Same discipline as pending_shards — set under key_mu by
-  // vt_capacity_set, applied by vt_reset while the tables are empty, so
-  // no slot ever straddles two capacities and the per-shard slot
+  // vt_capacity_set, applied by vt_reset, which empties the tables for
+  // it, so no slot ever straddles two capacities and the per-shard slot
   // rebase (slot = shard * per_shard + local) changes only between
   // intervals.
   uint32_t pending_caps[4] = {0, 0, 0, 0};
@@ -359,14 +441,19 @@ struct Parser {
   // scratch but route every key-table/new-key/special access to the
   // master parser so all rings share ONE slot space. Steady-state lookups
   // are served from a ring-local replica cache with no lock at all; the
-  // shared table is touched only on cache miss (shared lock) and on
-  // first-allocation (unique lock, once per key per flush interval).
+  // shared table is touched only on cache miss (shared lock) and on a
+  // key's first arrival in the interval (unique lock, once per key per
+  // flush interval).
   Parser* master = nullptr;
   std::shared_mutex key_mu;                          // tables + new_keys
   std::mutex specials_mu;                            // specials deque
   std::unordered_map<std::string, int32_t> local_cache;
 
   Parser& rt() { return master ? *master : *this; }
+  // in vt_live_keys / vt_table_stats order
+  std::array<KindTable*, 4> tables() {
+    return {&counters, &gauges, &sets, &histos};
+  }
 
   // Multi-tenant identity (master only; rings route via rt()). The
   // cur_* fields are per-parser parse context: set before each vt_feed
@@ -392,6 +479,10 @@ struct Parser {
   uint32_t nc = 0, ng = 0, ns = 0, nh = 0;
 
   std::vector<NewKey> new_keys;
+  // how often the tables' persistence engages, added up at each vt_reset
+  // over the four tables: the keys the closed intervals held, how many of
+  // them were allocated in their interval, and the keys evicted for them
+  uint64_t keys_live = 0, keys_new = 0, keys_evicted = 0;
   std::deque<std::string> specials;  // _e{ / _sc lines for Python
 
   // import path (vi_import): per-histogram stats + alloc marking
@@ -444,8 +535,9 @@ struct Parser {
     keybuf.push_back('\x1f');
     keybuf.append(joined);
     if (master) {
-      // lock-free hot path: the ring-local replica (slots are stable
-      // within a flush interval; vrm_reset clears these under quiesce)
+      // lock-free hot path: the ring-local replica. vrm_reset clears
+      // these under quiesce, so a hit here is a slot this ring has
+      // already seen touched in this interval.
       auto cit = local_cache.find(keybuf);
       if (cit != local_cache.end()) return cit->second;
     }
@@ -453,32 +545,35 @@ struct Parser {
     {
       std::shared_lock<std::shared_mutex> lk(m.key_mu);
       auto it = t.by_key.find(keybuf);
-      if (it != t.by_key.end()) {
+      if (it != t.by_key.end() && t.touched(it->second)) {
         int32_t slot = it->second;
         lk.unlock();
         if (master) local_cache.emplace(keybuf, slot);
         return slot;
       }
     }
+    // the key's first arrival in this interval: once a key an interval
     std::unique_lock<std::shared_mutex> lk(m.key_mu);
+    int32_t slot;
     auto it = t.by_key.find(keybuf);
-    if (it == t.by_key.end()) {
-      uint32_t shard = digest % t.n_shards;
-      uint32_t nxt = t.next_free[shard];
-      if (nxt >= t.per_shard) {
-        t.dropped++;
-        return -1;
-      }
-      t.next_free[shard] = nxt + 1;
-      int32_t slot = (int32_t)(shard * t.per_shard + nxt);
+    if (it != t.by_key.end()) {
+      slot = it->second;
+    } else {
+      slot = t.allocate(digest);
+      if (slot < 0) return -1;
       it = t.by_key.emplace(keybuf, slot).first;
+      t.key_of[slot] = &it->first;
       m.new_keys.push_back(NewKey{kind, slot, scope,
                                   (uint8_t)(alloc_imported ? 1 : 0),
                                   std::string(name, name_len), joined});
-      // tag-explosion detector: every distinct-key allocation charges
-      // the owning tenant's window counter; crossing the budget demotes
-      // it (subsequent datagrams collapse onto rollup keys instead of
-      // evicting healthy tenants' hot keys out of shard capacity)
+    }
+    if (!t.touched(slot)) {
+      t.touch(slot, (uint8_t)(scope | (alloc_imported ? 0x80 : 0)));
+      // tag-explosion detector: every distinct key of the interval
+      // charges the owning tenant's window counter; crossing the budget
+      // demotes it (subsequent datagrams collapse onto rollup keys
+      // instead of evicting healthy tenants' hot keys out of shard
+      // capacity)
       if (cur_entry) {
         uint64_t w =
             cur_entry->window_keys.fetch_add(1, std::memory_order_relaxed)
@@ -491,7 +586,6 @@ struct Parser {
           cur_entry->demoted.store(true, std::memory_order_relaxed);
       }
     }
-    int32_t slot = it->second;
     lk.unlock();
     if (master) local_cache.emplace(keybuf, slot);
     return slot;
@@ -967,9 +1061,10 @@ int vt_next_special(void* hp, char* buf, int cap) {
 
 // Slot allocation for Python-side callers (imports, span-extracted
 // metrics) so native wire ingest and the Python paths share one slot
-// space. kind: 0=counter 1=gauge 2=histogram 3=set 4=timer. *was_new is
-// set to 1 when this call allocated the slot. Returns -1 when the shard
-// is at capacity.
+// space. kind: 0=counter 1=gauge 2=histogram 3=set 4=timer; scope's bit 7
+// marks a caller on the import path (NewKey.imported). *was_new is set to
+// 1 when this call allocated the slot. Returns -1 when the shard is at
+// capacity.
 int32_t vt_slot_for(void* hp, int kind, int scope, const char* name,
                     int name_len, const char* tags, int tags_len,
                     uint32_t digest, int* was_new) {
@@ -985,42 +1080,82 @@ int32_t vt_slot_for(void* hp, int kind, int scope, const char* name,
   }
   p->joined.assign(tags, tags_len);
   size_t before = p->new_keys.size();
-  int32_t slot = p->slot_for(*t, (uint8_t)kind, (uint8_t)scope, name,
-                             name_len, digest);
+  p->alloc_imported = (scope & 0x80) != 0;
+  int32_t slot = p->slot_for(*t, (uint8_t)kind, (uint8_t)(scope & 0x7F),
+                             name, name_len, digest);
+  p->alloc_imported = false;
   *was_new = p->new_keys.size() > before ? 1 : 0;
   return slot;
 }
 
-// Flush boundary: clear key maps (state is flush-scoped, worker.go:498).
-// A staged shard map (vt_shard_map_set) is applied HERE — tables are
-// empty at this instant, so re-deriving per_shard/next_free under the
-// new count re-keys nothing and no packed batch straddles two maps.
+// A histo slot took a directly-sampled value: it is no longer
+// imported-only in this interval (aggregation/host.py SlotMeta).
+void vt_sampled_directly(void* hp, int32_t slot) {
+  auto* p = (Parser*)hp;
+  KindTable& t = p->histos;
+  {
+    std::shared_lock<std::shared_mutex> lk(p->key_mu);
+    if (slot < 0 || (size_t)slot >= t.first.size() ||
+        !(t.first[slot] & 0x80))
+      return;
+  }
+  std::unique_lock<std::shared_mutex> lk(p->key_mu);
+  t.first[slot] &= 0x7F;
+}
+
+// The interval's live keys of one table (0=counter 1=gauge 2=set
+// 3=histo) so far: their slots in first-arrival order and, a row each,
+// the scope of the interval's first arrival (bit 7: imported). Returns
+// the count, or -count when cap is smaller (nothing written).
+int vt_live_keys(void* hp, int table, int32_t* slots, uint8_t* first,
+                 int cap) {
+  auto* p = (Parser*)hp;
+  std::shared_lock<std::shared_mutex> lk(p->key_mu);
+  if (table < 0 || table > 3) return 0;
+  const KindTable& t = *p->tables()[table];
+  int n = (int)t.live.size();
+  if (n > cap) return -n;
+  if (n) memcpy(slots, t.live.data(), (size_t)n * sizeof(int32_t));
+  for (int i = 0; i < n; i++) first[i] = t.first[t.live[i]];
+  return n;
+}
+
+// [keys_live, keys_new, keys_evicted] of the intervals closed so far
+// (Parser::keys_live).
+void vt_key_counters(void* hp, uint64_t* out) {
+  auto* p = (Parser*)hp;
+  std::shared_lock<std::shared_mutex> lk(p->key_mu);
+  out[0] = p->keys_live;
+  out[1] = p->keys_new;
+  out[2] = p->keys_evicted;
+}
+
+// Flush boundary: every table starts its next interval with an empty live
+// list and keeps its keys (the reference's worker maps are flush-scoped,
+// worker.go:498; here only what an interval emits is). A staged shard
+// map (vt_shard_map_set) or capacity (vt_capacity_set) is applied HERE
+// and empties the tables, since it moves slots: no packed batch
+// straddles two maps, and every key is allocated (and recorded) anew.
 void vt_reset(void* hp) {
   auto* p = (Parser*)hp;
   std::unique_lock<std::shared_mutex> lk(p->key_mu);
-  p->counters.reset();
-  p->gauges.reset();
-  p->sets.reset();
-  p->histos.reset();
-  p->new_keys.clear();
+  auto ts = p->tables();
+  for (KindTable* t : ts) {
+    p->keys_live += t->live.size();
+    p->keys_new += t->new_keys;
+    p->keys_evicted += t->evicted;
+    t->next_interval();
+  }
   if (p->pending_shards) {
     uint32_t n = p->pending_shards;
     p->pending_shards = 0;
-    p->counters.init(p->counters.capacity, n);
-    p->gauges.init(p->gauges.capacity, n);
-    p->sets.init(p->sets.capacity, n);
-    p->histos.init(p->histos.capacity, n);
+    for (KindTable* t : ts) t->init(t->capacity, n);
   }
   // staged per-kind growth applies after any shard-map change so a
   // combined stage lands as (new shards, new caps) in one quiesce
-  if (p->pending_caps[0] | p->pending_caps[1] | p->pending_caps[2] |
-      p->pending_caps[3]) {
-    KindTable* ts[4] = {&p->counters, &p->gauges, &p->sets, &p->histos};
-    for (int i = 0; i < 4; i++) {
-      if (p->pending_caps[i])
-        ts[i]->init(p->pending_caps[i], ts[i]->n_shards);
-      p->pending_caps[i] = 0;
-    }
+  for (int i = 0; i < 4; i++) {
+    if (p->pending_caps[i]) ts[i]->init(p->pending_caps[i], ts[i]->n_shards);
+    p->pending_caps[i] = 0;
   }
   // tenant quarantine decay: fold this window's exact distinct-key count
   // into the carried estimate (est = est*decay + window) and re-admit a
@@ -1068,18 +1203,15 @@ void vt_capacity_set(void* hp, uint32_t cc, uint32_t gc, uint32_t sc,
 }
 
 // Per-kind occupancy snapshot for the growth planner: 3 u64 per kind in
-// counter/gauge/set/histo order — [allocated slots, cumulative dropped,
-// capacity]. Takes key_mu shared; safe to call from the pipeline thread
-// while ring workers parse.
+// counter/gauge/set/histo order — [keys live in this interval,
+// cumulative dropped, capacity]. Takes key_mu shared; safe to call from
+// the pipeline thread while ring workers parse.
 void vt_table_stats(void* hp, uint64_t* out) {
   auto* p = (Parser*)hp;
   std::shared_lock<std::shared_mutex> lk(p->key_mu);
-  const KindTable* ts[4] = {&p->counters, &p->gauges, &p->sets,
-                            &p->histos};
+  auto ts = p->tables();
   for (int i = 0; i < 4; i++) {
-    uint64_t used = 0;
-    for (uint32_t nf : ts[i]->next_free) used += nf;
-    out[i * 3 + 0] = used;
+    out[i * 3 + 0] = ts[i]->live.size();
     out[i * 3 + 1] = ts[i]->dropped;
     out[i * 3 + 2] = ts[i]->capacity;
   }
@@ -2292,8 +2424,9 @@ void vrm_resume(void* h) {
   }
 }
 
-// Flush boundary: reset the master tables and every ring's key-replica
-// cache. Caller must hold the quiesce (vrm_pause) and have emitted all
+// Flush boundary: start the master tables' next interval and clear every
+// ring's key-replica cache, so a ring's first hit of a key in the
+// interval goes to the master and marks it live. Caller must hold the quiesce (vrm_pause) and have emitted all
 // rings first.
 void vrm_reset(void* h) {
   auto* mr = (MultiRing*)h;

@@ -302,9 +302,7 @@ class Aggregator:
             self.dropped_capacity += 1
             return
         if kind in ("histogram", "timer"):
-            mt = self.table.meta_for_slot(kind, slot)
-            if mt is not None and mt.imported_only:
-                mt.imported_only = False
+            self.table.sampled_directly(kind, slot)
         if kind == "counter":
             self.batcher.add_counter(slot, float(m.value), m.sample_rate)
         elif kind == "gauge":

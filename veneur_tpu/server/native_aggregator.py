@@ -7,17 +7,24 @@ metrics, service checks) share the same slot space through vt_slot_for and
 stage through the ordinary Python Batcher — both batch streams feed the
 same jitted ingest step.
 
-Slot metadata (SlotMeta for flush labeling) is reconstructed lazily from
-the C++ engine's new-key records; status checks keep a pure-Python table
-(they never ride the native wire path's kinds).
+The engine's key table outlives the flush interval: a key keeps its slot
+from interval to interval, and its SlotMeta (flush labeling), built once
+from the engine's new-key record, lives with the feed (_SlotMetas). What
+an interval owns is its live list, the slots touched in it in
+first-arrival order, which the engine hands over as arrays; a swap
+therefore pays for the keys that are new, not for every live key. What
+is emitted is what a flush-scoped table (aggregation/host.py KeyTable,
+the reference's worker maps) emits, row for row. Status checks keep a
+pure-Python table per interval (they never ride the native wire path's
+kinds).
 
 Known imprecisions, documented:
 
-- A histo slot first created by the import path and later hit by native
-  wire samples keeps imported_only=True for the interval (the native path
-  doesn't report per-slot direct-hit sets), so its aggregates are
-  suppressed on a global tier — strictly conservative (percentiles still
-  flush).
+- A histo slot whose first arrival in an interval came by the import path
+  and that native wire samples hit later in it keeps imported_only=True
+  for that interval (the native path doesn't report per-slot direct-hit
+  sets), so its aggregates are suppressed on a global tier — strictly
+  conservative (percentiles still flush).
 - Gauge last-write-wins is per-stream: when the same gauge key arrives
   both over the wire (native staging) and via Python-side paths
   (span-extracted/imported) in one interval, the flush order is
@@ -35,6 +42,7 @@ Known imprecisions, documented:
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import time
 from typing import List
@@ -46,7 +54,7 @@ from veneur_tpu.aggregation.host import (
 from veneur_tpu.aggregation.state import TableSpec
 from veneur_tpu.aggregation.step import (
     ingest_step_packed, ingest_step_packed_rings, packed_layout)
-from veneur_tpu.native import NativeIngest
+from veneur_tpu.native import IMPORTED_BIT, LIVE_TABLES, NativeIngest
 from veneur_tpu.observability import hostspans
 from veneur_tpu.server.aggregator import Aggregator
 from veneur_tpu.server.sharded_aggregator import ShardedAggregator
@@ -54,30 +62,79 @@ from veneur_tpu.server.sharded_aggregator import ShardedAggregator
 log = logging.getLogger("veneur_tpu.server.native_aggregator")
 
 
-class NativeKeyTable:
-    """KeyTable facade over the C++ slot maps + a Python status table."""
+class _SlotMetas:
+    """slot -> (slot, SlotMeta) for every key the engine's tables hold: the
+    Python half of the persistent key table. It lives with the feed, not
+    with the interval, and is written on the pipeline thread only, when
+    the engine allocates a slot. A pair is never changed once an
+    interval's view may hold it: where a key returns with another scope
+    or import standing, its slot gets a new pair."""
 
-    def __init__(self, spec: TableSpec, eng: NativeIngest, n_shards: int):
+    def __init__(self, spec: TableSpec):
+        caps = (spec.counter_capacity, spec.gauge_capacity,
+                spec.set_capacity, spec.histo_capacity)
+        self.pairs = {t: np.empty(c, object)
+                      for t, c in zip(LIVE_TABLES, caps)}
+        # what pairs[slot][1] states, in the engine's one byte a key
+        self.first = {t: np.zeros(c, np.uint8)
+                      for t, c in zip(LIVE_TABLES, caps)}
+
+    def put(self, table: str, slot: int, meta: SlotMeta) -> None:
+        self.pairs[table][slot] = (slot, meta)
+        self.first[table][slot] = meta.scope | (
+            IMPORTED_BIT if meta.imported_only else 0)
+
+    def rows(self, table: str, slots, first) -> list:
+        """[(slot, SlotMeta)] of an interval's live keys (NativeIngest.
+        live_keys), by lookup: nothing is built for a key whose scope and
+        import standing are what they were when it was last emitted."""
+        pairs = self.pairs[table]
+        for i in np.flatnonzero(self.first[table][slots] != first).tolist():
+            slot, f = int(slots[i]), int(first[i])
+            self.put(table, slot, dataclasses.replace(
+                pairs[slot][1], scope=f & ~IMPORTED_BIT,
+                imported_only=bool(f & IMPORTED_BIT)))
+        return pairs[slots].tolist()
+
+
+class _IntervalKeys:
+    """A detached interval's keys, as KeyTable states them to the flush
+    worker: frozen when the swap made it. The next interval's allocations
+    and evictions, which reuse slots while the worker still reads, do not
+    reach it."""
+
+    def __init__(self, meta: dict, status: _KindTable):
+        self.meta = meta          # kind-table name -> [(slot, SlotMeta)]
+        self.status = status
+
+    def get_meta(self, kind: str):
+        if kind == "status":
+            return self.status.meta
+        return self.meta[KeyTable._table_name(kind)]
+
+    def meta_for_slot(self, kind: str, slot: int):
+        if kind == "status":
+            return self.status.by_slot.get(slot)
+        return dict(self.get_meta(kind)).get(slot)
+
+
+class NativeKeyTable:
+    """KeyTable facade over the live interval: the C++ slot maps, the
+    feed's _SlotMetas and a Python status table of the interval's own."""
+
+    def __init__(self, spec: TableSpec, eng: NativeIngest, n_shards: int,
+                 metas: _SlotMetas):
         self.spec = spec
         self.eng = eng
         self.n_shards = n_shards
+        self.metas = metas
         self.status = _KindTable(spec.status_capacity, n_shards)
-        # drained metadata: kind-table name -> [(slot, SlotMeta)]
-        self.meta = {"counter": [], "gauge": [], "set": [], "histo": []}
-        self.by_slot = {"counter": {}, "gauge": {}, "set": {}, "histo": {}}
-        self._finalized = False
 
     _TABLE = staticmethod(KeyTable._table_name)
 
-    def _drain(self):
-        if self._finalized:
-            return
+    def _absorb_new_keys(self):
         for kind, slot, scope, name, joined, imported in \
                 self.eng.drain_new_keys():
-            tname = self._TABLE(kind)
-            if slot in self.by_slot[tname]:
-                # registered python-side with the exact tag tuple already
-                continue
             # flush labels use the FIRST arrival's tags, matching the
             # reference's one-sampler-per-MetricKey semantics. Deliberate
             # deviation: an empty tag SECTION (`|#`) and no section both
@@ -86,12 +143,10 @@ class NativeKeyTable:
             # the empty section arrived first — a cosmetic empty tag on
             # a pathological packet shape; the key identity (and the
             # digest) agree with the reference either way.
-            m = SlotMeta(name=name,
-                         tags=tuple(joined.split(",")) if joined else (),
-                         scope=scope, kind=kind, joined_tags=joined,
-                         imported_only=imported)
-            self.meta[tname].append((slot, m))
-            self.by_slot[tname][slot] = m
+            self.metas.put(self._TABLE(kind), slot, SlotMeta(
+                name=name, tags=tuple(joined.split(",")) if joined else (),
+                scope=scope, kind=kind, joined_tags=joined,
+                imported_only=imported))
 
     def slot_for(self, kind: str, name: str, tags: tuple, scope: int,
                  digest: int, hostname: str = "", imported: bool = False,
@@ -107,38 +162,49 @@ class NativeKeyTable:
             return self.status.alloc(key, digest, name, tags, scope, kind,
                                      hostname=hostname)
         joined = joined_tags if joined_tags is not None else ",".join(tags)
-        slot, was_new = self.eng.slot_for(kind, name, joined, scope, digest)
+        slot, was_new = self.eng.slot_for(kind, name, joined, scope, digest,
+                                          imported)
         if slot is not None and was_new:
-            # register the exact tuple now — tags from SSF maps may contain
-            # commas, which a joined-string round-trip would corrupt
-            tname = self._TABLE(kind)
-            m = SlotMeta(name=name, tags=tags, scope=scope, kind=kind,
-                         hostname=hostname, imported_only=imported,
-                         joined_tags=joined)
-            self.meta[tname].append((slot, m))
-            self.by_slot[tname][slot] = m
+            # register the exact tuple — tags from SSF maps may contain
+            # commas, which a joined-string round-trip would corrupt —
+            # over the allocation's own record, absorbed first
+            self._absorb_new_keys()
+            self.metas.put(self._TABLE(kind), slot, SlotMeta(
+                name=name, tags=tags, scope=scope, kind=kind,
+                hostname=hostname, imported_only=imported,
+                joined_tags=joined))
         return slot
 
+    def sampled_directly(self, kind: str, slot: int) -> None:
+        self.eng.sampled_directly(slot)
+
+    def _rows(self, table: str) -> list:
+        # the list first: a ring worker may allocate between the two
+        # calls, and every slot of the list must have its record absorbed
+        slots, first = self.eng.live_keys(table)
+        self._absorb_new_keys()
+        return self.metas.rows(table, slots, first)
+
     def get_meta(self, kind: str):
-        self._drain()
+        """[(slot, SlotMeta)] of the interval so far, in first-arrival
+        order; pipeline thread only, a new list at every call."""
         if kind == "status":
             return self.status.meta
-        return self.meta[self._TABLE(kind)]
+        return self._rows(self._TABLE(kind))
 
     def meta_for_slot(self, kind: str, slot: int):
         if kind == "status":
             return self.status.by_slot.get(slot)
-        self._drain()
-        return self.by_slot[self._TABLE(kind)].get(slot)
+        return dict(self.get_meta(kind)).get(slot)
 
     def dropped(self) -> int:
         return self.eng.stats()["dropped"] + self.status.dropped
 
-    def finalize(self):
-        """Detach: absorb remaining key records, stop draining (the engine's
-        maps are about to be reset for the next interval)."""
-        self._drain()
-        self._finalized = True
+    def detach(self) -> _IntervalKeys:
+        """The interval's keys for the flush worker, taken before the
+        engine's reset starts the next interval."""
+        return _IntervalKeys({t: self._rows(t) for t in LIVE_TABLES},
+                             self.status)
 
 
 class _NativeFeed:
@@ -154,7 +220,12 @@ class _NativeFeed:
         # the drain swap), so ingest never restarts
         self.eng = engine if engine is not None \
             else NativeIngest(self.spec, self.bspec, self.n_shards)
-        self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
+        # an engine handed over has had its tables emptied (the reset
+        # that applied the staged map or capacity), so every key it holds
+        # from here on leaves its record with this feed
+        self._slot_metas = _SlotMetas(self.spec)
+        self.table = NativeKeyTable(self.spec, self.eng, self.n_shards,
+                                    self._slot_metas)
 
     # -- wire path -----------------------------------------------------------
     def feed(self, data: bytes) -> List[bytes]:
@@ -250,9 +321,15 @@ class _NativeFeed:
         steps those emits fed that the engine does not keep: compactions
         (Aggregator._count_step) and the digest rows they compressed, as
         the device counted them (Aggregator._settle_step: exact at each
-        swap, up to _MAX_STEPS_IN_FLIGHT steps behind between two)."""
+        swap, up to _MAX_STEPS_IN_FLIGHT steps behind between two); and
+        how often the key table's persistence engaged in the intervals
+        swapped so far (NativeIngest.key_counters): the keys they held,
+        of them the ones a swap paid for (new) and did not (reused), and
+        the keys evicted to make room."""
+        keys = self.eng.key_counters()
         return {**self.eng.ring_stats(), "compactions": self.compactions,
-                "compact_rows": self.compact_rows}
+                "compact_rows": self.compact_rows, **keys,
+                "keys_reused": keys["keys_live"] - keys["keys_new"]}
 
     def ring_stats_per_ring(self) -> List[dict]:
         """Per-ring telemetry rows ([] outside multi-ring mode) — the
@@ -328,18 +405,19 @@ class _NativeFeed:
                 self.eng.rings_pause()
                 self._emit_rings()
             self._emit_native()
-        detached = self.table
-        # SlotMeta for every key of the interval, rebuilt from the
-        # engine's new-key records: host work in proportion to the live
-        # keys, on the pipeline thread
+        # the interval's keys, frozen for the flush worker: a SlotMeta is
+        # built for the keys allocated in it (none, in a steady stream);
+        # the rest is the engine's live list looked up in _slot_metas
         with hostspans.span("swap.finalize"):
-            detached.finalize()
+            detached = self.table.detach()
         state, _ = super().swap()
         # super() replaced self.table with a fresh Python KeyTable; the
-        # native engine keeps the slot space, so re-wrap it post-reset
+        # native engine keeps the keys and their slots and starts the
+        # next interval's live list, so re-wrap it post-reset
         with hostspans.span("swap.reset"):
             self.eng.reset()
-            self.table = NativeKeyTable(self.spec, self.eng, self.n_shards)
+            self.table = NativeKeyTable(self.spec, self.eng, self.n_shards,
+                                        self._slot_metas)
             if rings:
                 self.eng.rings_resume()
         return state, detached

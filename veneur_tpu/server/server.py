@@ -877,6 +877,21 @@ class Server:
                        self._ring_stats().get("emit_packed_ns", 0)),
                    kind="counter",
                    help="wall time inside C++ vt_emit_packed")
+        # the native key table across intervals (NativeIngest.key_counters,
+        # added up at each swap): how often its persistence engages
+        M.callback("veneur.swap.keys_live_total",
+                   lambda: float(self._ring_stats().get("keys_live", 0)),
+                   kind="counter", help="keys the swapped intervals held")
+        M.callback("veneur.swap.keys_new_total",
+                   lambda: float(self._ring_stats().get("keys_new", 0)),
+                   kind="counter",
+                   help="of them, keys allocated in their interval: the "
+                        "ones a swap builds a SlotMeta for")
+        M.callback("veneur.swap.keys_evicted_total",
+                   lambda: float(self._ring_stats().get("keys_evicted", 0)),
+                   kind="counter",
+                   help="keys of earlier intervals evicted to make room "
+                        "for new ones")
         # per-ring family (multi-ring engine only; empty single-ring).
         # The unlabeled veneur.ring.* names above stay the EXACT
         # cross-ring aggregates — sums, with depth_highwater as the

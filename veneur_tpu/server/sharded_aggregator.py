@@ -167,9 +167,7 @@ class ShardedAggregator(Aggregator):
             self.dropped_capacity += 1
             return
         if kind in ("histogram", "timer"):
-            mt = self.table.meta_for_slot(kind, slot)
-            if mt is not None and mt.imported_only:
-                mt.imported_only = False
+            self.table.sampled_directly(kind, slot)
         shard, local = self._local(kind, slot)
         b = self.batchers[shard]
         if kind == "counter":

@@ -4,11 +4,14 @@ Growth reuses the reshard drain's staged-then-applied-at-reset
 discipline (reshard/quiesce.py): new per-kind capacities are STAGED on
 the C++ engine under its key mutex (`capacity_set` → pending_caps),
 then APPLIED by the `vt_reset` that runs inside the very next swap's
-quiesce — while the engine's tables are empty and (for the multi-ring
-group) the ring workers are paused. Key tables are flush-scoped (every
-swap builds a fresh table from spec on both the Python and C++ paths),
-so a grow needs NO mid-interval rehash at all: the grow pause IS the
-swap pause, bounded at one flush interval by construction.
+quiesce — which empties the engine's tables for it, while (for the
+multi-ring group) the ring workers are paused. An interval's device
+state starts empty at every swap and the backend is rebuilt around the
+engine (a fresh Python KeyTable from spec; the C++ tables, which
+otherwise keep their keys across intervals, allocate every key anew
+under the new capacity), so a grow needs NO mid-interval rehash at
+all: the grow pause IS the swap pause, bounded at one flush interval by
+construction.
 
 Shard assignment (`route_digest % n_shards`, host.py slot rule) is
 capacity-independent, so growth only changes a shard's slot budget —

@@ -6,9 +6,11 @@ non-admitted row visible. The mechanism half — executing a capacity
 change at the swap boundary — lives in growth.py, the one site the
 vtlint `table-grow-quiesce` pass allows.
 
-Key tables are flush-scoped (a fresh table per interval), so "idle
-eviction" is not a table operation at all: a key that stops arriving
-simply occupies nothing next interval. What the census adds is exact
+Capacity is counted in an interval's own keys (the Python KeyTable is
+rebuilt at every swap; the native engine's table keeps a key's slot
+across intervals but gives it up to any new key once the key stays
+away), so "idle eviction" is not a table operation at all: a key that
+stops arriving simply occupies nothing next interval. What the census adds is exact
 OBSERVABILITY of that reclamation — `(kind, key) -> last_seen`, swept
 against `table_idle_ttl_s`, each expiry counted once in
 `evicted_total` — plus the demand signal that lets capacity shrink
@@ -150,7 +152,7 @@ class TableManager:
     @staticmethod
     def _iter_meta(table):
         """(table_kind, [(slot, SlotMeta)]) pairs of a DETACHED table,
-        Python KeyTable or finalized NativeKeyTable alike."""
+        Python KeyTable or a native interval's keys alike."""
         tables = getattr(table, "tables", None)
         if tables is not None:
             return [(k, t.meta) for k, t in tables.items()]
