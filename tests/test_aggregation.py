@@ -227,10 +227,13 @@ def test_histo_p99_max_error_per_key_zipf():
 
 
 def test_tiled_flush_matches_single_shot(monkeypatch):
-    """VERDICT r04 #2: a flush whose live buckets exceed FLUSH_BLOCK_ROWS
-    loops one block-shaped executable over row blocks instead of
-    compiling at live cardinality — and must produce EXACTLY the
-    single-shot flush's values, in the same get_meta positional order."""
+    """A flush whose live buckets exceed FLUSH_BLOCK_ROWS loops one
+    block-shaped executable over row blocks, so that compile time and
+    the program's working set are bounded by the block and not by live
+    cardinality — and must produce EXACTLY the single-shot flush's
+    values, in the same get_meta positional order. The benchmark's cell
+    agent-1m-names measures the tiling on the chip (five blocks a flush);
+    tests/test_1m_names_deployment.py holds it on the served path."""
     from veneur_tpu.samplers import parser
     from veneur_tpu.aggregation import step as step_mod
     from veneur_tpu.server.aggregator import Aggregator

@@ -175,7 +175,7 @@ SWAP_CHILDREN = {"swap.emit_staged", "swap.finalize", "swap.device_wait",
                  "swap.reset"}
 FLUSH_STAGES = {"device_update", "post_device", "frame_build",
                 "sink_fanout", "self_metrics"}
-DEVICE_UPDATE_CHILDREN = {"flush_dispatch", "flush_d2h"}
+DEVICE_UPDATE_CHILDREN = {"flush_plan", "flush_dispatch", "flush_d2h"}
 
 
 def _phase_counts(srv):
@@ -229,7 +229,7 @@ def test_served_interval_leaves_every_span():
     first = [r for r in recs if r.parent == swaps[0].index]
     assert SWAP_CHILDREN <= {r.name for r in first}
     assert all(inside(r, swaps[0]) for r in first)
-    # flush contains its stages, device_update its two
+    # flush contains its stages, device_update its three
     stages = [r for r in recs if r.parent == flushes[0].index]
     assert FLUSH_STAGES <= {r.name for r in stages}
     assert all(inside(r, flushes[0]) for r in stages)
@@ -254,9 +254,9 @@ def test_served_interval_leaves_every_span():
     # the new phases are observed once a flush, beside the old ones
     counts = _phase_counts(srv)
     for phase in ("ingest_drain", "swap_device_wait", "swap_host",
-                  "queue_wait", "device_update", "flush_dispatch",
-                  "flush_d2h", "post_device", "frame_build", "self_metrics",
-                  "total"):
+                  "queue_wait", "device_update", "flush_plan",
+                  "flush_dispatch", "flush_d2h", "post_device", "frame_build",
+                  "self_metrics", "total"):
         assert counts.get(phase) == 2, (phase, counts)
     totals = srv._t_flush_phase.totals()
     drain = totals[("ingest_drain",)][1]

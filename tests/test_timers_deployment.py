@@ -73,6 +73,27 @@ def bench():
             sys.path.remove(BENCH)
 
 
+def send_interval(agg, out, pool, datagrams, sizes, base, sent, lo, hi):
+    """Stream positions [lo, hi) of the cycled pool over the connected
+    socket `out`, never more than CREDIT datagrams ahead of what the
+    engine has parsed beyond `base`, then wait until it has parsed them
+    all. `sent` is the samples sent so far; returns it with these."""
+    for pos in range(lo, hi):
+        d = pos % pool.n_datagrams
+        deadline = time.monotonic() + 60
+        while sent - (agg.eng.stats()["processed"] - base) \
+                > CREDIT * pool.lines:
+            assert time.monotonic() < deadline, "the engine stalled"
+            time.sleep(0.0005)
+        out.send(datagrams[d])
+        sent += int(sizes[d])
+    deadline = time.monotonic() + 60
+    while agg.eng.stats()["processed"] - base < sent:
+        assert time.monotonic() < deadline, "the engine did not drain"
+        time.sleep(0.001)
+    return sent
+
+
 def serve_stream(bench, tmp_path, seed, overrides):
     """The deployment's server (config.read_config + new_from_config
     through the harness's build_server) fed BOUNDS' intervals over UDP;
@@ -107,19 +128,8 @@ def serve_stream(bench, tmp_path, seed, overrides):
         sent = 0
         for k in range(1, len(BOUNDS)):
             steps0, compactions0 = agg.steps_total, agg.compactions
-            for pos in range(BOUNDS[k - 1], BOUNDS[k]):
-                d = pos % pool.n_datagrams
-                deadline = time.monotonic() + 60
-                while sent - (agg.eng.stats()["processed"] - base) \
-                        > CREDIT * pool.lines:
-                    assert time.monotonic() < deadline, "the engine stalled"
-                    time.sleep(0.0005)
-                out.send(datagrams[d])
-                sent += int(sizes[d])
-            deadline = time.monotonic() + 60
-            while agg.eng.stats()["processed"] - base < sent:
-                assert time.monotonic() < deadline, "the engine did not drain"
-                time.sleep(0.001)
+            sent = send_interval(agg, out, pool, datagrams, sizes, base,
+                                 sent, BOUNDS[k - 1], BOUNDS[k])
             assert server.trigger_flush(wait=True, timeout=300)
             assert agg.compactions - compactions0 >= 2, (
                 agg.steps_total - steps0, agg.compactions - compactions0)

@@ -12,7 +12,7 @@ There is no switch. "Tracing off" is "no profiler session": the
 annotation is then a flag check and the record two clock reads and an
 append (~2 us a span on one slow CPU core). That is only affordable at
 step or stage granularity, so spans never go per datagram, per sample or
-per row: about three per ingest step and seventeen per flush. Work that
+per row: about three per ingest step and eighteen per flush. Work that
 repeats faster than that (the pump loop) is recorded as a run (`run_call`
 / `run_returned`): one record per run of consecutive calls, with their
 number.
@@ -37,7 +37,7 @@ from jax.profiler import TraceAnnotation
 
 # Records kept. Saturated ingest on a v5e writes 35-36 a second (PERF.md
 # §6, PR 28: ~11 steps/s x pump run + emit + dispatch, a sampled sync
-# every 64th step, ~17 a flush); 65536 holds half an hour of that, so a
+# every 64th step, ~18 a flush); 65536 holds half an hour of that, so a
 # 30 s run with its warm-up never wraps, at ~20 MB when full.
 MAX_RECORDS = 1 << 16
 

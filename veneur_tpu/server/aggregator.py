@@ -114,6 +114,13 @@ class Aggregator:
         # them (_settle_step), monotonic like steps_total
         self.compactions = 0
         self.compact_rows = 0
+        # flushes computed (compute_flush), the block-shaped calls of the
+        # flush program they dispatched and the live rows they flushed,
+        # written by the flush worker alone: blocks a flush and rows a
+        # block are read from their ratios
+        self.flushes_computed = 0
+        self.flush_blocks = 0
+        self.flush_rows = 0
         self._steps_in_flight = collections.deque()
         # shape key -> ring of host buffers, next to be packed first
         self._step_bufs: dict = {}
@@ -541,6 +548,11 @@ class Aggregator:
         the single-device layout already is one."""
         return state
 
+    def _count_flush(self, blocks: int, rows: int) -> None:
+        self.flushes_computed += 1
+        self.flush_blocks += blocks
+        self.flush_rows += rows
+
     def compute_flush(self, state, table, percentiles: List[float],
                       want_raw: bool = False, history=None
                       ) -> Tuple[Dict[str, np.ndarray], KeyTable]:
@@ -575,48 +587,57 @@ class Aggregator:
         caps = [spec.counter_capacity, spec.gauge_capacity,
                 spec.status_capacity, spec.set_capacity,
                 spec.histo_capacity]
-        slots = [live_slots(table, k) for k in
-                 ("counter", "gauge", "status", "set", "histogram")]
-        lens = [len(s) for s in slots]
-        n_blocks = max(1, max(
-            -(-n // min(pad_bucket(n, cap), FLUSH_BLOCK_ROWS))
-            for n, cap in zip(lens, caps)))
-        # Per-kind buckets sized to SPREAD each kind's rows evenly over
-        # all n_blocks invocations (ceil(n/n_blocks), padded): a kind
-        # smaller than the block-count driver never runs full-padding
-        # garbage blocks — e.g. 7M counters + 1M timers tiles as 57
-        # blocks of 128k counters x 18k timers, not 57 x 128k timers of
-        # which 49 are pure waste on the expensive quantile kernel.
-        buckets = tuple(min(pad_bucket(-(-n // n_blocks), cap),
-                            FLUSH_BLOCK_ROWS)
-                        for n, cap in zip(lens, caps))
-        shapes = flush_live_shapes(spec, *buckets, len(perc),
-                                   want_raw=want_raw)
-        # Tiled flush (VERDICT r04 #2): every invocation reuses ONE
-        # block-shaped executable — compile cost is bounded by the block
-        # size, never by live cardinality. n_blocks == 1 is the steady
-        # small-table case: same shapes as the old single-shot path. All
-        # blocks are dispatched before any is materialized, so the
-        # device pipelines them.
-        with hostspans.span("flush_dispatch"):
+        # the host's part before anything is dispatched: the live slots,
+        # the block count and bucket sizes, every block's packed input
+        with hostspans.span("flush_plan"):
+            slots = [live_slots(table, k) for k in
+                     ("counter", "gauge", "status", "set", "histogram")]
+            lens = [len(s) for s in slots]
+            n_blocks = max(1, max(
+                -(-n // min(pad_bucket(n, cap), FLUSH_BLOCK_ROWS))
+                for n, cap in zip(lens, caps)))
+            # Per-kind buckets sized to SPREAD each kind's rows evenly over
+            # all n_blocks invocations (ceil(n/n_blocks), padded): a kind
+            # smaller than the block-count driver never runs full-padding
+            # garbage blocks — e.g. 7M counters + 1M timers tiles as 57
+            # blocks of 128k counters x 18k timers, not 57 x 128k timers of
+            # which 49 are pure waste on the expensive quantile kernel.
+            buckets = tuple(min(pad_bucket(-(-n // n_blocks), cap),
+                                FLUSH_BLOCK_ROWS)
+                            for n, cap in zip(lens, caps))
+            shapes = flush_live_shapes(spec, *buckets, len(perc),
+                                       want_raw=want_raw)
+            flats = [pack_flush_inputs(
+                perc, pack_bucket_chunks(slots, buckets, i))
+                for i in range(n_blocks)]
             if history is not None:
                 from veneur_tpu.history.writer import SENTINEL
                 plan = history.plan_flush(table)
+                hflats = [np.concatenate(
+                    pack_bucket_chunks(plan.dests, buckets, i, fill=SENTINEL)
+                    + [np.asarray([plan.col], np.int32)])
+                    for i in range(n_blocks)]
+        self._count_flush(n_blocks, sum(lens))
+        # Tiled flush: every invocation reuses ONE block-shaped
+        # executable, so that compile time and the program's working set
+        # are bounded by the block (FLUSH_BLOCK_ROWS rows a kind), never
+        # by live cardinality: a million live names flush as five calls
+        # of the program a hundred thousand flush with, not as a program
+        # of their own. n_blocks == 1 is the steady small-table case. All
+        # blocks are dispatched before any is materialized, so the device
+        # pipelines them. The benchmark's cell agent-1m-names measures it
+        # (PERF.md sections 4 and 5).
+        with hostspans.span("flush_dispatch"):
+            if history is not None:
                 hist = history.begin_flush(plan)
                 try:
                     packs = []
-                    for i in range(n_blocks):
-                        hflat = np.concatenate(
-                            pack_bucket_chunks(plan.dests, buckets, i,
-                                               fill=SENTINEL)
-                            + [np.asarray([plan.col], np.int32)])
+                    for flat, hflat in zip(flats, hflats):
                         p, hist = flush_live_hist_packed(
-                            state, pack_flush_inputs(
-                                perc,
-                                pack_bucket_chunks(slots, buckets, i)),
-                            hist, hflat, spec=spec, hspec=history.spec,
-                            n_q=len(perc), buckets=buckets,
-                            want_raw=want_raw, clear=(i == 0))
+                            state, flat, hist, hflat, spec=spec,
+                            hspec=history.spec, n_q=len(perc),
+                            buckets=buckets, want_raw=want_raw,
+                            clear=not packs)
                         packs.append(p)
                 except BaseException:
                     history.abort_flush()
@@ -625,11 +646,9 @@ class Aggregator:
             else:
                 packs = [
                     flush_live_in_packed(
-                        state, pack_flush_inputs(
-                            perc, pack_bucket_chunks(slots, buckets, i)),
-                        spec=spec, n_q=len(perc), buckets=buckets,
-                        want_raw=want_raw)
-                    for i in range(n_blocks)]
+                        state, flat, spec=spec, n_q=len(perc),
+                        buckets=buckets, want_raw=want_raw)
+                    for flat in flats]
         # the host's wait for the flush program, which queues on the
         # device behind every ingest step dispatched since the swap,
         # plus the transfer

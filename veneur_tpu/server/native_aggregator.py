@@ -321,14 +321,19 @@ class _NativeFeed:
         steps those emits fed that the engine does not keep: compactions
         (Aggregator._count_step) and the digest rows they compressed, as
         the device counted them (Aggregator._settle_step: exact at each
-        swap, up to _MAX_STEPS_IN_FLIGHT steps behind between two); and
-        how often the key table's persistence engaged in the intervals
-        swapped so far (NativeIngest.key_counters): the keys they held,
-        of them the ones a swap paid for (new) and did not (reused), and
-        the keys evicted to make room."""
+        swap, up to _MAX_STEPS_IN_FLIGHT steps behind between two); the
+        flushes computed, their blocks and their live rows
+        (Aggregator._count_flush); and how often the key table's
+        persistence engaged in the intervals swapped so far
+        (NativeIngest.key_counters): the keys they held, of them the ones
+        a swap paid for (new) and did not (reused), and the keys evicted
+        to make room."""
         keys = self.eng.key_counters()
         return {**self.eng.ring_stats(), "compactions": self.compactions,
-                "compact_rows": self.compact_rows, **keys,
+                "compact_rows": self.compact_rows,
+                "flushes": self.flushes_computed,
+                "flush_blocks": self.flush_blocks,
+                "flush_rows": self.flush_rows, **keys,
                 "keys_reused": keys["keys_live"] - keys["keys_new"]}
 
     def ring_stats_per_ring(self) -> List[dict]:

@@ -835,6 +835,18 @@ class Server:
                    kind="counter",
                    help="digest rows those compactions compressed "
                         "(the rows that took a sample since the last)")
+        M.callback("veneur.flush.computed_total",
+                   lambda: self.aggregator.flushes_computed,
+                   kind="counter", help="flushes computed (compute_flush)")
+        M.callback("veneur.flush.blocks_total",
+                   lambda: self.aggregator.flush_blocks,
+                   kind="counter",
+                   help="block-shaped calls of the flush program those "
+                        "flushes dispatched (one while every kind's live "
+                        "rows fit a block)")
+        M.callback("veneur.flush.rows_total",
+                   lambda: self.aggregator.flush_rows,
+                   kind="counter", help="live rows those flushes gathered")
         M.callback("veneur.device.steps_synced_total",
                    lambda: self.aggregator.steps_synced,
                    kind="counter",
@@ -2671,7 +2683,8 @@ class Server:
                     and self._flushes_since_ckpt + 1
                     >= max(1, self.cfg.checkpoint_interval_flushes))
         with stage("device_update",
-                   split=("flush_dispatch", "flush_d2h")) as sp:
+                   split=("flush_plan", "flush_dispatch",
+                          "flush_d2h")) as sp:
             if (self._forward_client is not None or ckpt_due
                     or self.cfg.collective_attach):
                 flush_arrays, table, raw = agg.compute_flush(

@@ -372,12 +372,16 @@ class ShardedAggregator(Aggregator):
         # boundary as the single-device flush_live): the KeyTable's
         # global slot numbers ARE flat indices into the [S, K_per]
         # reshape by construction (slot = shard * per_shard + local)
-        idx = {kind: jnp.asarray(live_indices(table, kind, cap))
-               for kind, cap in (("counter", self.spec.counter_capacity),
-                                 ("gauge", self.spec.gauge_capacity),
-                                 ("status", self.spec.status_capacity),
-                                 ("set", self.spec.set_capacity),
-                                 ("histogram", self.spec.histo_capacity))}
+        with hostspans.span("flush_plan"):
+            idx = {kind: jnp.asarray(live_indices(table, kind, cap))
+                   for kind, cap in (
+                       ("counter", self.spec.counter_capacity),
+                       ("gauge", self.spec.gauge_capacity),
+                       ("status", self.spec.status_capacity),
+                       ("set", self.spec.set_capacity),
+                       ("histogram", self.spec.histo_capacity))}
+        # the merged flush is one program over every shard: one block
+        self._count_flush(1, sum(len(table.get_meta(k)) for k in idx))
 
         with hostspans.span("flush_dispatch"):
             gathered = _gather_sharded(
