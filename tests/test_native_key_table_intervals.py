@@ -355,3 +355,231 @@ def test_a_staged_shard_map_and_capacity_change(pair):
     n = len(lines)
     # allocated twice (before the change and after), reused once
     assert key_counters(pair) == (3 * n, 2 * n, 0)
+
+
+# -- the frame of a detached interval ---------------------------------------
+# The view a swap hands to the flush worker is columns
+# (native_aggregator._SlotColumns); a frame's names are read by slot from
+# the feed's columns on the worker while the next interval may write the
+# same slots.
+
+FRAME_KW = dict(percentiles=[0.5, 0.99], aggregates=["min", "max", "count"],
+                timestamp=7, hostname="h")
+
+
+def frame_of(agg, state, table, is_local=False):
+    from veneur_tpu.server.flusher import generate_frame
+    res = agg.compute_flush(state, table, FRAME_KW["percentiles"])[0]
+    frame = generate_frame(res, table, is_local=is_local, **FRAME_KW)
+    agg.count_frame(len(frame), frame.labels_reused)
+    return frame
+
+
+def labelled(frame):
+    """name -> (tags, value) of a frame's rows, each name once."""
+    out = {}
+    for name, value, _t, _msg, tags, _sinks, _host in frame.rows():
+        assert name not in out, name
+        out[name] = (tuple(tags), round(value, 3))
+    return out
+
+
+def fresh_lines(pair, n, line):
+    """n keys per table shard, dealt as fresh_counters deals them, of the
+    lines `line(i)` for i = 0, 1, ...: the i's (a key's shard is its
+    digest's, which its tags are part of)."""
+    by_shard = [[] for _ in range(pair.n_shards)]
+    i = 0
+    while min(len(b) for b in by_shard) < n:
+        b = by_shard[parser.parse_metric(line(i)).digest % pair.n_shards]
+        if len(b) < n:
+            b.append(i)
+        i += 1
+        assert i < 4096, "the lines' digests leave a shard out"
+    return [b[j] for j in range(n) for b in by_shard]
+
+
+def fresh_timers(pair, n, prefix):
+    return [f"{prefix}{i}" for i in fresh_lines(
+        pair, n, lambda i: f"{prefix}{i}:1|ms".encode())]
+
+
+def test_keys_that_arrive_in_another_order_keep_their_labels(pair):
+    def counter(i):
+        return f"ord.c{i}:{i + 1}|c|#i:{7 * i + 3}".encode()
+
+    def timer(i):
+        return f"ord.t{i}:{10 + i}|ms|#t:{5 * i + 1}".encode()
+
+    counters = fresh_lines(pair, 2, counter)
+    timers = fresh_lines(pair, 1, timer)
+    lines = [counter(i) for i in counters] + [timer(i) for i in timers]
+    want = {f"ord.c{i}": ((f"i:{7 * i + 3}",), i + 1.0) for i in counters}
+    for i in timers:
+        for suf, v in ((".min", 10.0 + i), (".max", 10.0 + i),
+                       (".count", 1.0), (".50percentile", 10.0 + i),
+                       (".99percentile", 10.0 + i)):
+            want[f"ord.t{i}{suf}"] = ((f"t:{5 * i + 1}",), v)
+    orders = (lines, lines[::-1], lines[1:] + lines[:1])
+    for k, order in enumerate(orders):
+        pair.send(*order)
+        frame = frame_of(pair.nat, *pair.nat.swap())
+        assert labelled(frame) == want
+        # the second and third flush build no name
+        assert frame.labels_reused == (len(frame) if k else 0)
+    pair.py.flush([0.5])
+
+
+def test_a_frame_is_labelled_by_its_own_interval(pair):
+    """Interval k is detached and not yet flushed while interval k+1
+    evicts its slots for other keys and gives one of its keys another
+    scope: k's frame carries k's names, tags and scopes, and k+1's its
+    own afterwards."""
+    per_c = SPEC.counter_capacity // pair.n_shards
+    per_h = SPEC.histo_capacity // pair.n_shards
+
+    old_c = fresh_counters(pair, per_c)
+    old_t = fresh_timers(pair, per_h, "ft.old")
+    first = [f"{n}:1|c|#i:1".encode() for n in old_c[:-1]] + \
+        [f"{old_c[-1]}:1|c|#veneurlocalonly,i:1".encode()] + \
+        [f"{n}:5|ms|#i:1".encode() for n in old_t] + \
+        [b"ft.g:3|g|#veneurlocalonly"]
+
+    def of_first(frame):
+        got = labelled(frame)
+        assert {n for n in got if "." not in n or n == "ft.g"} == \
+            set(old_c) | {"ft.g"}
+        assert {n.rsplit(".", 1)[0] for n in got
+                if n.startswith("ft.old")} == set(old_t)
+        for name, (tags, _v) in got.items():
+            if name != "ft.g":
+                assert tags == ("i:1",), name
+        return got
+
+    # interval 0 is flushed at once: its compound names are kept
+    pair.send(*first)
+    of_first(frame_of(pair.nat, *pair.nat.swap()))
+    # interval k: the same keys, detached and left waiting
+    pair.send(*first)
+    state_k, view_k = pair.nat.swap()
+    # interval k+1: every counter and timer slot goes to another key; the
+    # gauge, whose slot stays, returns global-only
+    new_c = fresh_counters(pair, per_c, avoid=set(old_c), prefix="n")
+    new_t = fresh_timers(pair, per_h, "ft.new")
+    pair.send(*[f"{n}:2|c|#i:2".encode() for n in new_c])
+    pair.send(*[f"{n}:6|ms|#i:2".encode() for n in new_t])
+    pair.send(b"ft.g:4|g|#veneurglobalonly")
+    assert pair.nat.ring_stats()["keys_evicted"] == 0   # counted at reset
+    state_n, view_n = pair.nat.swap()
+    assert pair.nat.ring_stats()["keys_evicted"] == \
+        SPEC.counter_capacity + SPEC.histo_capacity
+    assert sorted(view_k.columns("counter").slots.tolist()) == \
+        sorted(view_n.columns("counter").slots.tolist())
+    # k's frame, built after all that: a local tier still emits the gauge
+    # and the local-only counter as k scoped them, and every mixed timer's
+    # aggregates under its own name
+    frame_k = frame_of(pair.nat, state_k, view_k, is_local=True)
+    got = of_first(frame_k)
+    assert got["ft.g"] == ((), 3.0)
+    assert got[old_c[-1]][1] == 1.0
+    assert {n for n in got if n.endswith("percentile")} == set()
+    assert got[old_t[0] + ".count"] == (("i:1",), 1.0)
+    assert [m.scope for m in view_k.columns("gauge").metas] == [1]
+    assert [m.scope for m in view_n.columns("gauge").metas] == [2]
+    # no label of k's was kept over a key of k+1
+    frame_n = frame_of(pair.nat, state_n, view_n)
+    got_n = labelled(frame_n)
+    assert {n for n in got_n if not n.startswith("ft.")} == set(new_c)
+    assert {n.rsplit(".", 1)[0] for n in got_n
+            if n.startswith("ft.new")} == set(new_t)
+    assert not any(n.startswith("ft.old") for n in got_n)
+    assert all(tags == ("i:2",) for n, (tags, _v) in got_n.items()
+               if n != "ft.g")
+    assert got_n["ft.g"][1] == 4.0
+    assert frame_n.labels_reused == 0
+    # and the same keys again take every name from its column
+    pair.send(*[f"{n}:2|c|#i:2".encode() for n in new_c])
+    pair.send(*[f"{n}:6|ms|#i:2".encode() for n in new_t])
+    frame = frame_of(pair.nat, *pair.nat.swap())
+    assert {k: v for k, v in got_n.items() if k != "ft.g"} == labelled(frame)
+    assert frame.labels_reused == len(frame)
+    for _ in range(4):
+        pair.py.swap()
+
+
+def test_frame_rows_and_labels_reused_are_counted(pair):
+    steady = (b"fl.c0:1|c", b"fl.c1:1|c", b"fl.g:2|g", b"fl.s:a|s",
+              b"fl.t0:3|ms", b"fl.t1:4|ms")
+    rows = 4 + 2 * 5        # min, max, count and two percentiles a timer
+    pair.send(*steady)
+    frame_of(pair.nat, *pair.nat.swap())
+    st = pair.nat.ring_stats()
+    assert (st["frame_rows"], st["frame_labels_reused"]) == (rows, 0)
+    pair.send(*steady)
+    frame_of(pair.nat, *pair.nat.swap())
+    st = pair.nat.ring_stats()
+    assert (st["frame_rows"], st["frame_labels_reused"]) == (2 * rows, rows)
+    # two new counters and a new timer: their eight rows are built
+    pair.send(*steady, b"fl.c2:1|c", b"fl.c3:1|c", b"fl.t2:5|ms")
+    frame = frame_of(pair.nat, *pair.nat.swap())
+    st = pair.nat.ring_stats()
+    assert len(frame) == rows + 7
+    assert (st["frame_rows"], st["frame_labels_reused"]) == \
+        (3 * rows + 7, 2 * rows)
+    for _ in range(3):
+        pair.py.swap()
+
+
+def test_names_are_read_by_slot_while_the_next_interval_writes():
+    """The flush worker takes names from the feed's columns by slot, with
+    no lock, while the pipeline thread gives slots of the interval being
+    flushed to other keys (_SlotMetas: the stamp is written before a
+    label and read after it). Whatever the interleaving, a view's rows
+    carry its own keys' names, and no compound name of an old key is kept
+    over a new one."""
+    import sys
+    import threading
+    from veneur_tpu.aggregation.host import SlotMeta
+    from veneur_tpu.server.native_aggregator import _SlotMetas
+    n = 192
+    metas = _SlotMetas(TableSpec(counter_capacity=8, gauge_capacity=8,
+                                 status_capacity=8, set_capacity=8,
+                                 histo_capacity=n))
+    rng = np.random.default_rng(3)
+    first = np.zeros(n, np.uint8)
+    sufs = ("", ".min", ".99percentile")
+
+    def relabel(gen, slots):
+        for s in slots:
+            metas.put("histo", s, SlotMeta(
+                name=f"gen{gen}.k{s}", tags=(), scope=0, kind="timer"))
+
+    relabel(0, range(n))
+    held = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for gen in range(1, 250):
+            # the swap: this interval's view, then the next interval
+            view = metas.columns(
+                "histo", rng.permutation(n).astype(np.int32), first)
+            metas.epoch += 1
+            own = [m.name for m in view.metas]
+            writer = threading.Thread(
+                target=relabel,
+                args=(gen, rng.permutation(n)[:n // 2].tolist()))
+            writer.start()
+            sel = np.sort(rng.permutation(n)[:n // 3])
+            got = [(suf, rows, view.names(rows, suf)[0].tolist())
+                   for suf in sufs for rows in (None, sel)]
+            writer.join(timeout=60)
+            assert not writer.is_alive()
+            for suf, rows, names in got:
+                want = own if rows is None else [own[i] for i in rows]
+                assert names == [w + suf for w in want], (gen, suf)
+    finally:
+        sys.setswitchinterval(held)
+    # what the columns keep now is the last writer's
+    view = metas.columns("histo", np.arange(n, dtype=np.int32), first)
+    for suf in sufs:
+        names, _reused = view.names(None, suf)
+        assert names.tolist() == [m.name + suf for m in view.metas]

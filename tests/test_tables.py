@@ -215,9 +215,17 @@ def test_accounting_identity_merged_plus_resident_equals_sent():
     assert sum(counter_values(state, table).values()) == float(sent)
 
 
-def test_census_ttl_eviction_is_exact():
+@pytest.mark.parametrize("backend", ["python", "native"])
+def test_census_ttl_eviction_is_exact(backend):
     mgr = TableManager(SPEC, idle_ttl_s=50.0)
-    agg = Aggregator(SPEC, BSPEC)
+    if backend == "native":
+        from veneur_tpu import native
+        if not native.available():
+            pytest.skip("native engine unavailable")
+        from veneur_tpu.server.native_aggregator import NativeAggregator
+        agg = NativeAggregator(SPEC, BSPEC)
+    else:
+        agg = Aggregator(SPEC, BSPEC)
     for i in range(10):
         pm(agg, "counter", f"ev.c{i}", 1)
     _state, table1 = agg.swap()

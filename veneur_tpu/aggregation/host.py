@@ -17,12 +17,14 @@ sharded over a mesh (parallel/), so ingest scatters never cross devices.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import numpy as np
 
 from veneur_tpu.aggregation.state import TableSpec
 from veneur_tpu.aggregation.step import Batch
+from veneur_tpu.native import IMPORTED_BIT
 from veneur_tpu.utils.hashing import hll_reg_rho
 
 # metric type classes that own a table
@@ -58,6 +60,68 @@ class SlotMeta:
     # (tags + [...]) rather than mutate, which they all do.
     _emit_prep: Optional[tuple] = dataclasses.field(
         default=None, repr=False, compare=False)
+
+
+def object_column(items) -> np.ndarray:
+    """A 1-D object array of `items`, whatever they are (np.asarray
+    would look inside a tuple)."""
+    col = np.empty(len(items), object)
+    col[:] = items
+    return col
+
+
+def first_byte(meta: SlotMeta) -> int:
+    """What a SlotMeta states of its key's standing in an interval, as
+    the engine's one byte a key: the scope, IMPORTED_BIT set while it is
+    imported_only (NativeIngest.live_keys)."""
+    return meta.scope | IMPORTED_BIT if meta.imported_only else meta.scope
+
+
+def scopes_of(first: np.ndarray) -> np.ndarray:
+    """The scope column of a `first` column (KeyColumns)."""
+    return first & np.uint8(0xFF & ~IMPORTED_BIT)
+
+
+class KeyColumns:
+    """One kind's keys of an interval as columns: what flush labeling
+    reads, on every table type, through `table.columns(kind)`. Row i is
+    row i of the kind's flush arrays and pair i of get_meta(kind).
+
+    slots  int32: the device rows (step.live_slots).
+    first  uint8: first_byte of each SlotMeta.
+    metas  object: the SlotMeta.
+
+    This one is made from a get_meta list when a column is asked for (a
+    loop a column, what the list's readers paid before there were
+    columns); the native tables hand over arrays they hold
+    (native_aggregator._SlotColumns)."""
+
+    def __init__(self, pairs: list):
+        self.pairs = pairs
+
+    def __len__(self):
+        return len(self.pairs)
+
+    @functools.cached_property
+    def slots(self) -> np.ndarray:
+        return np.fromiter((s for s, _m in self.pairs), np.int32,
+                           len(self.pairs))
+
+    @functools.cached_property
+    def first(self) -> np.ndarray:
+        return np.fromiter((first_byte(m) for _s, m in self.pairs),
+                           np.uint8, len(self.pairs))
+
+    @functools.cached_property
+    def metas(self) -> np.ndarray:
+        return object_column([m for _s, m in self.pairs])
+
+    def names(self, sel=None, suffix: str = ""):
+        """(names of rows `sel` (all when None) with `suffix` appended,
+        as an object array; how many of them came out of a column kept
+        with the key: none here)."""
+        metas = self.metas if sel is None else self.metas[sel]
+        return object_column([m.name + suffix for m in metas]), 0
 
 
 class _KindTable:
@@ -166,6 +230,10 @@ class KeyTable:
     def get_meta(self, kind: str):
         """[(slot, SlotMeta)] in allocation order for flush labeling."""
         return self.tables[self._table_name(kind)].meta
+
+    def columns(self, kind: str) -> KeyColumns:
+        """get_meta(kind) as columns, made from the list."""
+        return KeyColumns(self.get_meta(kind))
 
     def meta_for_slot(self, kind: str, slot: int) -> Optional[SlotMeta]:
         return self.tables[self._table_name(kind)].by_slot.get(slot)

@@ -815,13 +815,11 @@ FLUSH_BLOCK_ROWS = 1 << 17
 
 
 def live_slots(table, kind: str):
-    """UNPADDED int32 slot-index array for a kind, in get_meta order."""
-    import numpy as np
-    metas = table.get_meta(kind)
-    idx = np.zeros(len(metas), np.int32)
-    for i, (slot, _m) in enumerate(metas):
-        idx[i] = slot
-    return idx
+    """UNPADDED int32 slot-index array for a kind, in get_meta order:
+    the table's own slot column (host.KeyColumns), which a native
+    interval holds as the array its engine handed over and a Python
+    KeyTable makes from its list."""
+    return table.columns(kind).slots
 
 
 def pack_bucket_chunks(slots, buckets, block_i: int, fill: int = 0):

@@ -121,6 +121,12 @@ class Aggregator:
         self.flushes_computed = 0
         self.flush_blocks = 0
         self.flush_rows = 0
+        # rows the frames built from those flushes emitted, and of them
+        # the rows whose name was taken from a column kept with the key
+        # and not built (flusher.MetricFrame.labels_reused); the flush
+        # worker's too
+        self.frame_rows = 0
+        self.frame_labels_reused = 0
         self._steps_in_flight = collections.deque()
         # shape key -> ring of host buffers, next to be packed first
         self._step_bufs: dict = {}
@@ -552,6 +558,10 @@ class Aggregator:
         self.flushes_computed += 1
         self.flush_blocks += blocks
         self.flush_rows += rows
+
+    def count_frame(self, rows: int, labels_reused: int) -> None:
+        self.frame_rows += rows
+        self.frame_labels_reused += labels_reused
 
     def compute_flush(self, state, table, percentiles: List[float],
                       want_raw: bool = False, history=None

@@ -24,12 +24,13 @@ from __future__ import annotations
 import dataclasses
 import math
 import threading
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from veneur_tpu.aggregation.host import (
-    KeyTable, SCOPE_GLOBAL, SCOPE_LOCAL)
+    KeyTable, SCOPE_GLOBAL, SCOPE_LOCAL, scopes_of)
+from veneur_tpu.native import IMPORTED_BIT
 from veneur_tpu.samplers.intermetric import (
     COUNTER, GAUGE, SINK_ONLY_TAG_PREFIX, STATUS, InterMetric, route_info)
 
@@ -59,13 +60,13 @@ def unique_timeseries(table: KeyTable, is_local: bool) -> int:
     the reference uses an HLL estimate over digests."""
     n = 0
     for kind in ("counter", "gauge", "set", "histogram", "status"):
-        for _slot, meta in table.get_meta(kind):
-            if not is_local or meta.kind == "status":
-                n += 1
-            elif meta.kind in ("counter", "gauge"):
-                n += meta.scope != SCOPE_GLOBAL
-            else:  # histogram / timer / set
-                n += meta.scope == SCOPE_LOCAL
+        cols = table.columns(kind)
+        if not is_local or kind == "status":
+            n += len(cols)
+        elif kind in ("counter", "gauge"):
+            n += int(np.count_nonzero(scopes_of(cols.first) != SCOPE_GLOBAL))
+        else:  # histogram / timer / set
+            n += int(np.count_nonzero(scopes_of(cols.first) == SCOPE_LOCAL))
     return n
 
 
@@ -84,18 +85,35 @@ def _prep(meta, hostname):
     return p
 
 
+def _as_list(col):
+    """A segment's column as a list: a loop over an object array boxes
+    an index a row."""
+    return col.tolist() if isinstance(col, np.ndarray) else col
+
+
 @dataclasses.dataclass
 class FrameSegment:
     """One homogeneous column group: every row shares the metric type and
     (for compound histo names) the suffix already baked into `names`.
-    `metas` holds the originating SlotMeta per row BY REFERENCE — tag
-    lists, routing, and hostname are derived lazily, so building a
+    `names` and `metas` are parallel columns, object arrays out of
+    generate_frame (one take each from the table's columns,
+    host.KeyColumns) or plain lists where a caller builds a segment by
+    hand. `metas` holds the originating SlotMeta per row BY REFERENCE:
+    tag lists, routing, and hostname are derived lazily, so building a
     segment allocates no per-metric Python objects."""
-    names: List[str]
-    values: np.ndarray       # float64, len == len(names)
+    names: Sequence[str]     # object array or list, len == len(values)
+    values: np.ndarray       # float64
     mtype: str               # COUNTER / GAUGE / STATUS
-    metas: List              # SlotMeta per row
+    metas: Sequence          # SlotMeta per row, object array or list
     is_status: bool = False  # carry meta.message into InterMetric
+
+    def take(self, idx) -> "FrameSegment":
+        """The segment cut to the rows `idx` (ascending ints)."""
+        def cut(col):
+            return (col[idx] if isinstance(col, np.ndarray)
+                    else [col[i] for i in idx])
+        return FrameSegment(cut(self.names), self.values[idx], self.mtype,
+                            cut(self.metas), self.is_status)
 
 
 @dataclasses.dataclass
@@ -110,10 +128,15 @@ class MetricFrame:
     uses in Go (flusher.go:169-298). Sinks that declare
     `accepts_frames = True` get the frame; `intermetrics()` materializes
     the exact object list for everything else (order is grouped by
-    segment, not interleaved per key — sinks are order-independent)."""
+    segment, not interleaved per key — sinks are order-independent).
+
+    `labels_reused` counts the rows whose name generate_frame took out
+    of a column kept with the key (KeyColumns.names) and did not build;
+    of len(frame) rows."""
     timestamp: int
     hostname: str
     segments: List[FrameSegment]
+    labels_reused: int = 0
     # memoized intermetrics(): several materializing consumers (plugins,
     # object-only sinks via the base-class default) may share one frame —
     # each rebuilding ~per-metric objects would multiply the exact cost
@@ -137,14 +160,13 @@ class MetricFrame:
         loop instead of reaching into SlotMeta internals."""
         hostname = self.hostname
         for seg in self.segments:
-            vals = seg.values.tolist()
             mtype = seg.mtype
-            metas = seg.metas
             is_status = seg.is_status
-            for i, name in enumerate(seg.names):
-                m = metas[i]
+            for name, m, value in zip(_as_list(seg.names),
+                                      _as_list(seg.metas),
+                                      seg.values.tolist()):
                 p = m._emit_prep or _prep(m, hostname)
-                yield (name, vals[i], mtype,
+                yield (name, value, mtype,
                        m.message if is_status else "", p[0], p[1], p[2])
 
     def intermetrics(self) -> List[InterMetric]:
@@ -163,112 +185,105 @@ class MetricFrame:
         return self._materialized
 
 
-def _simple_segment(metas, vals, mtype, is_local, *, skip_scope=None,
-                    keep_scope=None,
-                    is_status=False) -> Optional[FrameSegment]:
-    """Segment for a scalar kind. On a LOCAL tier, `skip_scope` drops
-    that scope (forwarded, not flushed) while `keep_scope` keeps only
-    that scope (the sets rule: everything else is forwarded). On a
-    global/standalone tier both are ignored — everything flushes."""
-    if not metas:
-        return None
-    n = len(metas)
-    vals = np.asarray(vals, np.float64)[:n]
-    if is_local and (skip_scope is not None or keep_scope is not None):
-        if keep_scope is not None:
-            keep = [i for i in range(n)
-                    if metas[i][1].scope == keep_scope]
-        else:
-            keep = [i for i in range(n)
-                    if metas[i][1].scope != skip_scope]
-        if len(keep) != n:
-            mlist = [metas[i][1] for i in keep]
-            return FrameSegment([m.name for m in mlist], vals[keep],
-                                mtype, mlist, is_status)
-    mlist = [m for _s, m in metas]
-    return FrameSegment([m.name for m in mlist], vals, mtype, mlist,
-                        is_status)
-
-
 def generate_frame(flush: Dict[str, np.ndarray], table: KeyTable,
                    *, percentiles: List[float], aggregates: List[str],
                    is_local: bool, timestamp: int,
                    hostname: str = "") -> MetricFrame:
     """Columnar twin of generate_intermetrics: identical emission rules
-    (scope routing, imported_only suppression, non-finite min/max drops),
-    vectorized filters, zero per-metric object construction."""
+    (scope routing, imported_only suppression, non-finite min/max drops)
+    as masks over the table's columns (host.KeyColumns: `first` holds a
+    key's scope and import standing), a segment's names and metas one
+    take each with the selection. Nothing here walks a row, and no
+    per-metric object is constructed."""
     segs: List[FrameSegment] = []
+    reused = 0
 
-    def add(seg):
-        if seg is not None and len(seg.names):
-            segs.append(seg)
+    def cut(col, sel):
+        return col if sel is None else col[sel]
 
-    add(_simple_segment(table.get_meta("counter"), flush["counter"],
-                        COUNTER, is_local, skip_scope=SCOPE_GLOBAL))
-    add(_simple_segment(table.get_meta("gauge"), flush["gauge"],
-                        GAUGE, is_local, skip_scope=SCOPE_GLOBAL))
-    add(_simple_segment(table.get_meta("status"), flush["status"],
-                        STATUS, is_local, is_status=True))
+    def add(cols, sel, metas, vals, mtype, suffix="", is_status=False):
+        """Rows `sel` of `cols` (all when None) as one segment; `metas`
+        and `vals` are already cut to them."""
+        nonlocal reused
+        if not len(vals):
+            return
+        names, kept = cols.names(sel, suffix)
+        reused += kept
+        segs.append(FrameSegment(names, vals, mtype, metas, is_status))
+
+    def simple(kind, vals, mtype, *, skip_scope=None, keep_scope=None,
+               is_status=False):
+        """Segment for a scalar kind. On a LOCAL tier, `skip_scope`
+        drops that scope (forwarded, not flushed) while `keep_scope`
+        keeps only that scope (the sets rule: everything else is
+        forwarded). On a global/standalone tier both are ignored:
+        everything flushes."""
+        cols = table.columns(kind)
+        sel = None
+        if is_local and (skip_scope is not None or keep_scope is not None):
+            scopes = scopes_of(cols.first)
+            keep = (scopes == keep_scope if keep_scope is not None
+                    else scopes != skip_scope)
+            if not keep.all():
+                sel = np.flatnonzero(keep)
+        add(cols, sel, cut(cols.metas, sel),
+            cut(np.asarray(vals, np.float64)[:len(cols)], sel), mtype,
+            is_status=is_status)
+
+    simple("counter", flush["counter"], COUNTER, skip_scope=SCOPE_GLOBAL)
+    simple("gauge", flush["gauge"], GAUGE, skip_scope=SCOPE_GLOBAL)
+    simple("status", flush["status"], STATUS, is_status=True)
     # sets have no local part: a local tier forwards the HLL and emits
     # only local-only sets (flusher.go:277-280)
-    add(_simple_segment(table.get_meta("set"), flush["set_estimate"],
-                        GAUGE, is_local, keep_scope=SCOPE_LOCAL))
+    simple("set", flush["set_estimate"], GAUGE, keep_scope=SCOPE_LOCAL)
 
-    metas = table.get_meta("histogram")
-    if metas:
-        n = len(metas)
-        hcount = np.asarray(flush["histo_count"])[:n]
-        mask = hcount > 0
-        scopes = imported = None
-        if is_local or any(m.imported_only for _s, m in metas):
-            scopes = np.fromiter((m.scope for _s, m in metas), np.int8, n)
-            imported = np.fromiter((m.imported_only for _s, m in metas),
-                                   np.bool_, n)
+    cols = table.columns("histogram")
+    n = len(cols)
+    if n:
+        mask = np.asarray(flush["histo_count"])[:n] > 0
+        scopes = scopes_of(cols.first)
+        imported = (cols.first & IMPORTED_BIT) != 0
         if is_local:
             mask &= scopes != SCOPE_GLOBAL
         # aggregate eligibility: imported-only MIXED histos on a global
         # tier emit percentiles only (flusher.go:61-77)
         agg_mask = mask
-        if imported is not None:
+        if imported.any():
             agg_mask = mask & (~imported | ((scopes == SCOPE_GLOBAL)
                                             & (not is_local)))
         perc_mask = mask
         if is_local:
             perc_mask = mask & (scopes == SCOPE_LOCAL)
 
-        asel = np.flatnonzero(agg_mask)
-        if len(asel):
-            base = [metas[i][1].name for i in asel]
-            mlist = [metas[i][1] for i in asel]
+        def selection(rows):
+            """(sel, metas) of a row mask; sel None where it takes
+            every row, so that a column is handed on and not copied."""
+            sel = None if rows.all() else np.flatnonzero(rows)
+            return sel, cut(cols.metas, sel)
+
+        if agg_mask.any():
+            asel, ametas = selection(agg_mask)
             for a in dict.fromkeys(aggregates):
                 if a not in AGGREGATE_FIELDS:
                     continue
-                col = np.asarray(flush[AGGREGATE_FIELDS[a][0]],
-                                 np.float64)[asel]
-                suf = "." + a
+                field, mtype = AGGREGATE_FIELDS[a]
+                sel, metas = asel, ametas
+                col = cut(np.asarray(flush[field], np.float64)[:n], sel)
                 if a in ("min", "max"):
                     fin = np.isfinite(col)
                     if not fin.all():
-                        keep = np.flatnonzero(fin)
-                        add(FrameSegment(
-                            [base[i] + suf for i in keep], col[keep],
-                            AGGREGATE_FIELDS[a][1],
-                            [mlist[i] for i in keep]))
-                        continue
-                add(FrameSegment([b + suf for b in base], col,
-                                 AGGREGATE_FIELDS[a][1], mlist))
-        if percentiles:
-            psel = np.flatnonzero(perc_mask)
-            if len(psel):
-                base = [metas[i][1].name for i in psel]
-                mlist = [metas[i][1] for i in psel]
-                hq = np.asarray(flush["histo_quantiles"],
-                                np.float64)[psel]
-                for pi, p in enumerate(percentiles):
-                    suf = "." + percentile_name(p)
-                    add(FrameSegment([b + suf for b in base], hq[:, pi],
-                                     GAUGE, mlist))
-    return MetricFrame(timestamp, hostname, segs)
+                        sel = np.flatnonzero(fin) if sel is None \
+                            else sel[fin]
+                        metas, col = metas[fin], col[fin]
+                add(cols, sel, metas, col, mtype, "." + a)
+        if percentiles and perc_mask.any():
+            psel, pmetas = selection(perc_mask)
+            hq = cut(np.asarray(flush["histo_quantiles"], np.float64)[:n],
+                     psel)
+            for pi, p in enumerate(percentiles):
+                add(cols, psel, pmetas, hq[:, pi], GAUGE,
+                    "." + percentile_name(p))
+    return MetricFrame(timestamp, hostname, segs, reused)
 
 
 def generate_intermetrics(flush: Dict[str, np.ndarray], table: KeyTable,

@@ -44,13 +44,15 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import threading
 import time
 from typing import List
 
 import numpy as np
 
 from veneur_tpu.aggregation.host import (
-    Batcher, BatchSpec, KeyTable, SlotMeta, _KindTable)
+    Batcher, BatchSpec, KeyColumns, KeyTable, SlotMeta, _KindTable,
+    first_byte)
 from veneur_tpu.aggregation.state import TableSpec
 from veneur_tpu.aggregation.step import (
     ingest_step_packed, ingest_step_packed_rings, packed_layout)
@@ -62,60 +64,170 @@ from veneur_tpu.server.sharded_aggregator import ShardedAggregator
 log = logging.getLogger("veneur_tpu.server.native_aggregator")
 
 
+class _KindLabels:
+    """One kind-table's flush labels by slot (_SlotMetas)."""
+
+    __slots__ = ("meta", "name", "first", "stamp", "compound")
+
+    def __init__(self, capacity: int):
+        self.meta = np.empty(capacity, object)     # SlotMeta
+        self.name = np.empty(capacity, object)     # its name
+        self.first = np.zeros(capacity, np.uint8)  # its first_byte
+        # the interval (_SlotMetas.epoch) a slot was last written in
+        self.stamp = np.zeros(capacity, np.uint32)
+        # suffix -> (object column name + suffix, bool column: filled),
+        # the compound names a timer emits (`name.min`, ...). The flush
+        # worker alone fills them, for a slot the first time a flush
+        # emits it, and they stay with the key; a write to the slot
+        # unfills them. The suffixes are the server's aggregates and
+        # percentiles, so a process holds as many columns as it emits
+        # rows a timer.
+        self.compound: dict = {}
+
+    def compound_column(self, suffix: str) -> tuple:
+        got = self.compound.get(suffix)
+        if got is None:
+            n = len(self.name)
+            got = self.compound[suffix] = (np.empty(n, object),
+                                           np.zeros(n, np.bool_))
+        return got
+
+
 class _SlotMetas:
-    """slot -> (slot, SlotMeta) for every key the engine's tables hold: the
+    """Flush labels by slot for every key the engine's tables hold: the
     Python half of the persistent key table. It lives with the feed, not
-    with the interval, and is written on the pipeline thread only, when
-    the engine allocates a slot. A pair is never changed once an
-    interval's view may hold it: where a key returns with another scope
-    or import standing, its slot gets a new pair."""
+    with the interval. The pipeline thread alone writes a slot (put),
+    when the engine allocates it or its key returns with another scope
+    or import standing; a SlotMeta is never changed once an interval's
+    view may hold it, the slot gets a new one.
+
+    The flush worker reads the name columns by slot while the next
+    interval may already be writing slots it reuses, with no lock: put
+    stamps the slot with `epoch` BEFORE it writes a label, detach hands
+    the view its epoch and starts the next, and the worker reads the
+    stamps AFTER the labels (_SlotColumns.names): a stamp above the
+    view's says the label may be a later key's, and the row is labelled
+    from the view's own SlotMeta."""
 
     def __init__(self, spec: TableSpec):
         caps = (spec.counter_capacity, spec.gauge_capacity,
                 spec.set_capacity, spec.histo_capacity)
-        self.pairs = {t: np.empty(c, object)
-                      for t, c in zip(LIVE_TABLES, caps)}
-        # what pairs[slot][1] states, in the engine's one byte a key
-        self.first = {t: np.zeros(c, np.uint8)
-                      for t, c in zip(LIVE_TABLES, caps)}
+        self.tables = {t: _KindLabels(c) for t, c in zip(LIVE_TABLES, caps)}
+        self.epoch = 0
 
     def put(self, table: str, slot: int, meta: SlotMeta) -> None:
-        self.pairs[table][slot] = (slot, meta)
-        self.first[table][slot] = meta.scope | (
-            IMPORTED_BIT if meta.imported_only else 0)
+        lab = self.tables[table]
+        lab.stamp[slot] = self.epoch
+        lab.meta[slot] = meta
+        lab.name[slot] = meta.name
+        lab.first[slot] = first_byte(meta)
+        # a list: the flush worker may add a column meanwhile (one it
+        # adds after this finds the stamp already written)
+        for names, filled in list(lab.compound.values()):
+            filled[slot] = False
+            names[slot] = None
 
-    def rows(self, table: str, slots, first) -> list:
-        """[(slot, SlotMeta)] of an interval's live keys (NativeIngest.
-        live_keys), by lookup: nothing is built for a key whose scope and
-        import standing are what they were when it was last emitted."""
-        pairs = self.pairs[table]
-        for i in np.flatnonzero(self.first[table][slots] != first).tolist():
+    def columns(self, table: str, slots, first) -> "_SlotColumns":
+        """An interval's live keys (NativeIngest.live_keys) as columns,
+        by lookup: nothing is built for a key whose scope and import
+        standing are what they were when it was last emitted."""
+        lab = self.tables[table]
+        for i in np.flatnonzero(lab.first[slots] != first).tolist():
             slot, f = int(slots[i]), int(first[i])
             self.put(table, slot, dataclasses.replace(
-                pairs[slot][1], scope=f & ~IMPORTED_BIT,
+                lab.meta[slot], scope=f & ~IMPORTED_BIT,
                 imported_only=bool(f & IMPORTED_BIT)))
-        return pairs[slots].tolist()
+        return _SlotColumns(slots, first, lab.meta[slots], lab, self.epoch)
+
+
+class _SlotColumns:
+    """host.KeyColumns of a native interval: the engine's `slots` and
+    `first` arrays as it handed them over and the SlotMeta column taken
+    at that moment, which are the interval's own and frozen; names are
+    read from the feed's columns by slot when a frame is built."""
+
+    __slots__ = ("slots", "first", "metas", "_labels", "_epoch")
+
+    def __init__(self, slots, first, metas, labels: _KindLabels,
+                 epoch: int):
+        self.slots, self.first, self.metas = slots, first, metas
+        self._labels, self._epoch = labels, epoch
+
+    def __len__(self):
+        return len(self.slots)
+
+    def names(self, sel=None, suffix: str = ""):
+        """KeyColumns.names: one take from the kept column; with a
+        suffix, the compound column of that suffix, built and kept for
+        the rows no flush has emitted yet. Reused are the rows whose
+        label was in its column before this interval began."""
+        lab, epoch = self._labels, self._epoch
+        slots = self.slots if sel is None else self.slots[sel]
+        if suffix:
+            column, filled = lab.compound_column(suffix)
+            out = column[slots]
+            kept = filled[slots]
+            todo = np.flatnonzero(~kept)
+            if len(todo):
+                at = slots[todo]
+                out[todo] = column[at] = lab.name[at] + suffix
+                filled[at] = True
+        else:
+            out = lab.name[slots]
+        stamps = lab.stamp[slots]          # after the labels: _SlotMetas
+        late = np.flatnonzero(stamps > epoch)
+        if len(late):
+            if suffix:
+                # what this flush filled there may lie over the new key's
+                filled[slots[late]] = False
+            rows = late if sel is None else sel[late]
+            out[late] = [m.name + suffix for m in self.metas[rows]]
+        reused = (kept & (stamps <= epoch)) if suffix else stamps < epoch
+        return out, int(np.count_nonzero(reused))
+
+    def pairs(self) -> list:
+        return list(zip(self.slots.tolist(), self.metas.tolist()))
+
+    def meta_for_slot(self, slot: int):
+        at = np.flatnonzero(self.slots == slot)
+        return self.metas[at[0]] if len(at) else None
 
 
 class _IntervalKeys:
     """A detached interval's keys, as KeyTable states them to the flush
     worker: frozen when the swap made it. The next interval's allocations
     and evictions, which reuse slots while the worker still reads, do not
-    reach it."""
+    reach it. It holds columns (_SlotColumns), which is all the tick
+    reads; the [(slot, SlotMeta)] list of get_meta is made for the
+    readers that ask for it (forward, checkpoint, history, watch, query),
+    once a kind."""
 
-    def __init__(self, meta: dict, status: _KindTable):
-        self.meta = meta          # kind-table name -> [(slot, SlotMeta)]
+    def __init__(self, cols: dict, status: _KindTable):
+        self.cols = cols          # kind-table name -> _SlotColumns
         self.status = status
+        self._pairs: dict = {}
+        self._pairs_lock = threading.Lock()
+
+    def columns(self, kind: str):
+        if kind == "status":
+            return KeyColumns(self.status.meta)
+        return self.cols[KeyTable._table_name(kind)]
 
     def get_meta(self, kind: str):
         if kind == "status":
             return self.status.meta
-        return self.meta[KeyTable._table_name(kind)]
+        table = KeyTable._table_name(kind)
+        # forward and checkpoint ask from threads of their own
+        with self._pairs_lock:
+            pairs = self._pairs.get(table)
+            if pairs is None:
+                pairs = self._pairs[table] = self.cols[table].pairs()
+        return pairs
 
     def meta_for_slot(self, kind: str, slot: int):
         if kind == "status":
             return self.status.by_slot.get(slot)
-        return dict(self.get_meta(kind)).get(slot)
+        return self.columns(kind).meta_for_slot(slot)
 
 
 class NativeKeyTable:
@@ -178,33 +290,42 @@ class NativeKeyTable:
     def sampled_directly(self, kind: str, slot: int) -> None:
         self.eng.sampled_directly(slot)
 
-    def _rows(self, table: str) -> list:
+    def _columns(self, table: str) -> _SlotColumns:
         # the list first: a ring worker may allocate between the two
         # calls, and every slot of the list must have its record absorbed
         slots, first = self.eng.live_keys(table)
         self._absorb_new_keys()
-        return self.metas.rows(table, slots, first)
+        return self.metas.columns(table, slots, first)
+
+    def columns(self, kind: str):
+        """The interval so far as columns; pipeline thread only."""
+        if kind == "status":
+            return KeyColumns(self.status.meta)
+        return self._columns(self._TABLE(kind))
 
     def get_meta(self, kind: str):
         """[(slot, SlotMeta)] of the interval so far, in first-arrival
         order; pipeline thread only, a new list at every call."""
         if kind == "status":
             return self.status.meta
-        return self._rows(self._TABLE(kind))
+        return self._columns(self._TABLE(kind)).pairs()
 
     def meta_for_slot(self, kind: str, slot: int):
         if kind == "status":
             return self.status.by_slot.get(slot)
-        return dict(self.get_meta(kind)).get(slot)
+        return self._columns(self._TABLE(kind)).meta_for_slot(slot)
 
     def dropped(self) -> int:
         return self.eng.stats()["dropped"] + self.status.dropped
 
     def detach(self) -> _IntervalKeys:
         """The interval's keys for the flush worker, taken before the
-        engine's reset starts the next interval."""
-        return _IntervalKeys({t: self._rows(t) for t in LIVE_TABLES},
+        engine's reset starts the next interval: a copy of the live list
+        and one take of the SlotMeta column a kind."""
+        view = _IntervalKeys({t: self._columns(t) for t in LIVE_TABLES},
                              self.status)
+        self.metas.epoch += 1
+        return view
 
 
 class _NativeFeed:
@@ -323,7 +444,9 @@ class _NativeFeed:
         the device counted them (Aggregator._settle_step: exact at each
         swap, up to _MAX_STEPS_IN_FLIGHT steps behind between two); the
         flushes computed, their blocks and their live rows
-        (Aggregator._count_flush); and how often the key table's
+        (Aggregator._count_flush); the rows their frames emitted and how
+        many of those took their name from a kept column
+        (Aggregator.count_frame); and how often the key table's
         persistence engaged in the intervals swapped so far
         (NativeIngest.key_counters): the keys they held, of them the ones
         a swap paid for (new) and did not (reused), and the keys evicted
@@ -333,7 +456,9 @@ class _NativeFeed:
                 "compact_rows": self.compact_rows,
                 "flushes": self.flushes_computed,
                 "flush_blocks": self.flush_blocks,
-                "flush_rows": self.flush_rows, **keys,
+                "flush_rows": self.flush_rows,
+                "frame_rows": self.frame_rows,
+                "frame_labels_reused": self.frame_labels_reused, **keys,
                 "keys_reused": keys["keys_live"] - keys["keys_new"]}
 
     def ring_stats_per_ring(self) -> List[dict]:

@@ -847,6 +847,16 @@ class Server:
         M.callback("veneur.flush.rows_total",
                    lambda: self.aggregator.flush_rows,
                    kind="counter", help="live rows those flushes gathered")
+        M.callback("veneur.flush.frame_rows_total",
+                   lambda: self.aggregator.frame_rows,
+                   kind="counter",
+                   help="rows the flushes' frames emitted (frame_build)")
+        M.callback("veneur.flush.frame_labels_reused_total",
+                   lambda: self.aggregator.frame_labels_reused,
+                   kind="counter",
+                   help="of those rows, the ones whose name came out of a "
+                        "column kept with the key and was not built in "
+                        "that flush")
         M.callback("veneur.device.steps_synced_total",
                    lambda: self.aggregator.steps_synced,
                    kind="counter",
@@ -2830,6 +2840,7 @@ class Server:
                 aggregates=self.cfg.aggregates,
                 is_local=self.cfg.is_local,
                 timestamp=ts, hostname=self.hostname)
+            agg.count_frame(len(final), getattr(final, "labels_reused", 0))
             if fbsp is not None:
                 fbsp.set_tag("rows", str(len(final)))
         # flush protection: at CRITICAL, withhold low-priority rows from
@@ -2963,22 +2974,18 @@ class Server:
                         return True
             return False
 
-        from veneur_tpu.server.flusher import FrameSegment, MetricFrame
+        from veneur_tpu.server.flusher import MetricFrame
         if isinstance(final, MetricFrame):
             segs, dropped = [], 0
             for seg in final.segments:
-                keep_idx = [i for i, m in enumerate(seg.metas)
-                            if keep(seg.names[i], m.tags)]
+                keep_idx = [i for i, (name, m)
+                            in enumerate(zip(seg.names, seg.metas))
+                            if keep(name, m.tags)]
                 dropped += len(seg.names) - len(keep_idx)
                 if not keep_idx:
                     continue
-                if len(keep_idx) == len(seg.names):
-                    segs.append(seg)
-                    continue
-                segs.append(FrameSegment(
-                    [seg.names[i] for i in keep_idx],
-                    seg.values[keep_idx], seg.mtype,
-                    [seg.metas[i] for i in keep_idx], seg.is_status))
+                segs.append(seg if len(keep_idx) == len(seg.names)
+                            else seg.take(keep_idx))
             return MetricFrame(final.timestamp, final.hostname,
                                segs), dropped
         kept = [m for m in final if keep(m.name, m.tags)]

@@ -381,7 +381,7 @@ class ShardedAggregator(Aggregator):
                        ("set", self.spec.set_capacity),
                        ("histogram", self.spec.histo_capacity))}
         # the merged flush is one program over every shard: one block
-        self._count_flush(1, sum(len(table.get_meta(k)) for k in idx))
+        self._count_flush(1, sum(len(table.columns(k)) for k in idx))
 
         with hostspans.span("flush_dispatch"):
             gathered = _gather_sharded(

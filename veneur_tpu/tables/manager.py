@@ -153,12 +153,8 @@ class TableManager:
     def _iter_meta(table):
         """(table_kind, [(slot, SlotMeta)]) pairs of a DETACHED table,
         Python KeyTable or a native interval's keys alike."""
-        tables = getattr(table, "tables", None)
-        if tables is not None:
-            return [(k, t.meta) for k, t in tables.items()]
-        out = [(k, m) for k, m in table.meta.items()]
-        out.append(("status", table.status.meta))
-        return out
+        return [(k, table.get_meta("histogram" if k == "histo" else k))
+                for k in ("counter", "gauge", "status", "set", "histo")]
 
     def census_flush(self, table, now: float) -> None:
         """Flush-worker side: mark the detached interval's keys live and
