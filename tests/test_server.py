@@ -588,7 +588,8 @@ def test_per_flush_runtime_gauges(server):
     srv.trigger_flush()           # interval 1 emits the gauges
     want = {"veneur.worker.span_chan.total_elements",
             "veneur.worker.span_chan.total_capacity",
-            "veneur.gc.number", "veneur.mem.heap_alloc_bytes",
+            "veneur.gc.number", "veneur.gc.pause_total_ns",
+            "veneur.mem.heap_alloc_bytes",
             "veneur.flush.flush_timestamp_ns"}
     deadline = time.time() + 30
     got = {}
@@ -601,6 +602,7 @@ def test_per_flush_runtime_gauges(server):
     assert want <= set(got), sorted(got)
     assert got["veneur.worker.span_chan.total_capacity"] == 100.0
     assert got["veneur.mem.heap_alloc_bytes"] > 1e6
+    assert got["veneur.gc.pause_total_ns"] > 0
     assert got["veneur.flush.flush_timestamp_ns"] > 1e18
 
 

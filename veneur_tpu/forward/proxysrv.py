@@ -549,13 +549,14 @@ class ProxyServer:
         ReportRuntimeMetrics. The Go fields map to their CPython
         equivalents: HeapAlloc -> current resident set size (the
         live-memory measure a CPython process has), NumGC -> total
-        collections across gc generations. Go's PauseTotalNs has no
-        CPython counterpart (collections are not stop-the-world-timed)
-        and is deliberately not faked; gc.alloc_heap_bytes mirrors
+        collections across gc generations. Go's PauseTotalNs is left
+        out: the proxy loads no JAX and so not hostspans, whose
+        gc.callbacks entry times collections (runtime_gauges gives None
+        here), and it is not faked; gc.alloc_heap_bytes mirrors
         mem.heap_alloc_bytes exactly as the reference emits HeapAlloc
         under both names. Returns (name, value, type_char) tuples."""
         from veneur_tpu.utils.statsd_emit import runtime_gauges
-        rss, ngc = runtime_gauges()
+        rss, ngc, _pause = runtime_gauges()
         return [("mem.heap_alloc_bytes", rss, "g"),
                 ("gc.number", ngc, "g"),
                 ("gc.alloc_heap_bytes", rss, "g")]

@@ -31,6 +31,16 @@ INJECT_OK = 1
 INJECT_REJECTED = 0
 INJECT_BACKPRESSURE = -1
 
+# vr_stats / vrm_ring_stats, slot by slot: the ring, the packed emit, and
+# what the thread that parses the ring spends (dogstatsd.cpp PumpCounters:
+# blocked on an empty ring, the rest, and one datagram in 64 timed whole
+# and in its key lookups)
+RING_STATS = ("ring_depth", "ring_highwater", "pump_batches", "pump_stalls",
+              "emit_packed_calls", "emit_packed_ns", "datagrams",
+              "ring_dropped", "pump_wait_ns", "pump_busy_ns",
+              "parse_sampled_ns", "parse_key_sampled_ns",
+              "parse_sampled_datagrams")
+
 
 def _build_and_load():
     global _lib, _load_err
@@ -642,40 +652,25 @@ class NativeIngest:
 
     def ring_stats(self) -> dict:
         """Deep ring/emit telemetry snapshot, callable from any thread
-        (one C++ lock, no hot-path cost): ring depth + high-water, pump
-        batch/stall counts, emit_packed call/ns totals, datagram and
-        ring-drop totals. Zeros when no reader group is running. With the
-        multi-ring engine, counters are exact cross-ring sums and
-        ring_depth/ring_highwater aggregate as sum/max."""
-        m = getattr(self, "_rings", None)
-        if m:
-            agg = {"ring_depth": 0, "ring_highwater": 0,
-                   "pump_batches": 0, "pump_stalls": 0,
-                   "emit_packed_calls": 0, "emit_packed_ns": 0,
-                   "datagrams": 0, "ring_dropped": 0}
+        (one C++ lock, no hot-path cost), keyed as `RING_STATS`: ring
+        depth + high-water, pump batch/stall counts, emit_packed call/ns
+        totals, datagram and ring-drop totals, and the parsing thread's
+        wait, busy and sampled parse time. Zeros when no reader group is
+        running. With the multi-ring engine, counters are exact
+        cross-ring sums and ring_highwater is the per-ring max."""
+        if getattr(self, "_rings", None):
+            agg = dict.fromkeys(RING_STATS, 0)
             for per in self.ring_stats_per_ring():
-                agg["ring_depth"] += per["ring_depth"]
-                agg["ring_highwater"] = max(agg["ring_highwater"],
-                                            per["ring_highwater"])
-                agg["pump_batches"] += per["pump_batches"]
-                agg["pump_stalls"] += per["pump_stalls"]
-                agg["emit_packed_calls"] += per["emit_packed_calls"]
-                agg["emit_packed_ns"] += per["emit_packed_ns"]
-                agg["datagrams"] += per["datagrams"]
-                agg["ring_dropped"] += per["ring_dropped"]
+                for k in RING_STATS:
+                    agg[k] = (max(agg[k], per[k]) if k == "ring_highwater"
+                              else agg[k] + per[k])
             return agg
         r = getattr(self, "_readers", None)
         if not r:
-            return {"ring_depth": 0, "ring_highwater": 0,
-                    "pump_batches": 0, "pump_stalls": 0,
-                    "emit_packed_calls": 0, "emit_packed_ns": 0,
-                    "datagrams": 0, "ring_dropped": 0}
-        out = (ctypes.c_uint64 * 8)()
+            return dict.fromkeys(RING_STATS, 0)
+        out = (ctypes.c_uint64 * len(RING_STATS))()
         _lib.vr_stats(r, out)
-        return {"ring_depth": out[0], "ring_highwater": out[1],
-                "pump_batches": out[2], "pump_stalls": out[3],
-                "emit_packed_calls": out[4], "emit_packed_ns": out[5],
-                "datagrams": out[6], "ring_dropped": out[7]}
+        return dict(zip(RING_STATS, out))
 
     def admission_set(self, enabled: bool, state: int, rate: float,
                       burst: float, high_tags) -> None:
@@ -840,12 +835,9 @@ class NativeIngest:
 
     def ring_stats_one(self, ring: int) -> dict:
         """Per-ring deep telemetry (ring_stats layout)."""
-        out = (ctypes.c_uint64 * 8)()
+        out = (ctypes.c_uint64 * len(RING_STATS))()
         _lib.vrm_ring_stats(self._rings, ring, out)
-        return {"ring_depth": out[0], "ring_highwater": out[1],
-                "pump_batches": out[2], "pump_stalls": out[3],
-                "emit_packed_calls": out[4], "emit_packed_ns": out[5],
-                "datagrams": out[6], "ring_dropped": out[7]}
+        return dict(zip(RING_STATS, out))
 
     def ring_stats_per_ring(self) -> List[dict]:
         """ring_stats_one for every ring (empty when not multi-ring)."""

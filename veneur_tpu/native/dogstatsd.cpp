@@ -72,6 +72,16 @@ inline uint64_t fnv64(const char* p, size_t n) {
   return h;
 }
 
+inline uint64_t ns_between(std::chrono::steady_clock::time_point a,
+                           std::chrono::steady_clock::time_point b) {
+  return (uint64_t)std::chrono::duration_cast<std::chrono::nanoseconds>(b - a)
+      .count();
+}
+
+inline uint64_t ns_since(std::chrono::steady_clock::time_point t0) {
+  return ns_between(t0, std::chrono::steady_clock::now());
+}
+
 inline uint64_t rotr64(uint64_t x, int r) {
   return (x >> r) | (x << (64 - r));
 }
@@ -500,6 +510,11 @@ struct Parser {
   std::atomic<uint64_t> emit_packed_calls{0};
   std::atomic<uint64_t> emit_packed_ns{0};
 
+  // set by the pump around a sampled datagram (feed_datagram): while it
+  // is on, parse_line's key lookups add their time to key_ns
+  bool time_keys = false;
+  uint64_t key_ns = 0;
+
   // scratch
   std::vector<std::pair<const char*, size_t>> tag_views;
   std::string keybuf, joined;
@@ -588,6 +603,16 @@ struct Parser {
     }
     lk.unlock();
     if (master) local_cache.emplace(keybuf, slot);
+    return slot;
+  }
+
+  // slot_for as parse_line calls it: timed only in a sampled datagram
+  int32_t lookup(KindTable& t, uint8_t kind, uint8_t scope,
+                 const char* name, size_t name_len, uint32_t digest) {
+    if (!time_keys) return slot_for(t, kind, scope, name, name_len, digest);
+    auto t0 = std::chrono::steady_clock::now();
+    int32_t slot = slot_for(t, kind, scope, name, name_len, digest);
+    key_ns += ns_since(t0);
     return slot;
   }
 
@@ -755,7 +780,7 @@ struct Parser {
 
     switch (kind) {
       case K_COUNTER: {
-        int32_t slot = slot_for(rt().counters, kind, scope, name, name_len, h);
+        int32_t slot = lookup(rt().counters, kind, scope, name, name_len, h);
         if (slot < 0) return 0;
         c_slot[nc] = slot;
         c_inc[nc] = (float)(value_f * (1.0 / rate));
@@ -763,7 +788,7 @@ struct Parser {
         break;
       }
       case K_GAUGE: {
-        int32_t slot = slot_for(rt().gauges, kind, scope, name, name_len, h);
+        int32_t slot = lookup(rt().gauges, kind, scope, name, name_len, h);
         if (slot < 0) return 0;
         g_slot[ng] = slot;
         g_val[ng] = (float)value_f;
@@ -771,7 +796,7 @@ struct Parser {
         break;
       }
       case K_SET: {
-        int32_t slot = slot_for(rt().sets, kind, scope, name, name_len, h);
+        int32_t slot = lookup(rt().sets, kind, scope, name, name_len, h);
         if (slot < 0) return 0;
         uint64_t mh = metro64(value, value_len);
         uint32_t reg = (uint32_t)(mh >> (64 - hll_precision));
@@ -791,7 +816,7 @@ struct Parser {
       }
       case K_HISTO:
       case K_TIMER: {
-        int32_t slot = slot_for(rt().histos, kind, scope, name, name_len, h);
+        int32_t slot = lookup(rt().histos, kind, scope, name, name_len, h);
         if (slot < 0) return 0;
         h_slot[nh] = slot;
         h_val[nh] = (float)value_f;
@@ -1661,6 +1686,52 @@ struct Admission {
   std::unordered_map<int32_t, std::array<uint64_t, 6>> per_tenant;
 };
 
+// Where the thread that parses a ring spends its time, counted in the
+// engine (vr_stats / vrm_ring_stats): blocked on an empty ring (wait),
+// the rest of it (busy: parse, staging, the ring's lock), and one
+// datagram in kPumpSampleEvery timed whole and in its key lookups
+// (Parser::lookup), so the parse splits with no clock read a line.
+constexpr uint64_t kPumpSampleEvery = 64;
+
+struct PumpCounters {
+  std::atomic<uint64_t> wait_ns{0};
+  std::atomic<uint64_t> busy_ns{0};
+  std::atomic<uint64_t> sampled_ns{0};
+  std::atomic<uint64_t> sampled_key_ns{0};
+  std::atomic<uint64_t> sampled_datagrams{0};
+  uint64_t seen = 0;  // datagrams fed: the parsing thread's alone
+
+  void add(std::atomic<uint64_t>& a, uint64_t v) {
+    a.fetch_add(v, std::memory_order_relaxed);
+  }
+  // out[0..4]: wait, busy, sampled, of it key lookups, sampled datagrams
+  void read(uint64_t* out) const {
+    out[0] = wait_ns.load(std::memory_order_relaxed);
+    out[1] = busy_ns.load(std::memory_order_relaxed);
+    out[2] = sampled_ns.load(std::memory_order_relaxed);
+    out[3] = sampled_key_ns.load(std::memory_order_relaxed);
+    out[4] = sampled_datagrams.load(std::memory_order_relaxed);
+  }
+};
+
+// vt_feed of a datagram from its first byte, or of a parked one from
+// `start`; every kPumpSampleEvery-th datagram begun is timed.
+int feed_datagram(Parser* p, PumpCounters& pc, const char* data, int len,
+                  int start, int* consumed) {
+  if (start > 0 || pc.seen++ % kPumpSampleEvery)
+    return vt_feed(p, data, len, start, consumed);
+  p->time_keys = true;
+  p->key_ns = 0;
+  auto t0 = std::chrono::steady_clock::now();
+  int full = vt_feed(p, data, len, 0, consumed);
+  uint64_t ns = ns_since(t0);
+  p->time_keys = false;
+  pc.add(pc.sampled_ns, ns);
+  pc.add(pc.sampled_key_ns, p->key_ns);
+  pc.add(pc.sampled_datagrams, 1);
+  return full;
+}
+
 struct ReaderGroup {
   void* parser = nullptr;
   std::vector<std::thread> threads;
@@ -1677,6 +1748,7 @@ struct ReaderGroup {
   uint64_t pump_batches = 0;      // guarded by mu; vr_pump calls that parsed
   uint64_t pump_stalls = 0;       // guarded by mu; vr_pump forced a swap
   Admission adm;                  // guarded by mu
+  PumpCounters pump;              // the pipeline thread's, in vr_pump
   // datagram whose parse hit a full lane, parked whole with a resume
   // offset (no remainder copy)
   std::string tail;
@@ -1921,8 +1993,9 @@ void* vr_start(void* parser, const int* fds, int n_fds, int max_len,
 // out: [0]=datagrams parsed this call, [1]=ring depth now,
 //      [2]=ring_dropped total, [3]=datagrams received total.
 int vr_pump(void* gp, int max_wait_ms, uint64_t* out) {
+  auto t_in = std::chrono::steady_clock::now();
   auto* g = (ReaderGroup*)gp;
-  uint64_t parsed_dg = 0;
+  uint64_t parsed_dg = 0, wait_ns = 0;
   int full = 0;
   int consumed = 0;
   if (g->tail_off < g->tail.size()) {
@@ -1938,14 +2011,18 @@ int vr_pump(void* gp, int max_wait_ms, uint64_t* out) {
   while (!full) {
     {
       std::unique_lock<std::mutex> lk(g->mu);
-      if (g->ring.empty() && parsed_dg == 0 && max_wait_ms > 0)
+      if (g->ring.empty() && parsed_dg == 0 && max_wait_ms > 0) {
+        auto t0 = std::chrono::steady_clock::now();
         g->cv.wait_for(lk, std::chrono::milliseconds(max_wait_ms));
+        wait_ns += ns_since(t0);
+      }
       if (g->ring.empty()) break;
       local = std::move(g->ring.front());
       g->ring.pop_front();
     }
     parsed_dg++;
-    full = vt_feed(g->parser, local.data(), (int)local.size(), 0, &consumed);
+    full = feed_datagram((Parser*)g->parser, g->pump, local.data(),
+                         (int)local.size(), 0, &consumed);
     if (full) {
       // park the whole datagram with a resume offset — no remainder copy
       g->tail = std::move(local);
@@ -1959,6 +2036,8 @@ int vr_pump(void* gp, int max_wait_ms, uint64_t* out) {
     out[3] = g->datagrams;
     if (parsed_dg > 0) g->pump_batches++;
     if (full) g->pump_stalls++;  // staging lane filled: forced buffer swap
+    g->pump.add(g->pump.wait_ns, wait_ns);
+    g->pump.add(g->pump.busy_ns, ns_since(t_in) - wait_ns);
   }
   out[0] = parsed_dg;
   return full;
@@ -2004,8 +2083,10 @@ void vr_counters(void* gp, uint64_t* out) {
 // [0]=ring depth now, [1]=ring depth high-water, [2]=pump batches (vr_pump
 // calls that parsed >=1 datagram), [3]=buffer-swap stalls (vr_pump returned
 // full), [4]=emit_packed calls, [5]=emit_packed ns total, [6]=datagrams
-// received, [7]=ring_dropped. Per-class admission is NOT repeated here —
-// vr_admission_counters already drains it exactly.
+// received, [7]=ring_dropped, [8..12]=the pump's PumpCounters (wait ns,
+// busy ns, sampled parse ns, of it key lookups, sampled datagrams).
+// Per-class admission is NOT repeated here — vr_admission_counters
+// already drains it exactly.
 void vr_stats(void* gp, uint64_t* out) {
   auto* g = (ReaderGroup*)gp;
   {
@@ -2020,6 +2101,7 @@ void vr_stats(void* gp, uint64_t* out) {
   auto* p = (Parser*)g->parser;
   out[4] = p->emit_packed_calls.load(std::memory_order_relaxed);
   out[5] = p->emit_packed_ns.load(std::memory_order_relaxed);
+  g->pump.read(out + 8);
 }
 
 void vr_stop(void* gp) {
@@ -2082,6 +2164,7 @@ struct Ring {
   uint64_t parse_batches = 0;    // guarded by mu; datagrams parsed
   uint64_t stalls = 0;           // guarded by mu; staging filled mid-parse
   Admission adm;                 // guarded by mu
+  PumpCounters pump;             // the worker's
   std::atomic<bool> stalled{false};
   std::mutex stage_mu;           // staging lanes: worker parse vs emit
 };
@@ -2196,17 +2279,25 @@ void vrm_reader_main(MultiRing* mr, Ring* r) {
 // Per-ring parse loop: pop one datagram, parse it into this ring's staging
 // under stage_mu (held only for the parse itself). A full staging lane
 // parks the datagram with its resume offset and waits for the pipeline to
-// emit; the swap-boundary pause parks it the same way.
+// emit; the swap-boundary pause parks it the same way. Its time counts
+// as vr_pump's does: waits on an empty ring, and busy, the stretches
+// between its waits; a wait for an emit or a resume counts as neither.
 void vrm_worker_main(MultiRing* mr, Ring* r) {
   pin_self(r->pin_core);
   Dgram local;
   size_t off = 0;
   bool have = false;
+  auto busy_from = std::chrono::steady_clock::now();
   while (!mr->stop.load(std::memory_order_relaxed)) {
     if (!have) {
       std::unique_lock<std::mutex> lk(r->mu);
-      if (r->ring.empty())
+      if (r->ring.empty()) {
+        auto t0 = std::chrono::steady_clock::now();
+        r->pump.add(r->pump.busy_ns, ns_between(busy_from, t0));
         r->cv.wait_for(lk, std::chrono::milliseconds(100));
+        busy_from = std::chrono::steady_clock::now();
+        r->pump.add(r->pump.wait_ns, ns_between(t0, busy_from));
+      }
       if (mr->stop.load(std::memory_order_relaxed)) break;
       if (r->ring.empty() || mr->pause.load(std::memory_order_relaxed))
         continue;
@@ -2230,8 +2321,9 @@ void vrm_worker_main(MultiRing* mr, Ring* r) {
         r->parser.cur_demoted =
             local.te && local.te->demoted.load(std::memory_order_relaxed);
         int consumed = 0;
-        full = vt_feed(&r->parser, local.data.data(),
-                       (int)local.data.size(), (int)off, &consumed) != 0;
+        full = feed_datagram(&r->parser, r->pump, local.data.data(),
+                             (int)local.data.size(), (int)off,
+                             &consumed) != 0;
         off = (size_t)consumed;
         if (!full) have = false;
         parsed = true;
@@ -2259,11 +2351,13 @@ void vrm_worker_main(MultiRing* mr, Ring* r) {
     }
     // stalled (wait for an emit) or paused (wait for resume)
     std::unique_lock<std::mutex> lk(r->mu);
+    r->pump.add(r->pump.busy_ns, ns_since(busy_from));
     r->space_cv.wait_for(lk, std::chrono::milliseconds(50), [&] {
       return mr->stop.load(std::memory_order_relaxed) ||
              (!mr->pause.load(std::memory_order_relaxed) &&
               !r->stalled.load(std::memory_order_acquire));
     });
+    busy_from = std::chrono::steady_clock::now();
   }
 }
 
@@ -2474,7 +2568,8 @@ void vrm_counters(void* h, int ring, uint64_t* out) {
 
 // Per-ring deep telemetry (vr_stats layout): [0]=ring depth, [1]=depth
 // high-water, [2]=parse batches (datagrams parsed), [3]=staging stalls,
-// [4]=emit calls, [5]=emit ns, [6]=datagrams received, [7]=ring_dropped.
+// [4]=emit calls, [5]=emit ns, [6]=datagrams received, [7]=ring_dropped,
+// [8..12]=the worker's PumpCounters.
 void vrm_ring_stats(void* h, int ring, uint64_t* out) {
   auto* mr = (MultiRing*)h;
   Ring* r = mr->rings[ring].get();
@@ -2489,6 +2584,7 @@ void vrm_ring_stats(void* h, int ring, uint64_t* out) {
   }
   out[4] = r->parser.emit_packed_calls.load(std::memory_order_relaxed);
   out[5] = r->parser.emit_packed_ns.load(std::memory_order_relaxed);
+  r->pump.read(out + 8);
 }
 
 // Push controller admission knobs to every ring. The aggregate token rate

@@ -17,6 +17,14 @@ repeats faster than that (the pump loop) is recorded as a run (`run_call`
 / `run_returned`): one record per run of consecutive calls, with their
 number.
 
+The interpreter's collections are the one stop of the whole interpreter
+that no span marks, so a `gc.callbacks` entry, registered at import,
+marks them: every collection adds its pause to `gc_totals()`; a full
+collection (generation 2) enters a `gc.collect` annotation on the
+profiler's clock, and it and any collection of 1 ms or more leave a
+`gc.collect` record on the collecting thread, under that thread's open
+span, with no interval number.
+
 Like jaxruntime's compile counters the store is process-global: several
 Server instances in one process (the test suite) share it, and the
 records of one interval are told apart by `seq`, the interval's number
@@ -28,6 +36,7 @@ detached one's).
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import threading
 import time
@@ -216,6 +225,51 @@ def record(name: str, start_ns: int, end_ns: int,
     _end_run(st)
     parent, seq = _enclosing(st, seq)
     _append(name, seq, st, start_ns, end_ns, parent, next(_index), tag)
+
+
+# A young collection leaves a record only if it stopped the interpreter
+# this long: on the chip's host most take 0.1-0.7 ms (PERF.md, PR 41).
+GC_RECORD_NS = 1_000_000
+GC_COLLECT = "gc.collect"
+
+_gc_count = [0, 0, 0]        # collections by generation
+_gc_ns = [0, 0, 0]           # their pauses, ns
+_gc_t0 = 0                   # the collection under way: its start,
+_gc_ann = None               # and a full one's annotation
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """gc.callbacks: called on the collecting thread, interpreter held,
+    around each collection (never two at once)."""
+    global _gc_t0, _gc_ann
+    gen = info["generation"]
+    if phase == "start":
+        if gen == 2:
+            _gc_ann = TraceAnnotation(GC_COLLECT)
+            _gc_ann.__enter__()
+        _gc_t0 = time.monotonic_ns()
+        return
+    end = time.monotonic_ns()
+    if _gc_ann is not None:
+        _gc_ann.__exit__(None, None, None)
+        _gc_ann = None
+    _gc_count[gen] += 1
+    _gc_ns[gen] += end - _gc_t0
+    if gen == 2 or end - _gc_t0 >= GC_RECORD_NS:
+        # appended, not `record`ed: a collection in the glue between two
+        # pump calls must not end the thread's open run
+        st = _state()
+        _append(GC_COLLECT, None, st, _gc_t0, end,
+                st.stack[-1] if st.stack else None, next(_index),
+                (gen, info["collected"]))
+
+
+gc.callbacks.append(_on_gc)
+
+
+def gc_totals() -> List[tuple]:
+    """(collections, pause ns) of each generation, 0 to 2, since import."""
+    return list(zip(_gc_count, _gc_ns))
 
 
 def records() -> List[Record]:

@@ -899,6 +899,16 @@ class Server:
                        self._ring_stats().get("emit_packed_ns", 0)),
                    kind="counter",
                    help="wall time inside C++ vt_emit_packed")
+        M.callback("veneur.ring.pump_busy_ns_total",
+                   lambda: float(self._ring_stats().get("pump_busy_ns", 0)),
+                   kind="counter",
+                   help="time the ring's parsing thread spent parsing, "
+                        "staging and locking (its pump calls less waits)")
+        M.callback("veneur.ring.pump_wait_ns_total",
+                   lambda: float(self._ring_stats().get("pump_wait_ns", 0)),
+                   kind="counter",
+                   help="time the ring's parsing thread waited on an "
+                        "empty ring")
         # the native key table across intervals (NativeIngest.key_counters,
         # added up at each swap): how often its persistence engages
         M.callback("veneur.swap.keys_live_total",
@@ -3180,9 +3190,9 @@ class Server:
                "veneur.worker.span.hit_chan_cap":
                    stats.get("span_chan_cap_hits", 0)}
         # per-flush runtime gauges (flusher.go:36-43: span-chan depth,
-        # GC count, heap bytes, flush timestamp)
+        # GC count and pause, heap bytes, flush timestamp)
         from veneur_tpu.utils.statsd_emit import runtime_gauges
-        rss, ngc = runtime_gauges()
+        rss, ngc, gc_pause_ns = runtime_gauges()
         samples = [ssf_samples.timing("veneur.flush.total_duration_ns",
                                       flush_seconds),
                    ssf_samples.gauge("veneur.flush.metrics_total",
@@ -3194,6 +3204,8 @@ class Server:
                        "veneur.worker.span_chan.total_capacity",
                        float(self.span_pipeline.chan.maxsize)),
                    ssf_samples.gauge("veneur.gc.number", ngc),
+                   ssf_samples.gauge("veneur.gc.pause_total_ns",
+                                     gc_pause_ns),
                    ssf_samples.gauge("veneur.mem.heap_alloc_bytes", rss),
                    ssf_samples.gauge("veneur.flush.flush_timestamp_ns",
                                      float(time.time() * 1e9)),

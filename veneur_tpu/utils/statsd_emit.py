@@ -58,11 +58,18 @@ def current_rss_bytes() -> float:
 
 
 def runtime_gauges() -> tuple:
-    """(rss_bytes, total_gc_collections) — the ONE place the "CPython
-    equivalent of Go's HeapAlloc/NumGC" mapping lives (reference
-    flusher.go:36-43 and proxy.go:656 both report these; Go's
-    PauseTotalNs has no CPython counterpart — collections are not
-    stop-the-world-timed — and is deliberately not faked)."""
+    """(rss_bytes, total_gc_collections, gc_pause_total_ns) — the ONE
+    place the "CPython equivalent of Go's HeapAlloc/NumGC/PauseTotalNs"
+    mapping lives (reference flusher.go:36-43 and proxy.go:656 report
+    these). The pause is what `observability.hostspans`' gc.callbacks
+    entry timed: each collection from its `start` to its `stop`
+    callback, on the collecting thread, the interpreter held throughout.
+    None in a process that never imported hostspans (the proxy loads no
+    JAX), where nothing timed a collection: not faked as 0."""
     import gc
+    import sys
+    spans = sys.modules.get("veneur_tpu.observability.hostspans")
+    pause = (None if spans is None
+             else float(sum(ns for _n, ns in spans.gc_totals())))
     return (current_rss_bytes(),
-            float(sum(s["collections"] for s in gc.get_stats())))
+            float(sum(s["collections"] for s in gc.get_stats())), pause)
