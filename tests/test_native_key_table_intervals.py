@@ -224,6 +224,29 @@ def test_capacity_is_counted_in_the_intervals_own_keys(pair):
     assert evicted == SPEC.counter_capacity
 
 
+def test_a_seeded_stream_that_churns_past_capacity(pair):
+    """Keys of every kind come, go and return over five intervals, more
+    of them than the tables hold, on short and long names: the rows are
+    the flush-scoped table's, interval by interval, drops included."""
+    rng = np.random.default_rng(42 + pair.n_shards)
+    kinds = ("c", "g", "s", "ms", "h")
+    for k in range(5):
+        ids = rng.integers(8 * k, 8 * k + 40, size=120)
+        lines = []
+        for i, key in enumerate(ids.tolist()):
+            name = f"ch{key}" if key % 2 else f"churn.service.latency.{key}"
+            tags = ("", "|#az:b,env:p", "|#veneurlocalonly,t:1")[key % 3]
+            value = f"m{i % 5}" if kinds[key % 5] == "s" else str(1 + i % 7)
+            lines.append(f"{name}:{value}|{kinds[key % 5]}{tags}".encode())
+        for j in range(0, len(lines), 12):
+            pair.send(*lines[j:j + 12])
+        assert pair.nat.dropped_capacity == pair.py.dropped_capacity
+        py, nat, _ = pair.flush()
+        assert nat == py
+    assert pair.py.dropped_capacity > 0
+    assert key_counters(pair)[2] > 0    # evicted
+
+
 def test_a_key_that_returns_with_another_scope(pair):
     pair.send(b"sc:1|c|#veneurlocalonly,a:1", b"sc.t:1|ms|#veneurglobalonly")
     py1, nat1, table1 = pair.flush()

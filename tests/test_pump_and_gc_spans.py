@@ -1,9 +1,10 @@
 """The host's time named where the work happens: the engine's pump
 counters (dogstatsd.cpp PumpCounters, read through `ring_stats()`), the
 interpreter's collections as `gc.collect` records and totals
-(observability/hostspans.py), and the five per-layer readers built on
-them (perfbench/layer_metrics/: pump_busy_share, pump_wait_share,
-parse_key_share, gc_window_share, gc_tick_ms) on hand-made inputs."""
+(observability/hostspans.py), and the per-layer readers built on them
+(perfbench/layer_metrics/: pump_busy_share, pump_wait_share,
+parse_key_share, key_probes_per_lookup, gc_window_share, gc_tick_ms) on
+hand-made inputs."""
 
 import gc
 import os
@@ -26,7 +27,8 @@ SPEC = TableSpec(counter_capacity=4096, gauge_capacity=64,
                  status_capacity=16, set_capacity=32, histo_capacity=64)
 BSPEC = BatchSpec(counter=8192, gauge=128, status=16, set=64, histo=256)
 PUMP_KEYS = ("pump_wait_ns", "pump_busy_ns", "parse_sampled_ns",
-             "parse_key_sampled_ns", "parse_sampled_datagrams")
+             "parse_key_sampled_ns", "parse_sampled_datagrams",
+             "key_lookups_sampled", "key_probes_sampled")
 needs_engine = pytest.mark.skipif(not native.available(),
                                   reason="native engine not buildable")
 
@@ -81,6 +83,9 @@ def test_pump_counts_busy_and_samples_one_datagram_in_64(reader):
     assert st["parse_sampled_datagrams"] == 3
     assert 0 < st["parse_key_sampled_ns"] <= st["parse_sampled_ns"]
     assert st["parse_sampled_ns"] <= st["pump_busy_ns"]
+    # their 60 lines' lookups, each reading at least its key's home entry
+    assert st["key_lookups_sampled"] == 3 * 20
+    assert st["key_probes_sampled"] >= st["key_lookups_sampled"]
 
 
 @needs_engine
@@ -120,6 +125,10 @@ def test_multi_ring_workers_count_the_same_summed_across_rings():
         for r in per:
             assert r["pump_wait_ns"] > 0 and r["pump_busy_ns"] > 0
             assert 0 < r["parse_key_sampled_ns"] <= r["parse_sampled_ns"]
+            # two lines a sampled datagram; a key new to the ring reads
+            # its replica, then the master's index
+            assert r["key_lookups_sampled"] == 4
+            assert r["key_probes_sampled"] >= 2 * r["key_lookups_sampled"]
     finally:
         eng.readers_stop()
 
@@ -256,6 +265,10 @@ def _ctx(start=None, end=None, window_ms=2000):
      {"ring.parse_sampled_ns": 1000, "ring.parse_key_sampled_ns": 300},
      {"ring.parse_sampled_ns": 5000, "ring.parse_key_sampled_ns": 1500},
      30.0),
+    ("key_probes_per_lookup",
+     {"ring.key_lookups_sampled": 10, "ring.key_probes_sampled": 12},
+     {"ring.key_lookups_sampled": 410, "ring.key_probes_sampled": 452},
+     1.1),
 ])
 def test_counter_ratio_metrics(bench, metric, start, end, want):
     readers, _ = bench
@@ -263,7 +276,8 @@ def test_counter_ratio_metrics(bench, metric, start, end, want):
 
 
 @pytest.mark.parametrize("metric", ["pump_busy_share", "pump_wait_share",
-                                    "parse_key_share"])
+                                    "parse_key_share",
+                                    "key_probes_per_lookup"])
 def test_counter_ratio_metrics_absent_without_the_counters(bench, metric):
     readers, _ = bench
     assert readers.read(metric, _ctx()) is None

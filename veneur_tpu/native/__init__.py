@@ -34,12 +34,14 @@ INJECT_BACKPRESSURE = -1
 # vr_stats / vrm_ring_stats, slot by slot: the ring, the packed emit, and
 # what the thread that parses the ring spends (dogstatsd.cpp PumpCounters:
 # blocked on an empty ring, the rest, and one datagram in 64 timed whole
-# and in its key lookups)
+# and in its key lookups, whose number and key-index entries read it
+# counts)
 RING_STATS = ("ring_depth", "ring_highwater", "pump_batches", "pump_stalls",
               "emit_packed_calls", "emit_packed_ns", "datagrams",
               "ring_dropped", "pump_wait_ns", "pump_busy_ns",
               "parse_sampled_ns", "parse_key_sampled_ns",
-              "parse_sampled_datagrams")
+              "parse_sampled_datagrams", "key_lookups_sampled",
+              "key_probes_sampled")
 
 
 def _build_and_load():
@@ -655,7 +657,8 @@ class NativeIngest:
         (one C++ lock, no hot-path cost), keyed as `RING_STATS`: ring
         depth + high-water, pump batch/stall counts, emit_packed call/ns
         totals, datagram and ring-drop totals, and the parsing thread's
-        wait, busy and sampled parse time. Zeros when no reader group is
+        wait, busy and sampled parse time, with the sampled key lookups
+        and the key-index entries they read. Zeros when no reader group is
         running. With the multi-ring engine, counters are exact
         cross-ring sums and ring_highwater is the per-ring max."""
         if getattr(self, "_rings", None):

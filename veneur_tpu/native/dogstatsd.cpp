@@ -309,6 +309,109 @@ inline bool tenant_allow(TenantTable& tt, TenantEntry& e,
   return false;
 }
 
+// Keys to slots: open addressing with linear probing over a power of two
+// of 16-byte entries (the key's 64-bit hash, its slot, the interval that
+// last touched it), sized from the table's capacity so that it is never
+// more than half full. The key bytes live in an arena indexed by slot and
+// are compared in place on every hash match: equal hashes are never
+// trusted alone. An erase shifts the rest of its run back, so there are
+// no tombstones and a probe ends at the first empty entry.
+struct KeyIndex {
+  struct Entry {
+    uint64_t hash;
+    int32_t slot;    // -1: empty
+    uint32_t stamp;  // the interval that last touched the key (0: none)
+  };
+  // a slot's key in `arena` (len 0: the slot holds none) and its stamp,
+  // which the eviction sweep reads by slot
+  struct Bytes {
+    uint64_t off;
+    uint32_t len;
+    uint32_t stamp;
+  };
+  std::vector<Entry> entries;
+  size_t mask = 0;
+  std::vector<Bytes> at;
+  std::string arena;
+  size_t dead = 0;  // arena bytes of erased keys
+
+  static uint64_t hash(const char* k, size_t n) { return metro64(k, n, 0); }
+
+  // empty, with room for `capacity` keys at a load of at most one half
+  void reset(uint32_t capacity) {
+    size_t n = 16;
+    while (n < 2 * (size_t)capacity) n <<= 1;
+    entries.assign(n, Entry{0, -1, 0});
+    mask = n - 1;
+    at.clear();
+    arena.clear();
+    dead = 0;
+  }
+
+  bool holds(uint32_t slot) const { return slot < at.size() && at[slot].len; }
+  uint32_t stamp_of(uint32_t slot) const { return at[slot].stamp; }
+
+  // The entry that holds the key, or the empty entry that ends its run;
+  // *probes counts the entries read.
+  size_t probe(uint64_t h, const char* k, size_t n, uint32_t* probes) const {
+    for (size_t i = h & mask;; i = (i + 1) & mask) {
+      ++*probes;
+      const Entry& e = entries[i];
+      if (e.slot < 0) return i;
+      if (e.hash == h) {
+        const Bytes& b = at[e.slot];
+        if (b.len == n && memcmp(arena.data() + b.off, k, n) == 0) return i;
+      }
+    }
+  }
+
+  // the key into the empty entry i that probe() returned for it
+  void insert(size_t i, uint64_t h, int32_t slot, const char* k, size_t n) {
+    entries[i] = Entry{h, slot, 0};
+    if ((size_t)slot >= at.size()) at.resize((size_t)slot + 1, Bytes{0, 0, 0});
+    if (dead > (1u << 20) && dead > arena.size() / 2) compact();
+    at[slot] = Bytes{arena.size(), (uint32_t)n, 0};
+    arena.append(k, n);
+  }
+
+  void stamp(size_t i, uint32_t interval) {
+    entries[i].stamp = interval;
+    at[entries[i].slot].stamp = interval;
+  }
+
+  void erase(int32_t slot) {
+    Bytes& b = at[slot];
+    size_t i = hash(arena.data() + b.off, b.len) & mask;
+    while (entries[i].slot != slot) i = (i + 1) & mask;
+    // backward shift: a later entry of the run moves into the hole unless
+    // its home lies after the hole
+    for (size_t j = i;;) {
+      j = (j + 1) & mask;
+      if (entries[j].slot < 0) break;
+      if (((j - entries[j].hash) & mask) >= ((j - i) & mask)) {
+        entries[i] = entries[j];
+        i = j;
+      }
+    }
+    entries[i].slot = -1;
+    dead += b.len;
+    b.len = 0;
+  }
+
+  void compact() {
+    std::string packed;
+    packed.reserve(arena.size() - dead);
+    for (Bytes& b : at) {
+      if (!b.len) continue;
+      uint64_t off = packed.size();
+      packed.append(arena, b.off, b.len);
+      b.off = off;
+    }
+    arena.swap(packed);
+    dead = 0;
+  }
+};
+
 // One kind's key table. It outlives the flush interval: a key keeps its
 // slot from interval to interval, and what an interval owns is the LIVE
 // LIST, the slots touched in it in first-arrival order. Capacity is
@@ -321,16 +424,14 @@ struct KindTable {
   uint32_t capacity = 0;
   uint32_t n_shards = 1;
   uint32_t per_shard = 0;
-  std::unordered_map<std::string, int32_t> by_key;
+  KeyIndex index;
   std::vector<uint32_t> next_free;      // per shard: local slots handed out
   std::vector<std::vector<uint32_t>> evicted_free;  // per shard: reusable
   std::vector<uint32_t> live_in_shard;  // per shard: touched this interval
   // per slot, grown as slots are handed out (a ring parser's own tables
-  // hand out none): the interval it was last touched in, the scope (bit 7:
-  // imported) of that interval's first arrival, and its key in by_key
-  std::vector<uint32_t> seen;
+  // hand out none): the scope (bit 7: imported) of the interval's first
+  // arrival
   std::vector<uint8_t> first;
-  std::vector<const std::string*> key_of;
   std::vector<int32_t> live;  // slots touched this interval, arrival order
   uint32_t interval = 1;
   uint32_t new_keys = 0, evicted = 0;  // this interval's
@@ -341,13 +442,11 @@ struct KindTable {
     capacity = cap;
     n_shards = shards;
     per_shard = cap / shards;
-    by_key.clear();
+    index.reset(cap);
     next_free.assign(shards, 0);
     evicted_free.assign(shards, {});
     live_in_shard.assign(shards, 0);
-    seen.clear();
     first.clear();
-    key_of.clear();
     live.clear();
   }
 
@@ -359,17 +458,18 @@ struct KindTable {
     new_keys = evicted = 0;
   }
 
-  bool touched(int32_t slot) const { return seen[slot] == interval; }
-
-  void touch(int32_t slot, uint8_t scope_imported) {
-    seen[slot] = interval;
+  // the key at index entry i, first arrived in this interval
+  void touch(size_t i, uint8_t scope_imported) {
+    int32_t slot = index.entries[i].slot;
+    index.stamp(i, interval);
     first[slot] = scope_imported;
     live.push_back(slot);
     live_in_shard[(uint32_t)slot / per_shard]++;
   }
 
-  // A slot of digest's shard for a key by_key does not hold, or -1 when
-  // every slot of the shard was touched in this interval.
+  // A slot of digest's shard for a key the index does not hold, or -1
+  // when every slot of the shard was touched in this interval. Its sweep
+  // erases keys from the index, which moves entries.
   int32_t allocate(uint32_t digest) {
     uint32_t shard = digest % n_shards;
     if (live_in_shard[shard] >= per_shard) {
@@ -386,9 +486,8 @@ struct KindTable {
         // not touched (there is one: live_in_shard < per_shard)
         for (uint32_t l = per_shard; l-- > 0;) {
           uint32_t s = base + l;
-          if (seen[s] == interval || !key_of[s]) continue;
-          by_key.erase(by_key.find(*key_of[s]));
-          key_of[s] = nullptr;
+          if (!index.holds(s) || index.stamp_of(s) == interval) continue;
+          index.erase((int32_t)s);
           fr.push_back(l);
           evicted++;
         }
@@ -397,13 +496,10 @@ struct KindTable {
       fr.pop_back();
     }
     uint32_t slot = base + local;
-    if (slot >= seen.size()) {
-      size_t n = std::min<size_t>(capacity,
-                                  std::max<size_t>(slot + 1, seen.size() * 2));
-      seen.resize(n, 0);
-      first.resize(n, 0);
-      key_of.resize(n, nullptr);
-    }
+    if (slot >= first.size())
+      first.resize(std::min<size_t>(
+                       capacity, std::max<size_t>(slot + 1, first.size() * 2)),
+                   0);
     new_keys++;
     return (int32_t)slot;
   }
@@ -450,19 +546,28 @@ struct Parser {
   // Multi-ring sharing: ring parsers keep their own staging lanes and
   // scratch but route every key-table/new-key/special access to the
   // master parser so all rings share ONE slot space. Steady-state lookups
-  // are served from a ring-local replica cache with no lock at all; the
-  // shared table is touched only on cache miss (shared lock) and on a
+  // are served from a ring-local replica with no lock at all: the index of
+  // the ring parser's own table of the kind, which holds the keys this
+  // ring has seen touched in the interval (vrm_reset empties it). The
+  // shared table is touched only on a replica miss (shared lock) and on a
   // key's first arrival in the interval (unique lock, once per key per
   // flush interval).
   Parser* master = nullptr;
   std::shared_mutex key_mu;                          // tables + new_keys
   std::mutex specials_mu;                            // specials deque
-  std::unordered_map<std::string, int32_t> local_cache;
 
   Parser& rt() { return master ? *master : *this; }
   // in vt_live_keys / vt_table_stats order
   std::array<KindTable*, 4> tables() {
     return {&counters, &gauges, &sets, &histos};
+  }
+  KindTable& table(uint8_t kind) {
+    switch (kind) {
+      case K_COUNTER: return counters;
+      case K_GAUGE: return gauges;
+      case K_SET: return sets;
+      default: return histos;
+    }
   }
 
   // Multi-tenant identity (master only; rings route via rt()). The
@@ -511,9 +616,10 @@ struct Parser {
   std::atomic<uint64_t> emit_packed_ns{0};
 
   // set by the pump around a sampled datagram (feed_datagram): while it
-  // is on, parse_line's key lookups add their time to key_ns
+  // is on, parse_line's key lookups add their time to key_ns and count
+  // themselves and the index entries they read
   bool time_keys = false;
-  uint64_t key_ns = 0;
+  uint64_t key_ns = 0, key_lookups = 0, key_probes = 0;
 
   // scratch
   std::vector<std::pair<const char*, size_t>> tag_views;
@@ -540,50 +646,84 @@ struct Parser {
     return nc >= bc || ng >= bg || ns >= bs || nh >= bh;
   }
 
-  // `t` must be a table of rt() — callers route through rt().counters etc.
-  int32_t slot_for(KindTable& t, uint8_t kind, uint8_t scope,
-                   const char* name, size_t name_len, uint32_t digest) {
-    // key = kind byte + name + '\x1f' + joined tags (joined is in `joined`)
-    keybuf.clear();
-    keybuf.push_back((char)kind);
-    keybuf.append(name, name_len);
-    keybuf.push_back('\x1f');
-    keybuf.append(joined);
+  // The slot of one key: `key` holds its bytes (the kind byte, the name,
+  // '\x1f', the joined tags) and `h` their KeyIndex::hash. `held` is the
+  // caller's shared lock on key_mu where it holds one for a whole
+  // datagram: a first arrival releases it for the unique lock and takes it
+  // again. Without one a lookup in the master's table takes its own.
+  int32_t slot_for(uint8_t kind, uint8_t scope, const char* key,
+                   size_t key_len, size_t name_len, uint64_t h,
+                   uint32_t digest,
+                   std::shared_lock<std::shared_mutex>* held) {
+    uint32_t probes = 0;
+    int32_t slot;
     if (master) {
-      // lock-free hot path: the ring-local replica. vrm_reset clears
-      // these under quiesce, so a hit here is a slot this ring has
-      // already seen touched in this interval.
-      auto cit = local_cache.find(keybuf);
-      if (cit != local_cache.end()) return cit->second;
-    }
-    Parser& m = rt();
-    {
-      std::shared_lock<std::shared_mutex> lk(m.key_mu);
-      auto it = t.by_key.find(keybuf);
-      if (it != t.by_key.end() && t.touched(it->second)) {
-        int32_t slot = it->second;
-        lk.unlock();
-        if (master) local_cache.emplace(keybuf, slot);
-        return slot;
+      // lock-free hot path: the ring-local replica, emptied by vrm_reset
+      // under quiesce, so a hit is a slot this ring has already seen
+      // touched in this interval
+      KeyIndex& rep = table(kind).index;
+      size_t i = rep.probe(h, key, key_len, &probes);
+      slot = rep.entries[i].slot;
+      if (slot < 0) {
+        slot = from_master(kind, scope, key, key_len, name_len, h, digest,
+                           nullptr, &probes);
+        if (slot >= 0) rep.insert(i, h, slot, key, key_len);
       }
+    } else {
+      slot = from_master(kind, scope, key, key_len, name_len, h, digest,
+                         held, &probes);
+    }
+    if (time_keys) {
+      key_lookups++;
+      key_probes += probes;
+    }
+    return slot;
+  }
+
+  int32_t from_master(uint8_t kind, uint8_t scope, const char* key,
+                      size_t key_len, size_t name_len, uint64_t h,
+                      uint32_t digest,
+                      std::shared_lock<std::shared_mutex>* held,
+                      uint32_t* probes) {
+    Parser& m = rt();
+    KindTable& t = m.table(kind);
+    {
+      std::shared_lock<std::shared_mutex> lk(m.key_mu, std::defer_lock);
+      if (!held) lk.lock();
+      const KeyIndex::Entry& e =
+          t.index.entries[t.index.probe(h, key, key_len, probes)];
+      if (e.slot >= 0 && e.stamp == t.interval) return e.slot;
     }
     // the key's first arrival in this interval: once a key an interval
-    std::unique_lock<std::shared_mutex> lk(m.key_mu);
+    if (held) held->unlock();
     int32_t slot;
-    auto it = t.by_key.find(keybuf);
-    if (it != t.by_key.end()) {
-      slot = it->second;
-    } else {
-      slot = t.allocate(digest);
-      if (slot < 0) return -1;
-      it = t.by_key.emplace(keybuf, slot).first;
-      t.key_of[slot] = &it->first;
-      m.new_keys.push_back(NewKey{kind, slot, scope,
-                                  (uint8_t)(alloc_imported ? 1 : 0),
-                                  std::string(name, name_len), joined});
+    {
+      std::unique_lock<std::shared_mutex> lk(m.key_mu);
+      slot = first_arrival(m, t, kind, scope, key, key_len, name_len, h,
+                           digest);
     }
-    if (!t.touched(slot)) {
-      t.touch(slot, (uint8_t)(scope | (alloc_imported ? 0x80 : 0)));
+    if (held) held->lock();
+    return slot;
+  }
+
+  // under the unique lock: find or allocate the key, and touch it
+  int32_t first_arrival(Parser& m, KindTable& t, uint8_t kind, uint8_t scope,
+                        const char* key, size_t key_len, size_t name_len,
+                        uint64_t h, uint32_t digest) {
+    uint32_t probes = 0;
+    size_t i = t.index.probe(h, key, key_len, &probes);
+    if (t.index.entries[i].slot < 0) {
+      int32_t slot = t.allocate(digest);
+      if (slot < 0) return -1;
+      i = t.index.probe(h, key, key_len, &probes);  // the sweep moves entries
+      t.index.insert(i, h, slot, key, key_len);
+      m.new_keys.push_back(NewKey{
+          kind, slot, scope, (uint8_t)(alloc_imported ? 1 : 0),
+          std::string(key + 1, name_len),
+          std::string(key + 2 + name_len, key_len - 2 - name_len)});
+    }
+    if (t.index.entries[i].stamp != t.interval) {
+      t.touch(i, (uint8_t)(scope | (alloc_imported ? 0x80 : 0)));
       // tag-explosion detector: every distinct key of the interval
       // charges the owning tenant's window counter; crossing the budget
       // demotes it (subsequent datagrams collapse onto rollup keys
@@ -601,19 +741,21 @@ struct Parser {
           cur_entry->demoted.store(true, std::memory_order_relaxed);
       }
     }
-    lk.unlock();
-    if (master) local_cache.emplace(keybuf, slot);
-    return slot;
+    return t.index.entries[i].slot;
   }
 
-  // slot_for as parse_line calls it: timed only in a sampled datagram
-  int32_t lookup(KindTable& t, uint8_t kind, uint8_t scope,
-                 const char* name, size_t name_len, uint32_t digest) {
-    if (!time_keys) return slot_for(t, kind, scope, name, name_len, digest);
-    auto t0 = std::chrono::steady_clock::now();
-    int32_t slot = slot_for(t, kind, scope, name, name_len, digest);
-    key_ns += ns_since(t0);
-    return slot;
+  // slot_for of a name and the tags in `joined`, its key built in keybuf
+  int32_t slot_for_name(uint8_t kind, uint8_t scope, const char* name,
+                        size_t name_len, uint32_t digest,
+                        std::shared_lock<std::shared_mutex>* held = nullptr) {
+    keybuf.clear();
+    keybuf.push_back((char)kind);
+    keybuf.append(name, name_len);
+    keybuf.push_back('\x1f');
+    keybuf.append(joined);
+    return slot_for(kind, scope, keybuf.data(), keybuf.size(), name_len,
+                    KeyIndex::hash(keybuf.data(), keybuf.size()), digest,
+                    held);
   }
 
   // strict float parse: Go strconv.ParseFloat-alike (no surrounding
@@ -634,8 +776,10 @@ struct Parser {
     return *out = v, true;
   }
 
-  // returns 0 ok, 1 parse error, 2 special (event/service check), 3 full
-  int parse_line(const char* line, size_t len) {
+  // returns 0 ok, 1 parse error, 2 special (event/service check). `held`
+  // as in slot_for.
+  int parse_line(const char* line, size_t len,
+                 std::shared_lock<std::shared_mutex>* held) {
     if (len == 0) return 0;
     if (len >= 3 && line[0] == '_' &&
         ((line[1] == 'e' && line[2] == '{') ||
@@ -778,26 +922,20 @@ struct Parser {
       demoted_rows[cur_tenant]++;
     }
 
+    int32_t slot = lookup(kind, scope, name, name_len, h, held);
+    if (slot < 0) return 0;
     switch (kind) {
-      case K_COUNTER: {
-        int32_t slot = lookup(rt().counters, kind, scope, name, name_len, h);
-        if (slot < 0) return 0;
+      case K_COUNTER:
         c_slot[nc] = slot;
         c_inc[nc] = (float)(value_f * (1.0 / rate));
         nc++;
         break;
-      }
-      case K_GAUGE: {
-        int32_t slot = lookup(rt().gauges, kind, scope, name, name_len, h);
-        if (slot < 0) return 0;
+      case K_GAUGE:
         g_slot[ng] = slot;
         g_val[ng] = (float)value_f;
         ng++;
         break;
-      }
       case K_SET: {
-        int32_t slot = lookup(rt().sets, kind, scope, name, name_len, h);
-        if (slot < 0) return 0;
         uint64_t mh = metro64(value, value_len);
         uint32_t reg = (uint32_t)(mh >> (64 - hll_precision));
         uint64_t restbits = mh << hll_precision;
@@ -814,18 +952,46 @@ struct Parser {
         ns++;
         break;
       }
-      case K_HISTO:
-      case K_TIMER: {
-        int32_t slot = lookup(rt().histos, kind, scope, name, name_len, h);
-        if (slot < 0) return 0;
+      default:  // K_HISTO, K_TIMER
         h_slot[nh] = slot;
         h_val[nh] = (float)value_f;
         h_wt[nh] = (float)(1.0 / rate);
         nh++;
-        break;
-      }
     }
     processed++;
+    return 0;
+  }
+
+  // slot_for_name as parse_line calls it: timed only in a sampled datagram
+  int32_t lookup(uint8_t kind, uint8_t scope, const char* name,
+                 size_t name_len, uint32_t digest,
+                 std::shared_lock<std::shared_mutex>* held) {
+    if (!time_keys)
+      return slot_for_name(kind, scope, name, name_len, digest, held);
+    auto t0 = std::chrono::steady_clock::now();
+    int32_t slot = slot_for_name(kind, scope, name, name_len, digest, held);
+    key_ns += ns_since(t0);
+    return slot;
+  }
+
+  // vt_feed. The master's parser holds key_mu shared for the whole buffer;
+  // a ring parser's hits come from its replica and lock only on a miss.
+  int feed(const char* data, int len, int start, int* consumed) {
+    std::shared_lock<std::shared_mutex> lk(key_mu, std::defer_lock);
+    if (!master) lk.lock();
+    std::shared_lock<std::shared_mutex>* held = master ? nullptr : &lk;
+    int off = start < 0 ? 0 : start;
+    while (off < len) {
+      if (any_full()) {
+        *consumed = off;
+        return 1;
+      }
+      const char* nl = (const char*)memchr(data + off, '\n', len - off);
+      int line_len = nl ? (int)(nl - (data + off)) : (len - off);
+      if (parse_line(data + off, line_len, held) == 1) parse_errors++;
+      off += line_len + (nl ? 1 : 0);
+    }
+    *consumed = off;
     return 0;
   }
 };
@@ -852,21 +1018,7 @@ void vt_free(void* h) { delete (Parser*)h; }
 // absolute offset of the first unhandled byte. Returns 1 if
 // stopped-for-full, else 0.
 int vt_feed(void* hp, const char* data, int len, int start, int* consumed) {
-  auto* p = (Parser*)hp;
-  int off = start < 0 ? 0 : start;
-  while (off < len) {
-    if (p->any_full()) {
-      *consumed = off;
-      return 1;
-    }
-    const char* nl = (const char*)memchr(data + off, '\n', len - off);
-    int line_len = nl ? (int)(nl - (data + off)) : (len - off);
-    int rc = p->parse_line(data + off, line_len);
-    if (rc == 1) p->parse_errors++;
-    off += line_len + (nl ? 1 : 0);
-  }
-  *consumed = off;
-  return 0;
+  return ((Parser*)hp)->feed(data, len, start, consumed);
 }
 
 // Copy staged samples into caller-provided buffers (caller pre-fills slot
@@ -1094,20 +1246,12 @@ int32_t vt_slot_for(void* hp, int kind, int scope, const char* name,
                     int name_len, const char* tags, int tags_len,
                     uint32_t digest, int* was_new) {
   auto* p = (Parser*)hp;
-  KindTable* t;
-  switch (kind) {
-    case K_COUNTER: t = &p->counters; break;
-    case K_GAUGE: t = &p->gauges; break;
-    case K_SET: t = &p->sets; break;
-    case K_HISTO:
-    case K_TIMER: t = &p->histos; break;
-    default: return -1;
-  }
+  if (kind < K_COUNTER || kind > K_TIMER) return -1;
   p->joined.assign(tags, tags_len);
   size_t before = p->new_keys.size();
   p->alloc_imported = (scope & 0x80) != 0;
-  int32_t slot = p->slot_for(*t, (uint8_t)kind, (uint8_t)(scope & 0x7F),
-                             name, name_len, digest);
+  int32_t slot = p->slot_for_name((uint8_t)kind, (uint8_t)(scope & 0x7F),
+                                  name, name_len, digest);
   p->alloc_imported = false;
   *was_new = p->new_keys.size() > before ? 1 : 0;
   return slot;
@@ -1524,10 +1668,8 @@ int vi_import(void* hp, const char* data, int len, int start,
     // histos keep Global else collapse to mixed
     uint8_t scope = (kind == K_COUNTER || kind == K_GAUGE)
                         ? 2 : (m.scope == 2 ? 2 : 0);
-    KindTable* t = (kind == K_COUNTER) ? &p->counters
-                   : (kind == K_GAUGE) ? &p->gauges : &p->histos;
-    int32_t slot = p->slot_for(*t, (uint8_t)kind, scope, m.name,
-                               (size_t)m.name_len, digest);
+    int32_t slot = p->slot_for_name((uint8_t)kind, scope, m.name,
+                                    (size_t)m.name_len, digest);
     if (slot < 0) {   // capacity drop, counted in t->dropped —
       staged++;       // still a HANDLED metric (imported_total parity
       p->processed++; // with the Python path, which counts before drops)
@@ -1690,7 +1832,9 @@ struct Admission {
 // engine (vr_stats / vrm_ring_stats): blocked on an empty ring (wait),
 // the rest of it (busy: parse, staging, the ring's lock), and one
 // datagram in kPumpSampleEvery timed whole and in its key lookups
-// (Parser::lookup), so the parse splits with no clock read a line.
+// (Parser::lookup and the pass that hashes the keys), so the parse splits
+// with no clock read a line; that datagram also counts its lookups and
+// the key index entries they read.
 constexpr uint64_t kPumpSampleEvery = 64;
 
 struct PumpCounters {
@@ -1699,18 +1843,23 @@ struct PumpCounters {
   std::atomic<uint64_t> sampled_ns{0};
   std::atomic<uint64_t> sampled_key_ns{0};
   std::atomic<uint64_t> sampled_datagrams{0};
+  std::atomic<uint64_t> sampled_lookups{0};
+  std::atomic<uint64_t> sampled_probes{0};
   uint64_t seen = 0;  // datagrams fed: the parsing thread's alone
 
   void add(std::atomic<uint64_t>& a, uint64_t v) {
     a.fetch_add(v, std::memory_order_relaxed);
   }
-  // out[0..4]: wait, busy, sampled, of it key lookups, sampled datagrams
+  // out[0..6]: wait, busy, sampled, of it key lookups, sampled datagrams,
+  // their key lookups and the index entries those read
   void read(uint64_t* out) const {
     out[0] = wait_ns.load(std::memory_order_relaxed);
     out[1] = busy_ns.load(std::memory_order_relaxed);
     out[2] = sampled_ns.load(std::memory_order_relaxed);
     out[3] = sampled_key_ns.load(std::memory_order_relaxed);
     out[4] = sampled_datagrams.load(std::memory_order_relaxed);
+    out[5] = sampled_lookups.load(std::memory_order_relaxed);
+    out[6] = sampled_probes.load(std::memory_order_relaxed);
   }
 };
 
@@ -1721,7 +1870,7 @@ int feed_datagram(Parser* p, PumpCounters& pc, const char* data, int len,
   if (start > 0 || pc.seen++ % kPumpSampleEvery)
     return vt_feed(p, data, len, start, consumed);
   p->time_keys = true;
-  p->key_ns = 0;
+  p->key_ns = p->key_lookups = p->key_probes = 0;
   auto t0 = std::chrono::steady_clock::now();
   int full = vt_feed(p, data, len, 0, consumed);
   uint64_t ns = ns_since(t0);
@@ -1729,6 +1878,8 @@ int feed_datagram(Parser* p, PumpCounters& pc, const char* data, int len,
   pc.add(pc.sampled_ns, ns);
   pc.add(pc.sampled_key_ns, p->key_ns);
   pc.add(pc.sampled_datagrams, 1);
+  pc.add(pc.sampled_lookups, p->key_lookups);
+  pc.add(pc.sampled_probes, p->key_probes);
   return full;
 }
 
@@ -2083,8 +2234,9 @@ void vr_counters(void* gp, uint64_t* out) {
 // [0]=ring depth now, [1]=ring depth high-water, [2]=pump batches (vr_pump
 // calls that parsed >=1 datagram), [3]=buffer-swap stalls (vr_pump returned
 // full), [4]=emit_packed calls, [5]=emit_packed ns total, [6]=datagrams
-// received, [7]=ring_dropped, [8..12]=the pump's PumpCounters (wait ns,
-// busy ns, sampled parse ns, of it key lookups, sampled datagrams).
+// received, [7]=ring_dropped, [8..14]=the pump's PumpCounters (wait ns,
+// busy ns, sampled parse ns, of it key lookups, sampled datagrams, their
+// key lookups, the key index entries those read).
 // Per-class admission is NOT repeated here — vr_admission_counters
 // already drains it exactly.
 void vr_stats(void* gp, uint64_t* out) {
@@ -2123,7 +2275,7 @@ void vr_stop(void* gp) {
 // parse -> staging), so N rings parse on N cores concurrently while the
 // pipeline thread only memcpys staged lanes into its packed arena rows and
 // steps the device. All rings share the master parser's key tables (see
-// Parser::slot_for: ring-local replica cache, shared lock on miss), so a
+// Parser::slot_for: a ring-local replica index, shared lock on miss), so a
 // flow-hashed key landing on any ring maps to the same device slot.
 // Admission, toolong, and ring-cap accounting run per ring with the same
 // datagrams == toolong + admitted + shed invariant, summed by Python.
@@ -2155,7 +2307,7 @@ struct Ring {
   std::deque<Dgram> ring;
   size_t ring_cap = 65536;
   // ring-local tenant-id replica (guarded by mu): hits skip the shared
-  // intern table's mutex, mirroring the key-table local_cache pattern
+  // intern table's mutex, mirroring the key tables' ring-local replica
   std::unordered_map<std::string, std::pair<int32_t, TenantEntry*>> tcache;
   uint64_t datagrams = 0;        // guarded by mu
   uint64_t toolong = 0;          // guarded by mu
@@ -2518,14 +2670,19 @@ void vrm_resume(void* h) {
   }
 }
 
-// Flush boundary: start the master tables' next interval and clear every
-// ring's key-replica cache, so a ring's first hit of a key in the
-// interval goes to the master and marks it live. Caller must hold the quiesce (vrm_pause) and have emitted all
-// rings first.
+// Flush boundary: start the master tables' next interval and empty every
+// ring's key replica, sized to the master's tables as they are now (a
+// staged capacity applies in vt_reset), so a ring's first hit of a key in
+// the interval goes to the master and marks it live. Caller must hold the
+// quiesce (vrm_pause) and have emitted all rings first.
 void vrm_reset(void* h) {
   auto* mr = (MultiRing*)h;
   vt_reset(mr->master);
-  for (auto& r : mr->rings) r->parser.local_cache.clear();
+  auto ms = mr->master->tables();
+  for (auto& r : mr->rings) {
+    auto rs = r->parser.tables();
+    for (int i = 0; i < 4; i++) rs[i]->index.reset(ms[i]->capacity);
+  }
 }
 
 // Multi-ring shard-map staging: the rings route every table access to
@@ -2569,7 +2726,7 @@ void vrm_counters(void* h, int ring, uint64_t* out) {
 // Per-ring deep telemetry (vr_stats layout): [0]=ring depth, [1]=depth
 // high-water, [2]=parse batches (datagrams parsed), [3]=staging stalls,
 // [4]=emit calls, [5]=emit ns, [6]=datagrams received, [7]=ring_dropped,
-// [8..12]=the worker's PumpCounters.
+// [8..14]=the worker's PumpCounters.
 void vrm_ring_stats(void* h, int ring, uint64_t* out) {
   auto* mr = (MultiRing*)h;
   Ring* r = mr->rings[ring].get();
