@@ -1,11 +1,16 @@
-"""One run of one cell: the server in this process, the sender in a child,
-the tick protocol between them, and the comparison after the window.
+"""One run of one cell: the server in this process, the load generator in
+a child, the tick protocol between them, and the comparison after the
+window.
 
-The tick protocol (README.md has it in ten lines): pause the sender and
-note where the stream stopped; wait until the engine has parsed all that
-was sent; enqueue the flush request the server's own ticker would send;
-wait until the swap has happened; resume. Interval boundaries are then
-known to the reference although ingest and flush overlap.
+The tick protocol (README.md has it in ten lines): pause the generator
+and note where the stream stopped; wait until the server has taken in all
+that was sent; enqueue the flush request the server's own ticker would
+send; wait until the swap has happened; resume. Interval boundaries are
+then known to the reference although ingest and flush overlap.
+
+The traffic file's `ingress` picks the way in (`Udp`, `Forward`): the
+child, what counts as taken in, the credit, the pool and what the
+reference expects of an interval. The protocol is the same for both.
 
 From the program this module takes the system under test (built the way
 cli/server.py builds it) and its counters and phase timers. Nothing here
@@ -156,6 +161,8 @@ def counters(server) -> dict:
            "packets_toolong": server.packets_toolong,
            "parse_errors_py": server.parse_errors,
            "internal_errors": server.internal_errors,
+           "imported_total": server.imported_total,
+           "import_errors": server.import_errors,
            "intervals_deferred": server.flush_intervals_deferred,
            "compiles_total": jaxruntime.compiles_total(),
            "compile_ns": jaxruntime.compile_time_ns_total()}
@@ -183,6 +190,152 @@ def memory_peak() -> int:
     return int(max(peaks))
 
 
+# -- the ways in ----------------------------------------------------------------
+
+class Ingress:
+    """A way in, made from the traffic file."""
+
+    def __init__(self, spec: dict):
+        self.spec = spec
+
+
+class Udp(Ingress):
+    """DogStatsD over UDP: `sender.py` streams the pool's datagrams; taken
+    in is what the engine has parsed, in samples."""
+    child, unit, position = "sender.py", "samples", "datagrams"
+
+    def build_pool(self, seed: int):
+        return T.build_pool(self.spec, seed)
+
+    @staticmethod
+    def positions(pool) -> int:
+        return pool.n_datagrams
+
+    @staticmethod
+    def second_warmup(n: int) -> int:
+        """Positions sent between the warm-up's tick and tick 0: a cycle."""
+        return n
+
+    @staticmethod
+    def taken_in(server) -> int:
+        return server.aggregator.eng.stats()["processed"]
+
+    @staticmethod
+    def queue_empty(server) -> bool:
+        return server.aggregator.eng.reader_counters()["ring_depth"] == 0
+
+    def open(self, server):
+        """(port, credit in samples, what was chosen)."""
+        rcvbuf = server._sockets[0].getsockopt(socket.SOL_SOCKET,
+                                               socket.SO_RCVBUF)
+        # a datagram of ~30 lines costs the kernel up to ~2.3 KB of buffer
+        # accounting; stay a factor of four inside the socket buffer, so
+        # that nothing is dropped even if the reader thread is not run
+        credit_d = max(4, rcvbuf // (4 * 2304))
+        return (server.local_addr()[1],
+                credit_d * self.spec["lines_per_datagram"],
+                f"credit {credit_d} datagrams (socket buffer {rcvbuf} B)")
+
+    @staticmethod
+    def stalled(server) -> str:
+        agg = server.aggregator
+        return f"engine parsed {agg.eng.stats()}; ring {agg.ring_stats()}"
+
+    @staticmethod
+    def expected(pool, b0, b1, percentiles):
+        return reference.expected(pool, b0, b1, percentiles)
+
+    @staticmethod
+    def control_rows(pool, b0, b1, percentiles, control) -> dict:
+        """The reference's counters kept in the control's lower precision,
+        and its sets from a plain HyperLogLog."""
+        low, _ = reference.expected(
+            pool, b0, b1, percentiles,
+            counter_dtype=getattr(np, control["counter_dtype"]))
+        low.update(reference.hll_estimates(pool, b0, b1,
+                                           control["hll_precision"]))
+        return low
+
+    @staticmethod
+    def lost(c_after: dict, c_start: dict, pool) -> int:
+        """Samples dropped, refused or unparsed over the window."""
+        return ((c_after["eng.dropped"] - c_start["eng.dropped"])
+                + lines_of(c_after, c_start, "ring.ring_dropped", pool)
+                + lines_of(c_after, c_start, "packets_dropped", pool)
+                + lines_of(c_after, c_start, "packets_toolong", pool)
+                + lines_of(c_after, c_start, "reader.toolong", pool)
+                + (c_after["eng.parse_errors"] - c_start["eng.parse_errors"])
+                + (c_after["parse_errors_py"] - c_start["parse_errors_py"]))
+
+
+class Forward(Ingress):
+    """Sketches forwarded by N locals over gRPC: `forwarder.py` sends the
+    pool's RPCs to the global's `grpc_port`; taken in is the server's
+    `imported_total`, in metrics, counted on the pipeline thread as each
+    import is folded. The imports wait in the server's FIFO packet queue
+    ahead of the tick's flush request."""
+    child, unit, position = "forwarder.py", "metrics", "RPCs"
+    # RPCs sent ahead of what is taken in: the pipeline thread's queue at a
+    # pause, so tick_to_sink_mean_s holds its drain (8 RPCs is ~0.1 s of
+    # work at 74k metrics/s, and outlasts the publisher's and an RPC's
+    # round trips, ~1 ms each, at ten times that)
+    CREDIT_RPCS = 8
+
+    def build_pool(self, seed: int):
+        return T.build_forward_pool(self.spec, seed)
+
+    @staticmethod
+    def positions(pool) -> int:
+        return pool.n_rpcs
+
+    def second_warmup(self, n: int) -> int:
+        """One burst: the first cycle has made every key and compiled every
+        shape, and a cycle at the rate a global takes its imports is tens
+        of seconds of set-up."""
+        return n // int(self.spec["bursts"])
+
+    @staticmethod
+    def taken_in(server) -> int:
+        return server.imported_total
+
+    @staticmethod
+    def queue_empty(server) -> bool:
+        return server.packet_queue.empty()
+
+    def open(self, server):
+        if server.grpc_port is None:
+            raise RunError("a forward mix needs a configuration that sets "
+                           "grpc_address: the server serves no gRPC import")
+        credit = self.CREDIT_RPCS * int(self.spec["metrics_per_rpc"])
+        return server.grpc_port, credit, f"credit {credit} metrics"
+
+    @staticmethod
+    def stalled(server) -> str:
+        return (f"server imported {server.imported_total} "
+                f"({server.import_errors} import errors); packet queue "
+                f"{server.packet_queue.qsize()}")
+
+    @staticmethod
+    def expected(pool, b0, b1, percentiles):
+        return reference.expected_forward(pool, b0, b1, percentiles)
+
+    @staticmethod
+    def control_rows(pool, b0, b1, percentiles, control) -> dict:
+        """The reference's counters kept in the control's lower precision."""
+        low, _ = reference.expected_forward(
+            pool, b0, b1, percentiles,
+            counter_dtype=getattr(np, control["counter_dtype"]))
+        return low
+
+    @staticmethod
+    def lost(c_after: dict, c_start: dict, pool) -> int:
+        """Metrics the server refused as it imported them."""
+        return c_after["import_errors"] - c_start["import_errors"]
+
+
+INGRESS = {"udp": Udp, "forward": Forward}
+
+
 # -- the run ------------------------------------------------------------------
 
 class Run:
@@ -198,6 +351,8 @@ class Run:
         # the control switches on the program's own lower-precision path
         self.overrides = dict(self.control["overrides"]) if control else {}
         self.prefix = cell["traffic_file"].get("prefix", "pb")
+        self.ingress = INGRESS[cell["traffic_file"].get("ingress", "udp")](
+            cell["traffic_file"])
         self.phases = []              # (name, start ns, end ns), host clock
         self.tick_log = []            # per tick: b, sent, t_ns, req, ...
         self.trace_dir = self.trace_mark_ns = self.trace_span = None
@@ -213,10 +368,13 @@ class Run:
         end = time.monotonic() + timeout
         while not cond():
             if self.child.poll() is not None:
-                raise RunError(f"the sender exited ({self.child.returncode}) "
-                               f"while waiting for {what}")
+                raise RunError(f"the {self.ingress.child} child exited "
+                               f"({self.child.returncode}) while waiting "
+                               f"for {what}")
             if not self.server._pipeline_thread.is_alive():
                 raise RunError("the pipeline thread died")
+            if self._publisher is not None and not self._publisher.is_alive():
+                raise RunError("the credit's publisher died")
             if time.monotonic() > end:
                 return False
             time.sleep(poll)
@@ -227,36 +385,42 @@ class Run:
         millisecond, and keeps for the per-interval line what the credit's
         round trip looked like while the sender was running: how many
         times it published, the longest time between two, how often the
-        ring stood empty and how far ahead the sender was. A round of
-        over STALL_NS starves the sender of credit for a real part of a
-        stretch, so it is said at once, with where the time went: asleep
-        or waiting for the interpreter, or in eng.stats() (the engine's key
-        lock, and the interpreter again on the way back)."""
-        eng, ctl, base = self.server.aggregator.eng, self.ctl, self.base
+        ring (forward: the packet queue) stood empty and how far ahead the
+        sender was. A round of over STALL_NS starves the sender of credit
+        for a real part of a stretch, so it is said at once, with where the
+        time went: asleep or waiting for the interpreter, or in the count
+        (UDP: eng.stats(), the engine's key lock, and the interpreter again
+        on the way back)."""
+        server, ctl, base = self.server, self.ctl, self.base
+        taken_in, queue_empty = self.ingress.taken_in, self.ingress.queue_empty
         pub, clock = self.pub, time.monotonic_ns
         last = clock()
         while not self._stop_publish.is_set():
             t_woke = clock()
-            done = eng.stats()["processed"] - base
+            done = taken_in(server) - base
             ctl[S.PROCESSED] = done
             now = clock()
             if ctl[S.CMD] == S.RUN:
                 pub["n"] += 1
                 pub["gap_max_ns"] = max(pub["gap_max_ns"], now - last)
-                pub["ring_empty"] += eng.reader_counters()["ring_depth"] == 0
+                pub["ring_empty"] += queue_empty(server)
                 pub["ahead"] += ctl[S.SENT] - done
                 if now - last > STALL_NS:
+                    # in the warm-up's first stretch no tick has resumed
+                    # the sender yet: an IndexError here killed this
+                    # thread and starved the sender for good
+                    since = (f"{(now - self.phases[-1][2]) / 1e9:.2f} s "
+                             "after the last resume" if self.phases
+                             else "before the first tick")
                     say(f"credit: not published for {(now - last) / 1e6:.0f} "
                         f"ms ({(t_woke - last) / 1e6:.0f} asleep or waiting "
-                        f"for the interpreter, {(now - t_woke) / 1e6:.0f} in "
-                        "eng.stats() and back), "
-                        f"{(now - self.phases[-1][2]) / 1e9:.2f} s after the "
-                        "last resume")
+                        f"for the interpreter, {(now - t_woke) / 1e6:.0f} "
+                        f"reading the count and back), {since}")
             last = clock()
             time.sleep(0.001)
 
     def _processed(self) -> int:
-        return self.server.aggregator.eng.stats()["processed"] - self.base
+        return self.ingress.taken_in(self.server) - self.base
 
     def _drained(self):
         return self._processed() >= self.ctl[S.SENT]
@@ -280,10 +444,10 @@ class Run:
         drained = self._wait(self._drained, "the engine to drain", 20)
         over = self._processed() - self.ctl[S.SENT]
         if over:
-            # the engine counts only what the sender sent, or the drain
+            # the server counts only what the sender sent, or the drain
             # test means nothing
-            raise RunError(f"the engine has parsed {over} samples more than "
-                           "were sent")
+            raise RunError(f"the server has taken in {over} "
+                           f"{self.ingress.unit} more than were sent")
         table = self.server.aggregator.table
         t_req = time.monotonic_ns()
         req = self.server.trigger_flush(wait=False)
@@ -311,12 +475,12 @@ class Run:
         ctl[S.LIMIT] = limit
         ctl[S.CMD] = S.RUN
         ok = self._wait(lambda: ctl[S.POS] >= limit and self._drained(),
-                        f"{limit} datagrams to be parsed", timeout, 0.002)
+                        f"{limit} {self.ingress.position} to be taken in",
+                        timeout, 0.002)
         if not ok:
             raise RunError(
-                f"engine parsed {self.server.aggregator.eng.stats()} of "
-                f"{ctl[S.SENT]} samples sent, then stalled; ring "
-                f"{self.server.aggregator.ring_stats()}")
+                f"{ctl[S.SENT]} {self.ingress.unit} sent, then stalled: "
+                + self.ingress.stalled(self.server))
 
     # tracing a slice of the window
     def _trace_start(self):
@@ -374,7 +538,7 @@ class Run:
         self.mm, self.ctl = S.open_block(ctl_path, create=True)
         ctl = self.ctl
         self.child = subprocess.Popen(
-            [sys.executable, os.path.join(HERE, "sender.py"), ctl_path,
+            [sys.executable, os.path.join(HERE, self.ingress.child), ctl_path,
              cell["traffic_path"], str(self.seed)])
         self.sink = make_sink()
         self.server = build_server(cfgf, self.tmp, self.sink, self.overrides)
@@ -382,18 +546,10 @@ class Run:
         self.server.start()
         self.info = describe(self.server)
         say("serve: " + " ".join(f"{k}={v}" for k, v in self.info.items()))
-        agg = self.server.aggregator
-        self.base = agg.eng.stats()["processed"]
-        rcvbuf = self.server._sockets[0].getsockopt(socket.SOL_SOCKET,
-                                                    socket.SO_RCVBUF)
-        lines = cell["traffic_file"]["lines_per_datagram"]
-        # a datagram of ~30 lines costs the kernel up to ~2.3 KB of buffer
-        # accounting; stay a factor of four inside the socket buffer, so
-        # that nothing is dropped even if the reader thread is not run
-        credit_d = max(4, rcvbuf // (4 * 2304))
-        ctl[S.CREDIT] = credit_d * lines
+        self.base = self.ingress.taken_in(self.server)
+        port, ctl[S.CREDIT], chosen = self.ingress.open(self.server)
         ctl[S.LIMIT] = 0
-        ctl[S.PORT] = self.server.local_addr()[1]
+        ctl[S.PORT] = port
         self._publisher = threading.Thread(target=self._publish, daemon=True,
                                            name="perfbench-credit")
         self._publisher.start()
@@ -401,17 +557,17 @@ class Run:
                           "the sender's pool", 120, 0.005):
             raise RunError("the sender built no pool")
         n_d = ctl[S.N_DATAGRAMS]
-        say(f"sender: pool of {n_d} datagrams, credit {credit_d} datagrams "
-            f"(socket buffer {rcvbuf} B)")
+        say(f"{self.ingress.child}: pool of {n_d} {self.ingress.position}, "
+            + chosen)
 
         # warm-up: one pool cycle, a tick, its emission (compiles or loads
         # the ingest program with its compaction branch, the swap and the
         # flush program at this cell's own row count); then a second
-        # cycle and tick 0, which is not waited for
+        # cycle (forward: a burst) and tick 0, which is not waited for
         c0 = counters(self.server)
         self._send_until(n_d, 1100)
         self._tick(wait_flush=True)
-        self._send_until(2 * n_d, 300)
+        self._send_until(n_d + self.ingress.second_warmup(n_d), 300)
         ctl[S.LIMIT] = 2 ** 62
         tick0 = self._tick(wait_flush=False)
         t0 = tick0["t_swap"]
@@ -472,7 +628,8 @@ class Run:
         cell, cfgf = self.cell, self.cell["config_file"]
         log, frames = self.tick_log, self.sink.handed
         attempted = log[-1]["sent"] - log[0]["sent"]
-        pool = T.build_pool(cell["traffic_file"], self.seed)
+        ingress = self.ingress
+        pool = ingress.build_pool(self.seed)
         if int(pool.digest()[:15], 16) != digest_child:
             raise RunError("the sender's pool is not the reference's pool")
         percentiles = cfgf["expect"]["percentiles"]
@@ -485,29 +642,26 @@ class Run:
         for k in range(1, len(log)):
             rec, prev = log[k], log[k - 1]
             sent_k = rec["sent"] - prev["sent"]
-            cycles = (rec["b"] - prev["b"]) / pool.n_datagrams
+            cycles = (rec["b"] - prev["b"]) / ingress.positions(pool)
             req = rec["req"]
             if not (req.done.is_set() and req.ok) or len(frames) <= at:
                 failed += sent_k
                 examples.append(f"flush {k} not emitted: {req.detail}")
-                say(f"interval {k}: {sent_k} samples, {cycles:.2f} pool "
-                    f"cycles, NOT EMITTED ({req.detail})")
+                say(f"interval {k}: {sent_k} {ingress.unit}, {cycles:.2f} "
+                    f"pool cycles, NOT EMITTED ({req.detail})")
                 continue
             e_ns, frame = frames[at]
             at += 1
             latencies.append((e_ns - rec["t_ns"]) / 1e9)
             self.phases.append(("flush_in_flight", rec["t_req"], e_ns))
             got, tags, twice = frame_rows(frame, self.prefix)
-            want, timers = reference.expected(pool, prev["b"], rec["b"],
-                                              percentiles)
+            want, timers = ingress.expected(pool, prev["b"], rec["b"],
+                                            percentiles)
             if self.control:
                 # the reference, put in the program's place, with counters
                 # kept in the control's lower precision
-                low, _ = reference.expected(
-                    pool, prev["b"], rec["b"], percentiles,
-                    counter_dtype=getattr(np, self.control["counter_dtype"]))
-                low.update(reference.hll_estimates(
-                    pool, prev["b"], rec["b"], self.control["hll_precision"]))
+                low = ingress.control_rows(pool, prev["b"], rec["b"],
+                                           percentiles, self.control)
                 for name in low.keys() & got.keys():
                     if name.startswith((self.prefix + ".c.",
                                         self.prefix + ".s.")):
@@ -518,20 +672,15 @@ class Run:
             for name, w in here.items():
                 if w["err"] >= widest.get(name, w)["err"]:
                     widest[name] = dict(w, interval=k)
-            say(f"interval {k}: {sent_k} samples, {cycles:.2f} pool cycles, "
+            say(f"interval {k}: {sent_k} {ingress.unit}, {cycles:.2f} pool "
+                f"cycles, "
                 f"{len(got)} rows, pause "
                 f"{(rec['t_resume'] - rec['t_pause']) / 1e6:.1f} ms (drain "
                 f"{(rec['t_req'] - rec['t_pause']) / 1e6:.1f}, swap "
                 f"{(rec['t_swap'] - rec['t_req']) / 1e6:.1f}), "
                 f"tick to sink {latencies[-1]:.3f} s; "
-                + stretch_line(prev, rec, sent_k))
-        dropped = ((c_after["eng.dropped"] - c_start["eng.dropped"])
-                   + lines_of(c_after, c_start, "ring.ring_dropped", pool)
-                   + lines_of(c_after, c_start, "packets_dropped", pool)
-                   + lines_of(c_after, c_start, "packets_toolong", pool)
-                   + lines_of(c_after, c_start, "reader.toolong", pool)
-                   + (c_after["eng.parse_errors"] - c_start["eng.parse_errors"])
-                   + (c_after["parse_errors_py"] - c_start["parse_errors_py"]))
+                + stretch_line(prev, rec, sent_k, ingress.unit))
+        dropped = ingress.lost(c_after, c_start, pool)
         undrained = sum(1 for rec in log[1:] if not rec["drained"])
         failed = min(attempted, failed + dropped)
         numbers["rows_missing"] += undrained
@@ -588,7 +737,7 @@ class Run:
                 "memory_peak_bytes": peak}
 
 
-def stretch_line(prev: dict, rec: dict, sent: int) -> str:
+def stretch_line(prev: dict, rec: dict, sent: int, unit: str) -> str:
     """The interval's sending stretch, resume to pause: its own rate, how
     long the sender waited for credit in it, and the credit's round trip
     as the publisher saw it (Run._publish)."""
@@ -598,13 +747,13 @@ def stretch_line(prev: dict, rec: dict, sent: int) -> str:
     empty = rec["pub"]["ring_empty"] - prev["pub"]["ring_empty"]
     ahead = rec["pub"]["ahead"] - prev["pub"]["ahead"]
     return (f"stretch {stretch_ns / 1e9:.3f} s at "
-            f"{sent / (stretch_ns / 1e9):.0f} samples/s, sender blocked "
+            f"{sent / (stretch_ns / 1e9):.0f} {unit}/s, sender blocked "
             f"{blocked_ns / 1e6:.1f} ms ({100 * blocked_ns / stretch_ns:.1f} %), "
             f"credit published {n} times, longest gap "
             f"{rec['pub']['gap_max_ns'] / 1e6:.1f} ms (the sender's longest "
             f"look for credit {rec['pub']['poll_max_ns'] / 1e6:.1f} ms), ring "
             f"empty at {100 * empty / n:.1f} % of them, sender ahead "
-            f"{ahead / n:.0f} samples on average")
+            f"{ahead / n:.0f} {unit} on average")
 
 
 def lines_of(after: dict, before: dict, key: str, pool) -> int:

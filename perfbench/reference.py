@@ -8,7 +8,8 @@ pool sample has a multiplicity in it. From that multiset: a counter is the
 sum of increment / rate; a gauge is the value written last; a timer has an
 exact count, min and max (float32, as the wire value is stored) and
 midpoint-rank ("hazen") percentiles; a set is its number of distinct
-members.
+members. `expected_forward` is the same for a global fed forwarded
+sketches, the positions being RPCs.
 
 A percentile is judged in rank space (`rank_errors`): how far the asked q
 lies from the ranks that the emitted value holds among the interval's own
@@ -23,7 +24,7 @@ import numpy as np
 
 from typing import NamedTuple
 
-from traffic import KINDS, Pool
+from traffic import KINDS, ForwardPool, Pool
 
 
 class TimerSamples(NamedTuple):
@@ -67,6 +68,40 @@ def hazen(sorted_vals, starts, lens, q: float) -> np.ndarray:
     return a + (b - a) * frac
 
 
+def _counter_rows(out: dict, prefix: str, ids, inc, m, counter_dtype) -> None:
+    """Each counter's sum of inc x multiplicity. `counter_dtype` float32
+    is one float, one add per sample as it arrives: the sum rounds once it
+    passes 2^24."""
+    if counter_dtype is np.float64:
+        total = np.bincount(ids, weights=inc * m)
+    else:
+        total = np.zeros(ids.max() + 1, counter_dtype)
+        np.add.at(total, np.repeat(ids, m),
+                  np.repeat(inc, m).astype(counter_dtype))
+    for i in np.unique(ids).tolist():
+        out[f"{prefix}.c.{i:07d}"] = float(total[i])
+
+
+def _timer_rows(out: dict, prefix: str, ids_x, val_x, percentiles,
+                aggregates: bool) -> TimerSamples:
+    """The rows of timers whose samples are ids_x, val_x, sorted by timer
+    and then by value: min, max and count where `aggregates`, and the
+    percentiles; and their TimerSamples."""
+    starts, lens = _segments(ids_x)
+    v32 = val_x.astype(np.float32)
+    mn, mx = v32[starts], v32[starts + lens - 1]
+    qs = [hazen(val_x, starts, lens, q) for q in percentiles]
+    for j, i in enumerate(ids_x[starts].tolist()):
+        base = f"{prefix}.t.{i:07d}"
+        if aggregates:
+            out[base + ".min"] = float(mn[j])
+            out[base + ".max"] = float(mx[j])
+            out[base + ".count"] = float(lens[j])
+        for q, col in zip(percentiles, qs):
+            out[f"{base}.{int(round(q * 100))}percentile"] = float(col[j])
+    return TimerSamples(ids_x[starts], starts, lens, v32.astype(np.float64))
+
+
 def expected(pool: Pool, b0: int, b1: int, percentiles,
              counter_dtype=np.float64):
     """name -> value for every row the sink must receive for the interval
@@ -88,16 +123,7 @@ def expected(pool: Pool, b0: int, b1: int, percentiles,
     sel, ids, val, m = of("counter")
     if len(sel):
         inc = val * np.where(pool.half_rate[sel], 2.0, 1.0)
-        if counter_dtype is np.float64:
-            total = np.bincount(ids, weights=inc * m)
-        else:
-            # one float, one add per sample as it arrives: the sum rounds
-            # once it passes 2^24
-            total = np.zeros(ids.max() + 1, counter_dtype)
-            np.add.at(total, np.repeat(ids, m),
-                      np.repeat(inc, m).astype(counter_dtype))
-        for i in np.unique(ids).tolist():
-            out[f"{p}.c.{i:07d}"] = float(total[i])
+        _counter_rows(out, p, ids, inc, m, counter_dtype)
 
     sel, ids, val, m = of("gauge")
     if len(sel):
@@ -112,20 +138,8 @@ def expected(pool: Pool, b0: int, b1: int, percentiles,
     if len(sel):
         ids_x, val_x = np.repeat(ids, m), np.repeat(val, m)
         order = np.lexsort((val_x, ids_x))
-        ids_x, val_x = ids_x[order], val_x[order]
-        starts, lens = _segments(ids_x)
-        v32 = val_x.astype(np.float32)
-        mn, mx = v32[starts], v32[starts + lens - 1]
-        timers = TimerSamples(ids_x[starts], starts, lens,
-                              v32.astype(np.float64))
-        qs = [hazen(val_x, starts, lens, q) for q in percentiles]
-        for j, i in enumerate(ids_x[starts].tolist()):
-            base = f"{p}.t.{i:07d}"
-            out[base + ".min"] = float(mn[j])
-            out[base + ".max"] = float(mx[j])
-            out[base + ".count"] = float(lens[j])
-            for q, col in zip(percentiles, qs):
-                out[f"{base}.{int(round(q * 100))}percentile"] = float(col[j])
+        timers = _timer_rows(out, p, ids_x[order], val_x[order], percentiles,
+                             aggregates=True)
 
     sel, ids, val, m = of("set")
     if len(sel):
@@ -134,6 +148,42 @@ def expected(pool: Pool, b0: int, b1: int, percentiles,
         distinct = np.bincount(pairs // span)
         for i in np.flatnonzero(distinct).tolist():
             out[f"{p}.s.{i:07d}"] = float(distinct[i])
+    return out, timers
+
+
+def expected_forward(pool: ForwardPool, b0: int, b1: int, percentiles,
+                     counter_dtype=np.float64):
+    """`expected` for forwarded sketches at a global: the rows of the
+    interval of RPC positions [b0, b1) of a forward pool, and its
+    TimerSamples. An RPC's multiplicity is a datagram's (`multiplicity`).
+
+    A counter is the exact sum of its forwarded values (float32 for the
+    control: one add a forwarded value). A timer is held against the raw
+    samples its digests summarise: count, min and max exact (float32, as
+    stored), percentiles by rank (`rank_errors`) among the union of the
+    interval's raw samples of that name. The rows follow upstream's rule
+    at a global (stripe/veneur `worker.go:438-495` `ImportMetricGRPC`
+    merges an imported digest into the histogram of its scope;
+    `samplers.go:511-675` `Histo.Flush` and `flusher.go:61-77`: a mixed
+    histogram emits its aggregates on the locals and its percentiles at
+    the global only, a global one both at the global; the program's side
+    is `server/flusher.py`'s `imported_only` rule): a `mixed` timer emits
+    its percentiles only, a `global` timer its min, max and count too."""
+    mult_m = multiplicity(b0, b1, pool.n_rpcs)[0][pool.metric_rpc]
+    out, p = {}, pool.prefix
+    sel = np.flatnonzero((pool.m_kind == KINDS.index("counter"))
+                         & (mult_m > 0))
+    if len(sel):
+        _counter_rows(out, p, pool.m_name[sel],
+                      pool.m_value[sel].astype(np.float64), mult_m[sel],
+                      counter_dtype)
+    timers = None
+    name, value, metric = pool.timer_samples
+    m = mult_m[metric]
+    if m.any():
+        # sorted by name and value already: repeating keeps the order
+        timers = _timer_rows(out, p, np.repeat(name, m), np.repeat(value, m),
+                             percentiles, pool.timer_scope == "global")
     return out, timers
 
 

@@ -6,7 +6,10 @@
 Runs one cell of BENCHMARK.json on the machine it is started on and prints
 one JSON object as the last line of its standard output. There is no CPU
 mode: with no TPU, or another number of chips than the cell asks for, it
-exits non-zero and prints no result. To rehearse off the chip, call
+exits non-zero and prints no result. A run that could not be made (a
+`harness.RunError`) exits with 4 and its last line is a result that is not
+correct, with the reason under `error`. The traffic file's `ingress` picks
+the way in (`harness.INGRESS`). To rehearse off the chip, call
 `harness.Run(...).execute()` from a throw-away snippet (README.md).
 """
 
@@ -25,6 +28,15 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(1, ROOT)
+
+
+ERROR_CHARS = 300
+
+
+def error_line(error: Exception) -> dict:
+    """The last line of a run that could not be made."""
+    return {"correct": False, "attempted": 0, "failed": 0, "metrics": {},
+            "error": str(error)[:ERROR_CHARS]}
 
 
 def result_line(cell: dict, out: dict, device: dict, trace: bool) -> dict:
@@ -99,6 +111,7 @@ def main(argv=None, control: bool = False) -> int:
                           _T_PROCESS, control=control).execute()
     except harness.RunError as e:
         print(f"perfbench: the run could not be made: {e}", file=sys.stderr)
+        print(json.dumps(error_line(e)), flush=True)
         return 4
     device["memory_peak_bytes"] = out["memory_peak_bytes"]
     out["ctx"]["device_kind"] = device["kind"]

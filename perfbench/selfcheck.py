@@ -3,7 +3,11 @@
 needing no chip and not collected by the repo's tests:
 
 - the reference against a brute-force loop at a tiny pool, and its rank
-  errors against a loop over one timer's sorted samples;
+  errors against a loop over one timer's sorted samples; the same for the
+  forward reference over RPC positions;
+- the forwarder imports nothing of JAX and nothing of the program but the
+  generated wire-schema modules (`veneur_tpu/proto/*_pb2`), and what it
+  sends decodes with them;
 - the interval arithmetic of trace_reduce.py on hand-made events;
 - the roofline byte count on a hand-counted batch;
 - every file BENCHMARK.json names exists, and every name and unit uses
@@ -15,6 +19,7 @@ from __future__ import annotations
 import json
 import os
 import re
+import subprocess
 import sys
 
 import numpy as np
@@ -105,6 +110,73 @@ def check_reference():
                       ex)
     rows, ok = reference.verdict(numbers, {k: 1e-6 for k in numbers})
     assert ok and len(rows) == len(numbers), rows
+
+
+FORWARD_TINY = {"ingress": "forward", "prefix": "pb", "locals": 3, "bursts": 2,
+                "compression": 100, "metrics_per_rpc": 7, "kinds": {
+                    "counter": {"names": 9, "names_per_local": 4,
+                                "samples_per_local": 12, "zipf_s": 1.0},
+                    "timer": {"names": 6, "names_per_local": 3,
+                              "samples_per_local": 40, "zipf_s": 1.0,
+                              "scope": "global"}}}
+
+
+def check_forward_reference():
+    qs = (0.5, 0.75, 0.99)
+    for seed in (0, 2 ** 31 + 11):
+        pool = traffic.build_forward_pool(FORWARD_TINY, seed)
+        n = pool.n_rpcs
+        for b0, b1 in ((0, n), (n + 3, 3 * n + 2), (2, 4)):
+            counters, timers = {}, {}
+            for pos in range(b0, b1):
+                r = pos % n
+                for i in range(pool.rpc_start[r], pool.rpc_start[r + 1]):
+                    name = int(pool.m_name[i])
+                    if traffic.KINDS[pool.m_kind[i]] == "counter":
+                        counters[name] = (counters.get(name, 0.0)
+                                          + float(pool.m_value[i]))
+                    else:
+                        timers.setdefault(name, []).extend(
+                            pool.s_value[pool.s_start[i]:pool.s_start[i + 1]])
+            want = {f"pb.c.{k:07d}": v for k, v in counters.items()}
+            for k, vals in timers.items():
+                base = f"pb.t.{k:07d}"
+                a32 = np.asarray(vals, np.float32)
+                want[base + ".min"] = float(a32.min())
+                want[base + ".max"] = float(a32.max())
+                want[base + ".count"] = float(len(vals))
+                for q in qs:
+                    want[f"{base}.{int(round(q * 100))}percentile"] = float(
+                        np.quantile(np.asarray(vals), q, method="hazen"))
+            got, _ = reference.expected_forward(pool, b0, b1, qs)
+            assert want.keys() == got.keys(), (seed, b0, b1)
+            for k, v in want.items():
+                assert abs(got[k] - v) <= 1e-9 * max(1.0, abs(v)), (k, got[k], v)
+
+
+def check_forwarder():
+    """In a child of its own: what forwarder.py and its pool pull in."""
+    code = ("import sys, json; sys.path.insert(0, sys.argv[1]); "
+            "import forwarder, traffic; "
+            "pool = traffic.build_forward_pool(json.loads(sys.argv[2]), 5); "
+            "rpcs, _ = forwarder.encode(pool, forwarder.Digests(pool)); "
+            "print(json.dumps(sorted(sys.modules)))")
+    out = subprocess.run([sys.executable, "-c", code, HERE,
+                          json.dumps(FORWARD_TINY)], capture_output=True,
+                         text=True, check=True, cwd=ROOT)
+    mods = json.loads(out.stdout)
+    assert not [m for m in mods if m == "jax" or m.startswith("jax.")], mods
+    ours = [m for m in mods if m.split(".")[0] == "veneur_tpu"]
+    assert all(m in ("veneur_tpu", "veneur_tpu.proto")
+               or re.fullmatch(r"veneur_tpu\.proto\.\w+_pb2", m)
+               for m in ours), ours
+    sys.path.insert(1, ROOT)
+    import forwarder
+    from veneur_tpu.proto import forwardrpc_pb2
+    pool = traffic.build_forward_pool(FORWARD_TINY, 5)
+    rpcs, sizes = forwarder.encode(pool, forwarder.Digests(pool))
+    assert [len(forwardrpc_pb2.MetricList.FromString(r).metrics)
+            for r in rpcs] == sizes
 
 
 def brute_rank_error(x, g, q):
@@ -277,8 +349,8 @@ def check_files():
 
 
 def main() -> int:
-    for check in (check_reference, check_trace_reduce, check_roofline,
-                  check_files):
+    for check in (check_reference, check_forward_reference, check_forwarder,
+                  check_trace_reduce, check_roofline, check_files):
         check()
         print(f"ok  {check.__name__}")
     return 0
