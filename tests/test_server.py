@@ -347,10 +347,12 @@ def test_ingest_continues_during_slow_sink_flush(server):
 
 def _wait_key(srv, kind, name, timeout=10.0):
     """Wait until a metric key is registered in the live interval's table —
-    unlike `processed` counts, immune to self-telemetry loop-back races."""
+    unlike `processed` counts, immune to self-telemetry loop-back races.
+    Read from the test's thread, a slot the pipeline thread has just
+    allocated can show before its SlotMeta does (None): not yet there."""
     t0 = time.time()
     while time.time() - t0 < timeout:
-        if any(m.name == name
+        if any(m is not None and m.name == name
                for _, m in srv.aggregator.table.get_meta(kind)):
             return
         time.sleep(0.02)

@@ -347,6 +347,13 @@ class _NativeFeed:
         self._slot_metas = _SlotMetas(self.spec)
         self.table = NativeKeyTable(self.spec, self.eng, self.n_shards,
                                     self._slot_metas)
+        # the gRPC import path (import_pb_bytes), added up on the pipeline
+        # thread: requests folded, engine rows they staged, lane stops and
+        # the device steps dispatched while folding them
+        self.import_rpcs = 0
+        self.import_rows = 0
+        self.import_lane_stops = 0
+        self.import_steps = 0
 
     # -- wire path -----------------------------------------------------------
     def feed(self, data: bytes) -> List[bytes]:
@@ -446,11 +453,12 @@ class _NativeFeed:
         flushes computed, their blocks and their live rows
         (Aggregator._count_flush); the rows their frames emitted and how
         many of those took their name from a kept column
-        (Aggregator.count_frame); and how often the key table's
+        (Aggregator.count_frame); how often the key table's
         persistence engaged in the intervals swapped so far
         (NativeIngest.key_counters): the keys they held, of them the ones
         a swap paid for (new) and did not (reused), and the keys evicted
-        to make room."""
+        to make room; and the gRPC import path's counts (import_pb_bytes).
+        """
         keys = self.eng.key_counters()
         return {**self.eng.ring_stats(), "compactions": self.compactions,
                 "compact_rows": self.compact_rows,
@@ -459,7 +467,11 @@ class _NativeFeed:
                 "flush_rows": self.flush_rows,
                 "frame_rows": self.frame_rows,
                 "frame_labels_reused": self.frame_labels_reused, **keys,
-                "keys_reused": keys["keys_live"] - keys["keys_new"]}
+                "keys_reused": keys["keys_live"] - keys["keys_new"],
+                "import_rpcs": self.import_rpcs,
+                "import_rows": self.import_rows,
+                "import_lane_stops": self.import_lane_stops,
+                "import_steps": self.import_steps}
 
     def ring_stats_per_ring(self) -> List[dict]:
         """Per-ring telemetry rows ([] outside multi-ring mode) — the
@@ -656,24 +668,40 @@ class NativeAggregator(_NativeFeed, Aggregator):
         SendMetrics). Counters/gauges/digests stage natively; sets,
         valueless metrics, and oneof/type mismatches fall back to the
         Python import_into path so error accounting matches the
-        reference's per-metric semantics. Returns (metrics, errors)."""
+        reference's per-metric semantics. Returns (metrics, errors).
+
+        Spanned inside the caller's `pipeline.item`: `import.decode` (each
+        engine call: decode, key lookup, staging), `import.fallback` (the
+        Python import of fallback metrics) and `import.stats` (the
+        digests' scalar stats into the Python stats lane); the emits and
+        dispatches keep their own spans. Counted: import_rpcs,
+        import_rows (rows the engine staged: a digest's centroids, a
+        counter's or gauge's value), import_lane_stops and import_steps
+        (steps dispatched in here, the stats lane's included)."""
         from veneur_tpu.forward.convert import import_into
         from veneur_tpu.proto import metricpb_pb2 as mpb
+        eng = self.eng
+        steps_before = self.steps_total
         total = 0
         errors = 0
         off = 0
         while off < len(data):
-            staged, new_off, spans, lane_full = \
-                self.eng.import_metriclist(data, off)
+            with hostspans.span("import.decode"):
+                pending = eng.pending()
+                staged, new_off, spans, lane_full = \
+                    eng.import_metriclist(data, off)
+                self.import_rows += eng.pending() - pending
             total += staged + len(spans)
-            for so, sl in spans:
-                try:
-                    import_into(self, mpb.Metric.FromString(
-                        data[so:so + sl]))
-                except Exception as e:
-                    errors += 1
-                    log.warning("bad imported metric (native path): %s",
-                                e)
+            if spans:
+                with hostspans.span("import.fallback"):
+                    for so, sl in spans:
+                        try:
+                            import_into(self, mpb.Metric.FromString(
+                                data[so:so + sl]))
+                        except Exception as e:
+                            errors += 1
+                            log.warning("bad imported metric (native "
+                                        "path): %s", e)
             if new_off >= len(data):
                 break
             if not lane_full and new_off == off and staged == 0 \
@@ -687,14 +715,18 @@ class NativeAggregator(_NativeFeed, Aggregator):
                 break
             # staging filled (or the fallback buffer did): free the
             # lanes, then re-enter at the reported boundary
+            self.import_lane_stops += lane_full
             self._emit_native()
             off = new_off
         # per-digest exact min/max/recip ride the Python stats lane —
         # scatter min/max/add are order-independent vs the centroid
         # re-add, so batch boundaries don't matter
-        slots, mns, mxs, rcs = self.eng.drain_import_stats()
-        if len(slots):
-            self.batcher.add_histo_stats_bulk(slots, mns, mxs, rcs)
+        with hostspans.span("import.stats"):
+            slots, mns, mxs, rcs = eng.drain_import_stats()
+            if len(slots):
+                self.batcher.add_histo_stats_bulk(slots, mns, mxs, rcs)
+        self.import_rpcs += 1
+        self.import_steps += self.steps_total - steps_before
         return total, errors
 
     def _emit_rings(self) -> bool:

@@ -924,6 +924,28 @@ class Server:
                    kind="counter",
                    help="keys of earlier intervals evicted to make room "
                         "for new ones")
+        # the native gRPC import path (NativeAggregator.import_pb_bytes)
+        M.callback("veneur.import.rpcs_total",
+                   lambda: float(self._ring_stats().get("import_rpcs", 0)),
+                   kind="counter",
+                   help="serialized MetricLists folded by the native "
+                        "import decoder")
+        M.callback("veneur.import.rows_total",
+                   lambda: float(self._ring_stats().get("import_rows", 0)),
+                   kind="counter",
+                   help="rows the engine staged for them: a digest's "
+                        "centroids, a counter's or gauge's value")
+        M.callback("veneur.import.lane_stops_total",
+                   lambda: float(
+                       self._ring_stats().get("import_lane_stops", 0)),
+                   kind="counter",
+                   help="of the decoder's engine calls, the ones a full "
+                        "staging lane stopped")
+        M.callback("veneur.import.steps_total",
+                   lambda: float(self._ring_stats().get("import_steps", 0)),
+                   kind="counter",
+                   help="device ingest steps dispatched while folding "
+                        "them, the digests' stats lane included")
         # per-ring family (multi-ring engine only; empty single-ring).
         # The unlabeled veneur.ring.* names above stay the EXACT
         # cross-ring aggregates — sums, with depth_highwater as the
