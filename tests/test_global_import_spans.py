@@ -153,6 +153,8 @@ def serve(bench, tmp_path, seed, overrides) -> dict:
                 "import_steps": ring["import_steps"] - ring0["import_steps"],
                 "lane_stops": (ring["import_lane_stops"]
                                - ring0["import_lane_stops"]),
+                "stat_steps": (ring["import_stat_steps"]
+                               - ring0["import_stat_steps"]),
                 "import_errors": server.import_errors - errors0})
             got, tags, twice = harness.frame_rows(sink.handed[-1][1],
                                                   pool.prefix)
@@ -223,6 +225,15 @@ def test_import_steps_are_the_steps_of_the_imports(served):
     for i in served(SEEDS[0])["intervals"]:
         assert 0 < i["lane_stops"] <= i["import_steps"] <= i["steps"]
         assert i["steps"] - i["import_steps"] <= 2, i
+
+
+def test_a_request_pays_no_step_for_its_stats(served):
+    """The global's stats lane is as wide as its histo lane, so the
+    digests' stats ride the lane stops' steps: an import step is a lane
+    stop's, and none is the stats lane's own."""
+    for i in served(SEEDS[0])["intervals"]:
+        assert i["stat_steps"] == 0, i
+        assert i["import_steps"] == i["lane_stops"], i
 
 
 @pytest.mark.parametrize("name, per_request", [

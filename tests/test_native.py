@@ -653,7 +653,7 @@ def test_packed_sentinel_tail_invariant_after_partial_emit():
     spec, agg = _small_native_agg()
     eng = agg.eng
     layout, _words = packed_layout(agg._pk_sizes)
-    flat, prev = agg._new_packed()
+    flat, prev, _carried = agg._new_packed()
 
     for i in range(40):
         eng.feed(b"t.c%d:1|c" % i)

@@ -253,3 +253,171 @@ def test_native_import_malformed_tail_counted():
     total, errors = nat.import_pb_bytes(data)
     assert total == len(ml.metrics)   # the valid prefix all landed
     assert errors == 1
+
+
+# An importing server's lanes (server.bspec_from_config): the stats lane
+# as wide as the histo lane.
+WIDE = BatchSpec(counter=512, gauge=128, status=16, set=64, histo=512,
+                 histo_stat=512)
+
+
+def _digest_requests(k, seed=21):
+    """k forwarded requests of 22 digests each over the same names, ~605
+    centroid rows a request, so that the 512-row histo lane stops about
+    once a request."""
+    rng = np.random.default_rng(seed)
+    return [_mk_list(rng, n_counters=0, n_gauges=0, n_timers=20,
+                     n_sets=0).SerializeToString() for _ in range(k)]
+
+
+def test_import_stats_ride_the_lane_stops_steps():
+    """On an importing aggregator the digests' stats dispatch no step of
+    their own: the steps are the lane stops' and the swap's one emit."""
+    from veneur_tpu.server.native_aggregator import NativeAggregator
+    nat = NativeAggregator(SPEC, WIDE)
+    for data in _digest_requests(6):
+        assert nat.import_pb_bytes(data)[1] == 0
+    stops = nat.import_lane_stops
+    assert stops >= 6
+    assert nat.import_stat_steps == 0
+    assert nat.steps_total == nat.import_steps == stops
+    nat.swap()
+    assert nat.steps_total == stops + 1
+    assert nat.ring_stats()["import_stat_steps"] == 0
+
+
+def test_import_lane_stop_steps_compact():
+    """A step an import's lane stop dispatches carries a full lane of
+    imported centroids and compacts, whatever compact_every says: a
+    digest row is compacted as often a lane of imported centroids as when
+    the stats lane made steps of its own."""
+    from veneur_tpu.server.native_aggregator import NativeAggregator
+    nat = NativeAggregator(SPEC, WIDE, compact_every=8)
+    for data in _digest_requests(6, seed=25):
+        nat.import_pb_bytes(data)
+    assert nat.import_lane_stops >= 6
+    assert nat.compactions == nat.import_lane_stops == nat.steps_total
+    # a wire step keeps compact_every's cadence
+    nat.feed(b"wire.c:1|c")
+    nat._emit_native()
+    assert nat.compactions == (nat.import_lane_stops
+                               + (nat.steps_total % 8 == 0))
+
+
+def test_carried_stats_flush_as_the_python_import_does():
+    """The same requests through the carrying native path and through a
+    256-wide Python import that carries nothing: min and max bit for bit,
+    the reciprocal sum (read as the harmonic mean) to f32 reordering."""
+    from veneur_tpu.forward.convert import import_into
+    from veneur_tpu.server.aggregator import Aggregator
+    from veneur_tpu.server.native_aggregator import NativeAggregator
+    requests = _digest_requests(6, seed=23)
+    py = Aggregator(SPEC, BatchSpec(counter=512, gauge=128, status=16,
+                                    set=64, histo=512))
+    nat = NativeAggregator(SPEC, WIDE)
+    for data in requests:
+        for m in fpb.MetricList.FromString(data).metrics:
+            import_into(py, m)
+        nat.import_pb_bytes(data)
+    assert nat.import_stat_steps == 0
+    a, b = _flush_of(py), _flush_of(nat)
+    timers = [key for key in a if key[0] == "timer"]
+    assert len(timers) == 22 and set(a) == set(b)
+    for key in timers:
+        for field in ("histo_min", "histo_max"):
+            np.testing.assert_array_equal(a[key][field], b[key][field],
+                                          err_msg=f"{key} {field}")
+        np.testing.assert_allclose(a[key]["histo_hmean"],
+                                   b[key]["histo_hmean"], rtol=1e-5,
+                                   err_msg=f"{key} histo_hmean")
+
+
+def test_stats_lane_overflow_folds_every_stat_and_is_counted():
+    """A request of more single-centroid digests than a deliberately
+    small stats lane holds: the lane's own steps are counted in
+    import_stat_steps, the rest ride the swap's emit, and every digest's
+    min, max and reciprocal sum lands exactly."""
+    from veneur_tpu.server.native_aggregator import NativeAggregator
+    n, lane = 30, 8
+    ml = fpb.MetricList()
+    values = np.arange(1, n + 1, dtype=np.float32) * np.float32(1.25)
+    for i, v in enumerate(values.tolist()):
+        m = ml.metrics.add()
+        m.name, m.type, m.scope = f"one.t.{i}", mpb.Timer, mpb.Global
+        td = m.histogram.t_digest
+        c = td.main_centroids.add()
+        c.mean, c.weight = v, 1.0
+        td.min = td.max = v
+        td.reciprocalSum = 1.0 / v
+    nat = NativeAggregator(SPEC, BatchSpec(
+        counter=512, gauge=128, status=16, set=64, histo=512,
+        histo_stat=lane))
+    assert nat.import_pb_bytes(ml.SerializeToString()) == (n, 0)
+    assert nat.import_stat_steps == n // lane
+    assert nat.import_steps == n // lane and nat.import_lane_stops == 0
+    got = _flush_of(nat)
+    for i, v in enumerate(values.tolist()):
+        row = got[("timer", f"one.t.{i}", "")]
+        assert row["histo_min"] == v and row["histo_max"] == v
+        np.testing.assert_allclose(row["histo_hmean"], v, rtol=1e-6)
+
+
+def test_carried_stats_rows_are_restored_on_the_buffers_next_use():
+    """_carry_stats' sentinel contract, as vt_emit_packed's for its lanes:
+    a packed buffer that carried 10 stats rows and then carries 1 holds
+    that one row and sentinels past it, exactly a fresh buffer's; the
+    batcher's stats lane is left empty and at its sentinels."""
+    from veneur_tpu.aggregation.step import packed_layout
+    from veneur_tpu.server.native_aggregator import NativeAggregator
+    nat = NativeAggregator(SPEC, WIDE)
+    b = nat.batcher
+    layout, _words = packed_layout(nat._pk_sizes)
+    fresh = nat._new_packed()[0]
+    flat, _prev, carried = nat._new_packed()
+    for i in range(10):
+        b.add_histo_stats(i, float(i), float(i) + 1.0, 0.5)
+    nat._carry_stats(flat, carried)
+    assert carried == [10] and b.nhs == 0
+    b.add_histo_stats(3, -1.0, 9.0, 0.25)
+    nat._carry_stats(flat, carried)
+    assert carried == [1] and b.nhs == 0
+
+    def lanes(buf):
+        out = {}
+        for name in ("histo_stat_slot", "histo_stat_min", "histo_stat_max",
+                     "histo_stat_recip"):
+            off, n, _w = layout[name]
+            v = buf[off:off + n]
+            out[name] = v if name.endswith("slot") else v.view(np.float32)
+        return out
+
+    got, want = lanes(flat), lanes(fresh)
+    assert (got["histo_stat_slot"][0], got["histo_stat_min"][0],
+            got["histo_stat_max"][0], got["histo_stat_recip"][0]) == (
+                3, -1.0, 9.0, 0.25)
+    for name in got:
+        np.testing.assert_array_equal(got[name][1:], want[name][1:],
+                                      err_msg=name)
+    np.testing.assert_array_equal(flat[1:layout["histo_stat_slot"][0]],
+                                  fresh[1:layout["histo_stat_slot"][0]])
+    assert (b.hs_slot == SPEC.histo_capacity).all()
+    assert np.isposinf(b.hs_min).all() and np.isneginf(b.hs_max).all()
+    assert (b.hs_recip == 0).all()
+
+
+@pytest.mark.parametrize("grpc_address", ["", "127.0.0.1:0"])
+def test_stats_lane_width_follows_the_import_listener(grpc_address):
+    """A server without a gRPC import listener keeps the 256-row stats
+    lane, so its packed ingest program's lane sizes are what they were;
+    one with a listener makes the lane as wide as tpu_batch_histo."""
+    from tests.test_server import small_config
+    from veneur_tpu.server.server import Server
+    srv = Server(small_config(grpc_address=grpc_address))
+    try:
+        stat = 512 if grpc_address else 256
+        assert srv.aggregator.bspec.histo_stat == stat
+        assert srv.aggregator._pk_sizes == (
+            512, 512, 128, 128, 16, 16, 64, 64, 64, 512, 512, 512,
+            stat, stat, stat, stat)
+    finally:
+        srv.shutdown()

@@ -261,7 +261,9 @@ class BatchSpec:
     status: int = 256
     set: int = 4096
     histo: int = 8192
-    histo_stat: int = 256  # imported-digest scalar lane (step.py)
+    # imported-digest scalar lane (step.py); a server that takes imports
+    # makes it as wide as `histo` (server.bspec_from_config)
+    histo_stat: int = 256
 
 
 class Batcher:
@@ -396,6 +398,27 @@ class Batcher:
                     self.hs_recip), (slots, mns, mxs, recips), "nhs",
                    self.bspec.histo_stat)
 
+    def move_histo_stats(self, slot, mn, mx, recip) -> int:
+        """Move the staged imported-digest stats into another step's
+        stats lanes (arrays of this batcher's histo_stat width) and reset
+        them here; returns the rows moved (NativeAggregator._emit_native
+        carries them in its packed step)."""
+        n = self.nhs
+        slot[:n] = self.hs_slot[:n]
+        mn[:n] = self.hs_min[:n]
+        mx[:n] = self.hs_max[:n]
+        recip[:n] = self.hs_recip[:n]
+        self._clear_histo_stats()
+        return n
+
+    def _clear_histo_stats(self):
+        n = self.nhs
+        self.hs_slot[:n] = self.spec.histo_capacity
+        self.hs_min[:n] = np.inf
+        self.hs_max[:n] = -np.inf
+        self.hs_recip[:n] = 0.0
+        self.nhs = 0
+
     def pending(self) -> int:
         return (self.nc + self.ng + self.nst + self.ns + self.nh
                 + self.nhs)
@@ -446,10 +469,7 @@ class Batcher:
         self.st_slot[:self.nst] = self.spec.status_capacity
         self.s_slot[:self.ns] = self.spec.set_capacity
         self.h_slot[:self.nh] = self.spec.histo_capacity
-        self.hs_slot[:self.nhs] = self.spec.histo_capacity
-        self.hs_min[:self.nhs] = np.inf
-        self.hs_max[:self.nhs] = -np.inf
-        self.hs_recip[:self.nhs] = 0.0
+        self._clear_histo_stats()
         self.c_inc[:self.nc] = 0.0
         self.h_wt[:self.nh] = 0.0
         self.nc = self.ng = self.nst = self.ns = self.nh = self.nhs = 0

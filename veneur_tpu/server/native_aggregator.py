@@ -64,6 +64,14 @@ from veneur_tpu.server.sharded_aggregator import ShardedAggregator
 log = logging.getLogger("veneur_tpu.server.native_aggregator")
 
 
+def _lane(flat, layout, name, f32=False):
+    """One lane of a packed buffer (step.packed_layout), as a view: f32
+    lanes bit-viewed."""
+    off, n, _ = layout[name]
+    view = flat[off:off + n]
+    return view.view(np.float32) if f32 else view
+
+
 class _KindLabels:
     """One kind-table's flush labels by slot (_SlotMetas)."""
 
@@ -348,12 +356,14 @@ class _NativeFeed:
         self.table = NativeKeyTable(self.spec, self.eng, self.n_shards,
                                     self._slot_metas)
         # the gRPC import path (import_pb_bytes), added up on the pipeline
-        # thread: requests folded, engine rows they staged, lane stops and
-        # the device steps dispatched while folding them
+        # thread: requests folded, engine rows they staged, lane stops,
+        # the device steps dispatched while folding them and, of those,
+        # the steps a full stats lane dispatched
         self.import_rpcs = 0
         self.import_rows = 0
         self.import_lane_stops = 0
         self.import_steps = 0
+        self.import_stat_steps = 0
 
     # -- wire path -----------------------------------------------------------
     def feed(self, data: bytes) -> List[bytes]:
@@ -471,7 +481,8 @@ class _NativeFeed:
                 "import_rpcs": self.import_rpcs,
                 "import_rows": self.import_rows,
                 "import_lane_stops": self.import_lane_stops,
-                "import_steps": self.import_steps}
+                "import_steps": self.import_steps,
+                "import_stat_steps": self.import_stat_steps}
 
     def ring_stats_per_ring(self) -> List[dict]:
         """Per-ring telemetry rows ([] outside multi-ring mode) — the
@@ -587,9 +598,10 @@ class NativeAggregator(_NativeFeed, Aggregator):
         # no Python repack. All 16 lanes are present at the Python
         # Batcher's sizes, in Batch._fields order, so the compile key
         # (spec, sizes) matches the Python path and ONE compiled ingest
-        # program serves both — the status and histo_stat lanes never
-        # ride the native wire path and stay Python-initialized constant
-        # sentinel regions that C++ never touches.
+        # program serves both. C++ never touches the status and
+        # histo_stat lanes: status stays a Python-initialized constant
+        # sentinel region, and the histo_stat region carries the Python
+        # Batcher's staged digest stats when there are any (_carry_stats).
         b = bspec
         self._pk_sizes = (b.counter, b.counter, b.gauge, b.gauge,
                           b.status, b.status, b.set, b.set, b.set,
@@ -599,7 +611,7 @@ class NativeAggregator(_NativeFeed, Aggregator):
         self._pk_layout, self._pk_words = packed_layout(self._pk_sizes)
         # word offsets of the ten lanes the C++ engine stages, in
         # vt_emit_packed's argument order; the interleaved status and
-        # histo_stat lanes stay Python-owned
+        # histo_stat lanes are Python-owned
         self._pk_offs = np.asarray(
             [self._pk_layout[name][0] for name in (
                 "counter_slot", "counter_inc", "gauge_slot", "gauge_val",
@@ -609,10 +621,11 @@ class NativeAggregator(_NativeFeed, Aggregator):
     def _new_packed(self):
         """One flat packed buffer, and beside it the staged-row counts of
         that buffer's previous emit — vt_emit_packed's incremental
-        sentinel-restore bound."""
+        sentinel-restore bound — and the stats rows its previous step
+        carried (_carry_stats' bound, a one-item list)."""
         flat = np.zeros(self._pk_words, np.int32)
         self._init_packed_sentinels(flat, self._pk_layout, self.spec)
-        return flat, np.zeros(4, np.uint32)
+        return flat, np.zeros(4, np.uint32), [0]
 
     def _new_arena(self):
         """One (rings, words) arena — a row per ring in the exact packed
@@ -633,13 +646,11 @@ class NativeAggregator(_NativeFeed, Aggregator):
         lanes 0, histo-stat min/max at +/-inf — the state Batcher.emit's
         partial reset maintains on the Python path. After this, the six
         C++-maintained lanes are kept in this state incrementally by
-        vt_emit_packed and the status/histo_stat regions are never
-        written again."""
+        vt_emit_packed, the histo_stat region by _carry_stats, and the
+        status region is never written again."""
 
         def lane(name, value, f32=False):
-            off, n, _ = layout[name]
-            view = flat[off:off + n]
-            (view.view(np.float32) if f32 else view)[:] = value
+            _lane(flat, layout, name, f32)[:] = value
 
         lane("counter_slot", spec.counter_capacity)
         lane("gauge_slot", spec.gauge_capacity)
@@ -650,15 +661,38 @@ class NativeAggregator(_NativeFeed, Aggregator):
         lane("histo_stat_min", np.inf, f32=True)
         lane("histo_stat_max", -np.inf, f32=True)
 
-    def _emit_native(self):
-        flat, prev = self._step_buffer("packed", self._new_packed)
+    def _emit_native(self, compact: bool = False):
+        flat, prev, carried = self._step_buffer("packed", self._new_packed)
         with hostspans.span("pipeline.emit"):
             nc, ng, ns, nh = self.eng.emit_packed(flat, self._pk_offs, prev)
         if nc + ng + ns + nh == 0:
             return
-        flat[0] = 1 if self._count_step() else 0
+        if self.batcher.nhs or carried[0]:
+            self._carry_stats(flat, carried)
+        flat[0] = 1 if self._count_step(force_compact=compact) else 0
         self._dispatch_step(ingest_step_packed, flat, "packed",
                             spec=self.spec, sizes=self._pk_sizes)
+
+    def _carry_stats(self, flat, carried) -> None:
+        """Move the Python Batcher's staged digest stats into this step's
+        histo_stat region, so that they ride a step the engine's rows
+        make and dispatch none of their own, and put the rows this buffer
+        carried last time past the new count back at their sentinels (as
+        vt_emit_packed does for its lanes with `prev`). Scatter min, max
+        and add do not care which step a row rides."""
+        lay = self._pk_layout
+        slot = _lane(flat, lay, "histo_stat_slot")
+        mn = _lane(flat, lay, "histo_stat_min", f32=True)
+        mx = _lane(flat, lay, "histo_stat_max", f32=True)
+        recip = _lane(flat, lay, "histo_stat_recip", f32=True)
+        n = self.batcher.move_histo_stats(slot, mn, mx, recip)
+        old = carried[0]
+        if old > n:
+            slot[n:old] = self.spec.histo_capacity
+            mn[n:old] = np.inf
+            mx[n:old] = -np.inf
+            recip[n:old] = 0.0
+        carried[0] = n
 
     # -- native import path (global tier) ------------------------------
     def import_pb_bytes(self, data: bytes):
@@ -673,11 +707,13 @@ class NativeAggregator(_NativeFeed, Aggregator):
         Spanned inside the caller's `pipeline.item`: `import.decode` (each
         engine call: decode, key lookup, staging), `import.fallback` (the
         Python import of fallback metrics) and `import.stats` (the
-        digests' scalar stats into the Python stats lane); the emits and
-        dispatches keep their own spans. Counted: import_rpcs,
-        import_rows (rows the engine staged: a digest's centroids, a
-        counter's or gauge's value), import_lane_stops and import_steps
-        (steps dispatched in here, the stats lane's included)."""
+        digests' scalar stats into the Python stats lane, which the next
+        engine step carries: _carry_stats); the emits and dispatches keep
+        their own spans. Counted: import_rpcs, import_rows (rows the
+        engine staged: a digest's centroids, a counter's or gauge's
+        value), import_lane_stops, import_steps (steps dispatched in
+        here, the stats lane's included) and import_stat_steps (of them,
+        the ones a full stats lane dispatched in `import.stats`)."""
         from veneur_tpu.forward.convert import import_into
         from veneur_tpu.proto import metricpb_pb2 as mpb
         eng = self.eng
@@ -714,17 +750,26 @@ class NativeAggregator(_NativeFeed, Aggregator):
                             "(%d bytes dropped)", off, len(data) - off)
                 break
             # staging filled (or the fallback buffer did): free the
-            # lanes, then re-enter at the reported boundary
+            # lanes, then re-enter at the reported boundary. The step
+            # compacts: it carries a full lane of imported centroids,
+            # already merged and heavy, and what overflows a digest row's
+            # temp cells before a compaction lands in its estimate cells
+            # (step._histo_plan), whose error grows with every step left
+            # uncompacted
             self.import_lane_stops += lane_full
-            self._emit_native()
+            self._emit_native(compact=True)
             off = new_off
-        # per-digest exact min/max/recip ride the Python stats lane —
-        # scatter min/max/add are order-independent vs the centroid
-        # re-add, so batch boundaries don't matter
+        # per-digest exact min/max/recip ride the Python stats lane until
+        # an engine step carries them — scatter min/max/add are
+        # order-independent vs the centroid re-add, so batch boundaries
+        # don't matter; a step dispatched here is the lane overflowing
         with hostspans.span("import.stats"):
             slots, mns, mxs, rcs = eng.drain_import_stats()
             if len(slots):
+                stat_steps_before = self.steps_total
                 self.batcher.add_histo_stats_bulk(slots, mns, mxs, rcs)
+                self.import_stat_steps += (self.steps_total
+                                           - stat_steps_before)
         self.import_rpcs += 1
         self.import_steps += self.steps_total - steps_before
         return total, errors

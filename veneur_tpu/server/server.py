@@ -144,6 +144,22 @@ def spec_from_config(cfg: Config) -> TableSpec:
         exact_extremes=int(cfg.tpu_digest_exact_extremes))
 
 
+def bspec_from_config(cfg: Config) -> BatchSpec:
+    """The staging lanes' widths. A server with a gRPC import listener
+    takes forwarded digests, one min/max/reciprocal-sum row each beside
+    at least one centroid, so its stats lane is as wide as its histo lane
+    and fills only where more digests than that arrive between two steps
+    of the centroids, which carry the stats
+    (NativeAggregator._carry_stats). Without one the lane keeps
+    BatchSpec's width."""
+    widths = dict(counter=cfg.tpu_batch_counter, gauge=cfg.tpu_batch_gauge,
+                  status=cfg.tpu_batch_status, set=cfg.tpu_batch_set,
+                  histo=cfg.tpu_batch_histo)
+    if cfg.grpc_address:
+        widths["histo_stat"] = cfg.tpu_batch_histo
+    return BatchSpec(**widths)
+
+
 class Server:
     def __init__(self, cfg: Config, metric_sinks: Optional[List] = None,
                  span_sinks: Optional[List] = None,
@@ -161,11 +177,7 @@ class Server:
             None if cfg.pallas_ingest_enabled else False)
         agg_args = dict(
             spec=spec_from_config(cfg),
-            bspec=BatchSpec(counter=cfg.tpu_batch_counter,
-                            gauge=cfg.tpu_batch_gauge,
-                            status=cfg.tpu_batch_status,
-                            set=cfg.tpu_batch_set,
-                            histo=cfg.tpu_batch_histo),
+            bspec=bspec_from_config(cfg),
             n_shards=max(1, cfg.tpu_n_shards) if cfg.tpu_n_shards else 1,
             compact_every=cfg.tpu_compact_every)
         self._native = False
@@ -751,11 +763,7 @@ class Server:
         cfg = self.cfg
         agg_args = dict(
             spec=spec if spec is not None else spec_from_config(cfg),
-            bspec=BatchSpec(counter=cfg.tpu_batch_counter,
-                            gauge=cfg.tpu_batch_gauge,
-                            status=cfg.tpu_batch_status,
-                            set=cfg.tpu_batch_set,
-                            histo=cfg.tpu_batch_histo),
+            bspec=bspec_from_config(cfg),
             n_shards=max(1, int(n_shards)),
             compact_every=cfg.tpu_compact_every)
         native = cfg.native_ingest and (engine is not None
@@ -946,6 +954,12 @@ class Server:
                    kind="counter",
                    help="device ingest steps dispatched while folding "
                         "them, the digests' stats lane included")
+        M.callback("veneur.import.stat_steps_total",
+                   lambda: float(
+                       self._ring_stats().get("import_stat_steps", 0)),
+                   kind="counter",
+                   help="of them, the steps a full digest stats lane "
+                        "dispatched")
         # per-ring family (multi-ring engine only; empty single-ring).
         # The unlabeled veneur.ring.* names above stay the EXACT
         # cross-ring aggregates — sums, with depth_highwater as the
