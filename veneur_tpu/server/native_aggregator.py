@@ -817,6 +817,31 @@ class NativeShardedAggregator(_NativeFeed, ShardedAggregator):
         self.preshard = preshard
         self._ps_bounds = np.zeros(4 * (n_shards + 1), np.int32)
         self._alloc_emit_buffers()
+        # the engine's rows staged into the shard batchers, in all and by
+        # shard, added up on the pipeline thread (ring_stats)
+        self.shard_rows_staged = 0
+        self._shard_rows = np.zeros(n_shards, np.int64)
+
+    def ring_stats(self) -> dict:
+        """_NativeFeed.ring_stats with the sharded staging's counts: the
+        rows the engine's emits staged into the shard batchers, the
+        busiest shard's part of them, the lane rows the dispatched
+        steps' tiles held (ShardedAggregator._dispatch_row) and the steps
+        one shard's full lane dispatched with the other tiles as they
+        stood (_on_shard_batch)."""
+        return {**super().ring_stats(),
+                "shard_rows_staged": self.shard_rows_staged,
+                "shard_rows_max": int(self._shard_rows.max()),
+                "shard_lane_rows_dispatched":
+                    self.shard_lane_rows_dispatched,
+                "shard_forced_emits": self.shard_forced_emits}
+
+    def _count_staged(self, rows: int, at) -> None:
+        """Count a kind's `rows` staged, split by the shard bounds `at`
+        (S + 1 offsets into its lane)."""
+        self.shard_rows_staged += rows
+        self._shard_rows += at[1:]
+        self._shard_rows -= at[:-1]
 
     def _alloc_emit_buffers(self):
         """Staging targets for emit_into — the sharded backend re-stages
@@ -878,9 +903,17 @@ class NativeShardedAggregator(_NativeFeed, ShardedAggregator):
         bounds in self._ps_bounds) into the per-shard batchers. Contiguous
         slices only — the argsort/searchsorted of _split_shards and the
         local-slot subtraction both happened in C++ during the one pass
-        the emit copy already makes."""
+        the emit copy already makes. Under the `pipeline.shard_split`
+        span, as _emit_split's staging."""
+        with hostspans.span("pipeline.shard_split"):
+            self._stage_bounds(nc, ng, ns, nh)
+
+    def _stage_bounds(self, nc, ng, ns, nh):
         b = self._ps_bounds
         S = self.n_shards
+        for k, n in enumerate((nc, ng, ns, nh)):
+            if n:
+                self._count_staged(n, b[k * (S + 1):(k + 1) * (S + 1)])
         if nc:
             at = b[0:S + 1]
             for i in range(S):
@@ -934,10 +967,18 @@ class NativeShardedAggregator(_NativeFeed, ShardedAggregator):
              self._h_val, self._h_wt))
         if nc + ng + ns + nh == 0:
             return
+        # the lanes' split by owner shard and their copy into the shard
+        # batchers; a step a full lane dispatches is its child
+        # (`pipeline.shard_pack`)
+        with hostspans.span("pipeline.shard_split"):
+            self._split_into_batchers(nc, ng, ns, nh)
+
+    def _split_into_batchers(self, nc, ng, ns, nh):
         p = self.pspec
         if nc:
             order, lo, at = self._split_shards(self._c_slot[:nc],
                                                p.counter_capacity)
+            self._count_staged(nc, at)
             inc = self._c_inc[:nc][order]
             for i in range(self.n_shards):
                 if at[i + 1] > at[i]:
@@ -946,6 +987,7 @@ class NativeShardedAggregator(_NativeFeed, ShardedAggregator):
         if ng:
             order, lo, at = self._split_shards(self._g_slot[:ng],
                                                p.gauge_capacity)
+            self._count_staged(ng, at)
             val = self._g_val[:ng][order]
             for i in range(self.n_shards):
                 if at[i + 1] > at[i]:
@@ -954,6 +996,7 @@ class NativeShardedAggregator(_NativeFeed, ShardedAggregator):
         if ns:
             order, lo, at = self._split_shards(self._s_slot[:ns],
                                                p.set_capacity)
+            self._count_staged(ns, at)
             reg = self._s_reg[:ns][order]
             rho = self._s_rho[:ns][order]
             for i in range(self.n_shards):
@@ -964,6 +1007,7 @@ class NativeShardedAggregator(_NativeFeed, ShardedAggregator):
         if nh:
             order, lo, at = self._split_shards(self._h_slot[:nh],
                                                p.histo_capacity)
+            self._count_staged(nh, at)
             val = self._h_val[:nh][order]
             wt = self._h_wt[:nh][order]
             for i in range(self.n_shards):

@@ -11,9 +11,9 @@ sharded ingest program serves every step, with each tile's scatters local
 to its device.
 
 Config: tpu_n_shards > 1 (or 0 = one shard per local device when several
-devices are present). Native C++ staging currently pairs with the
-single-device backend; sharded mode uses Python staging (the mesh path is
-about device scale-out, not host parse throughput).
+devices are present). The native C++ engine feeds this backend through
+native_aggregator.NativeShardedAggregator, which splits the engine's
+staged lanes by owner shard into these per-shard Batchers.
 """
 
 from __future__ import annotations
@@ -151,6 +151,15 @@ class ShardedAggregator(Aggregator):
         self.batchers = self._make_batchers()
         # (its _hll_slots hold (shard, local_slot) pairs in this backend)
         self._init_step_site()
+        # how full the mesh's steps go out, added up on the pipeline
+        # thread: the rows the dispatched tiles hold in the four lanes
+        # the native engine stages (every tile's, padding included), and
+        # the steps one shard's full lane dispatched with the other
+        # tiles as they stood (_on_shard_batch)
+        self._lane_rows = n_shards * (bspec.counter + bspec.gauge
+                                      + bspec.set + bspec.histo)
+        self.shard_lane_rows_dispatched = 0
+        self.shard_forced_emits = 0
 
     # -- slot routing --------------------------------------------------------
     def _local(self, kind: str, slot: int) -> Tuple[int, int]:
@@ -269,14 +278,21 @@ class ShardedAggregator(Aggregator):
         [R, S, W] buffer (pack_batch `out`: no per-step allocation, no
         np.stack pass) and run the fused mesh step; compaction rides the
         in-band control word at the same cadence as the single-device
-        backend (Aggregator._on_batch)."""
-        dc = self._count_step(force_compact)
-        flat = self._step_buffer("row", self._new_row)
-        for i, b in enumerate(row):
-            pack_batch(b, dc, out=flat[0, i])
-        self._dispatch_step(self._ingest, flat, "row")
+        backend (Aggregator._on_batch). Under the `pipeline.shard_pack`
+        span, whose child is the step's `pipeline.dispatch`."""
+        with hostspans.span("pipeline.shard_pack"):
+            dc = self._count_step(force_compact)
+            flat = self._step_buffer("row", self._new_row)
+            for i, b in enumerate(row):
+                pack_batch(b, dc, out=flat[0, i])
+            self.shard_lane_rows_dispatched += self._lane_rows
+            self._dispatch_step(self._ingest, flat, "row")
 
     def _on_shard_batch(self, shard: int, batch):
+        """One shard's lane filled: every other tile goes out as it
+        stands, partly full or empty."""
+        if self.n_shards > 1:
+            self.shard_forced_emits += 1
         self._dispatch_row([batch if i == shard else b.force_emit()
                             for i, b in enumerate(self.batchers)])
 
